@@ -104,22 +104,6 @@ def use_mesh(mesh: Mesh):
         _active_mesh = prev
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable ``shard_map``: newer jax exposes
-    ``jax.shard_map`` with a ``check_vma`` kwarg; older releases ship it
-    as ``jax.experimental.shard_map.shard_map`` with the same check
-    under the ``check_rep`` name.  Every mesh path (gbdt scans, the
-    Pallas ring-collective probes) routes through this one shim so a jax
-    upgrade/downgrade is a one-line event, not a broken distributed
-    subsystem."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def data_sharding(mesh: Mesh) -> NamedSharding:
     """Rows sharded along the data axis, everything else replicated."""
     return NamedSharding(mesh, PartitionSpec(DATA_AXIS))

@@ -43,11 +43,6 @@ from .grower import (GrowerConfig, TreeArrays, _grow_tree_impl,
 from .objectives import Objective
 
 
-from ..core.mesh import shard_map_compat as _shard_map  # noqa: E402
-# (the shim lives in core.mesh so the ops-layer ring collectives can
-#  share it without an ops -> gbdt import inversion)
-
-
 VALID_PARALLELISM = ("serial", "data", "feature", "data+feature", "voting")
 
 
@@ -209,7 +204,7 @@ def make_goss_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
     else:
         val_hist_spec = P(None, None) if K == 1 else P(None, None, None)
     fa = _f_ax(mesh)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         steps, mesh=mesh,
         in_specs=(P(DATA_AXIS, fa), sc_spec, P(DATA_AXIS),
                   P(DATA_AXIS), P(DATA_AXIS), P(None, None),
@@ -283,7 +278,7 @@ def make_boost_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
     bag_spec = P(None, DATA_AXIS) if bag_sharded else P(None, None)
     val_hist_spec = P(None, DATA_AXIS) if has_val else P(None, None)
     fa = _f_ax(mesh)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         steps, mesh=mesh,
         in_specs=(P(DATA_AXIS, fa), P(DATA_AXIS), P(DATA_AXIS),
                   P(DATA_AXIS), P(DATA_AXIS), bag_spec,
@@ -344,7 +339,7 @@ def make_multiclass_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig,
     bag_spec = P(None, DATA_AXIS) if bag_sharded else P(None, None)
     val_hist_spec = P(None, DATA_AXIS, None) if has_val else P(None, None)
     fa = _f_ax(mesh)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         steps, mesh=mesh,
         in_specs=(P(DATA_AXIS, fa), P(DATA_AXIS, None),
                   P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS), bag_spec,
@@ -380,7 +375,7 @@ def make_ranking_dart_step(mesh: Mesh, cfg: GrowerConfig, lr: float,
         tree = apply_shrinkage(tree, lr)
         return tree, tree.leaf_value[row_leaf]
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(None, DATA_AXIS), P(DATA_AXIS),
                   P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS, None, None),
@@ -432,7 +427,7 @@ def make_dart_step(mesh: Mesh, obj: Objective, cfg: GrowerConfig,
     binsT_spec = (P(FEATURE_AXIS, DATA_AXIS) if fshard
                   else P(None, DATA_AXIS))
     fi_spec = P(FEATURE_AXIS, None) if fshard else P(None, None)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(bins_spec, binsT_spec, sc_spec,
                   P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
@@ -470,7 +465,7 @@ def make_tree_predict(mesh: Mesh, num_leaves: int, num_class: int = 1):
             return jax.vmap(lambda t: walk(t, bins))(trees_st).T
         out_spec = P(DATA_AXIS, None)
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         pred, mesh=mesh,
         in_specs=(P(), bins_spec),
         out_specs=out_spec,
@@ -574,7 +569,7 @@ def make_ranking_scan(mesh: Mesh, cfg: GrowerConfig, lr: float,
     val_hist_spec = P(None, DATA_AXIS) if has_val else P(None, None)
     bag_spec = P(None, DATA_AXIS) if bag_sharded else P(None, None)
     fa = _f_ax(mesh)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         steps, mesh=mesh,
         in_specs=(P(DATA_AXIS, fa), P(DATA_AXIS), P(DATA_AXIS),
                   P(DATA_AXIS), P(DATA_AXIS, None, None),

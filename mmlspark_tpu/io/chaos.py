@@ -485,7 +485,7 @@ class ChaosBoostStep:
 
     * ``fail_on_calls`` — exact call indices (1-based, counting every
       invocation INCLUDING replays) that raise ``RuntimeError`` instead
-      of running — the "device/tunnel loss at chunk k" injection the
+      of running — the "device loss at chunk k" injection the
       engine's ``faultTolerantRetries`` replay must absorb.
     * ``exc_rate`` — per-call Bernoulli failure, drawn from the plan's
       channel (thread-interleaving deterministic, like every injector).

@@ -50,6 +50,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..core.backend import pin_cpu_backend
 from ..core.capacity import capacity_enabled
 from ..core.profiler import get_profiler
 from ..core.profiling import StageStats
@@ -230,8 +231,10 @@ def _fleet_worker_main(driver_host: str, driver_port: int,
     is stamped with the version the driver fanned it out under, so an
     in-flight request completes on its own version on every shard and
     no reduce ever mixes tree-range shards from two models."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if booster is None:
+        # a spawned shard process (threads are handed their booster): a
+        # CPU scorer by design, whose parent may hold the chip
+        pin_cpu_backend()
         from ..gbdt.booster import Booster
         booster = Booster.load_native_model(model_path)
     if replica:

@@ -602,6 +602,16 @@ def join_exchange(exchange: str, worker_id: int,
                     reconnect_tries, reconnect_backoff)
 
 
+def _mp_worker_proc(*args) -> None:
+    """Spawned-process entry for :func:`_mp_worker_main`.  The worker
+    only parks sockets and never needs a device; the fence makes sure
+    that if anything in it ever does touch jax, it lands on the CPU and
+    not on the chip the spawning driver holds."""
+    from ..core.backend import pin_cpu_backend
+    pin_cpu_backend()
+    _mp_worker_main(*args)
+
+
 def _mp_worker_main(driver_host: str, driver_port: int, worker_id: int,
                     http_host: str, api_path: str,
                     reply_timeout: float, token: str = "",
@@ -1220,7 +1230,7 @@ class MultiprocessHTTPServer:
         ctx = mp.get_context("spawn")  # no inherited jax/thread state
         dh, dp = self._ts.address
         return ctx.Process(
-            target=_mp_worker_main,
+            target=_mp_worker_proc,
             args=(dh, dp, worker_id, self._host, self._api_path,
                   self._reply_timeout, self.token,
                   self._request_read_timeout, self._reconnect_tries,
@@ -1885,6 +1895,11 @@ class MultiprocessHTTPServer:
 
     def stop(self) -> None:
         self._closing.set()    # supervisor + beacon wind down
+        if self._proc_supervisor is not None:
+            # first: a respawn in flight must finish start() before
+            # _procs is joined below
+            self._proc_supervisor.join(timeout=5)
+            self._proc_supervisor = None
         for session in list(self._ts.sessions.values()):
             try:
                 session.send(CH_CONTROL, {"op": "stop"}, timeout=1.0)
@@ -1895,9 +1910,6 @@ class MultiprocessHTTPServer:
             if p.is_alive():
                 p.terminate()
         self._ts.stop()
-        if self._proc_supervisor is not None:
-            self._proc_supervisor.join(timeout=5)
-            self._proc_supervisor = None
         if self._ready_beacon is not None:
             self._ready_beacon.join(timeout=5)
             self._ready_beacon = None

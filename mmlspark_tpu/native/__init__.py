@@ -27,11 +27,14 @@ Set ``MMLSPARK_TPU_NO_NATIVE=1`` to force the Python fallbacks.
 
 from __future__ import annotations
 
+import logging
 import os
 import subprocess
 import sys
 import sysconfig
 from typing import List, Optional, Tuple
+
+log = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _mods = {}
@@ -42,22 +45,32 @@ def _so_path(stem: str) -> str:
     return os.path.join(_HERE, f"{stem}{tag}")
 
 
-def _build(src_name: str, stem: str) -> bool:
-    """Compile one .cc with g++ (or cc) into the package directory."""
-    src = os.path.join(_HERE, src_name)
-    out = _so_path(stem)
-    include = sysconfig.get_paths()["include"]
+def _compile(src: str, out: str, include: str, *extra: str) -> bool:
+    """Compile one .cc into a shared object with the first C++ compiler
+    that works.  A compiler that runs and fails has its stderr logged:
+    the callers' numpy/XLA fallbacks are correct but slower, and a
+    silent fallback hides a broken toolchain."""
     for cxx in ("g++", "c++", "clang++"):
         try:
             proc = subprocess.run(
                 [cxx, "-O2", "-std=c++17", "-shared", "-fPIC",
-                 f"-I{include}", src, "-o", out, "-pthread"],
-                capture_output=True, text=True, timeout=120)
-        except (OSError, subprocess.TimeoutExpired):
+                 f"-I{include}", src, "-o", out, *extra],
+                capture_output=True, text=True, timeout=180)
+        except (OSError, subprocess.TimeoutExpired) as e:
+            log.debug("native build: %s unusable (%s)", cxx, e)
             continue
         if proc.returncode == 0:
             return True
+        log.warning("native build of %s with %s failed (rc=%d):\n%s",
+                    os.path.basename(src), cxx, proc.returncode,
+                    proc.stderr[-4000:])
     return False
+
+
+def _build(src_name: str, stem: str) -> bool:
+    """Compile one .cc into a CPython extension in the package directory."""
+    return _compile(os.path.join(_HERE, src_name), _so_path(stem),
+                    sysconfig.get_paths()["include"], "-pthread")
 
 
 def _fresh(out_path: str, src_path: str) -> bool:
@@ -125,29 +138,12 @@ _FFI_LIB = None
 
 def _build_ffi(src_name: str, stem: str) -> bool:
     """Compile an XLA FFI shared lib against jaxlib's bundled headers."""
-    src = os.path.join(_HERE, src_name)
+    from jax import ffi as _jffi
     # ".bin", not ".so": a bare .so in the package dir would be picked up
     # as a CPython extension module by pkgutil walkers (it isn't one)
-    out = os.path.join(_HERE, f"{stem}.bin")
-    try:
-        try:
-            from jax import ffi as _jffi        # jax >= 0.4.38
-        except ImportError:
-            from jax.extend import ffi as _jffi  # 0.4.3x series
-        ffi_inc = _jffi.include_dir()
-    except Exception:  # noqa: BLE001 - ancient jax
-        return False
-    for cxx in ("g++", "c++", "clang++"):
-        try:
-            proc = subprocess.run(
-                [cxx, "-O2", "-std=c++17", "-shared", "-fPIC",
-                 f"-I{ffi_inc}", src, "-o", out],
-                capture_output=True, text=True, timeout=180)
-        except (OSError, subprocess.TimeoutExpired):
-            continue
-        if proc.returncode == 0:
-            return True
-    return False
+    return _compile(os.path.join(_HERE, src_name),
+                    os.path.join(_HERE, f"{stem}.bin"),
+                    _jffi.include_dir())
 
 
 def _ffi_lib():

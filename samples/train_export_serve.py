@@ -3,8 +3,7 @@
 The reference's quickstart story (train a LightGBMClassifier, save the
 native model, score it elsewhere, stand it up behind Spark Serving) on the
 TPU-native stack.  Runs on any jax backend; pass ``--cpu`` to force the
-CPU backend (some images pin ``JAX_PLATFORMS`` at interpreter startup,
-so the env var alone may not stick).
+CPU backend.
 
     python samples/train_export_serve.py [--cpu]
 """

@@ -331,13 +331,9 @@ def _regen():
 if __name__ == "__main__":
     import sys
     if "--regen" in sys.argv:
-        # standalone run (no pytest conftest): force the 8-device CPU
-        # platform via the live-config path — the env-var route hangs
-        # backend init in this image (see __graft_entry__)
+        # standalone run (no pytest conftest): the same 8-device CPU
+        # platform the suite pins the expectations on
         import jax
-        try:
-            jax.config.update("jax_platforms", "cpu")
-            jax.config.update("jax_num_cpu_devices", 8)
-        except Exception:
-            pass
+        jax.config.update("jax_platforms", "cpu")
+        jax.config.update("jax_num_cpu_devices", 8)
         _regen()

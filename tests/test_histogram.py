@@ -253,7 +253,8 @@ class TestNativeFindSplit:
 class TestPallasFused:
     """Fused gather+histogram kernel (VERDICT r4 next #1): in-kernel VMEM
     row gather must reproduce gather-then-histogram exactly (interpret
-    mode on CPU; the on-chip A/B rides tools/tpu_session.sh)."""
+    mode on CPU only: Mosaic refuses the in-kernel gather, PERF.md
+    "Bring-up on v5e")."""
 
     def test_fused_matches_gather_then_pallas(self):
         from mmlspark_tpu.ops.pallas_histogram import (

@@ -219,35 +219,16 @@ class TestExposition:
         counts = [int(ln.rsplit(" ", 1)[1]) for ln in rows]
         assert counts == sorted(counts), "buckets must be cumulative"
 
-    def test_registry_scrape_carries_profile_and_probe_families(self):
+    def test_registry_scrape_carries_profile_family(self):
         """The process-global registry renders the profiler provider
-        (registered at module import) and the ops compile-probe info
-        family (ISSUE 12 satellite)."""
-        import mmlspark_tpu.ops.pallas_histogram as ph
+        (registered at module import).  The compile-probe family is
+        gone with the probes: a refused kernel raises, so there is no
+        verdict to publish."""
+        import mmlspark_tpu.ops.pallas_histogram  # noqa: F401
         get_profiler()                        # ensure module imported
-        ph._COMPILE_CACHE[("cpu", "_test_probe_kernel")] = False
-        try:
-            text = telemetry.get_registry().render_prometheus()
-            assert "mmlspark_tpu_profile_enabled" in text
-            m = re.search(
-                r'mmlspark_tpu_compile_probe_ok\{backend="cpu",'
-                r'method="_test_probe_kernel"\} (\d)', text)
-            assert m, "probe verdict missing from the scrape"
-            assert m.group(1) == "0"          # downgrade is VISIBLE
-        finally:
-            ph._COMPILE_CACHE.pop(("cpu", "_test_probe_kernel"), None)
-
-    def test_probe_exposition_empty_before_any_probe(self):
-        import mmlspark_tpu.ops.pallas_histogram as ph
-        saved_cache = dict(ph._COMPILE_CACHE)
-        saved_fused = ph._FUSED_COMPILE_OK
-        ph._COMPILE_CACHE.clear()
-        ph._FUSED_COMPILE_OK = None
-        try:
-            assert ph.probe_exposition() == ""
-        finally:
-            ph._COMPILE_CACHE.update(saved_cache)
-            ph._FUSED_COMPILE_OK = saved_fused
+        text = telemetry.get_registry().render_prometheus()
+        assert "mmlspark_tpu_profile_enabled" in text
+        assert "compile_probe" not in text
 
 
 # ----------------------------------------------------------- engine wiring

@@ -965,29 +965,20 @@ class TestMetricFamilyDocGuard:
                      ("busy_scoring.score", 0.25)):
             cmon.stats.set_gauge(g, v)
         reg.register_exposition("capacity", cmon.render_prometheus)
-        # the ops compile-probe info family, rendered off a seeded
-        # cache the way ops/pallas_histogram publishes the real one,
-        # and the quantized-gradient resolution family (ISSUE 17),
+        # the quantized-gradient resolution family (ISSUE 17),
         # rendered off a seeded last_fit_info the way gbdt/engine
         # publishes the real one
-        import mmlspark_tpu.ops.pallas_histogram as ph
         from mmlspark_tpu.gbdt import engine as eng
-        seeded = dict(ph._COMPILE_CACHE)
-        ph._COMPILE_CACHE[("cpu", "_docguard_probe")] = True
         fit_info = dict(eng.last_fit_info)
         eng.last_fit_info.update(quantized_bits="16",
                                  quantized_max_code="10",
                                  quantized_wire="int16",
                                  quantized_downgrade="none")
         try:
-            reg.register_exposition("compile_probes",
-                                    ph.probe_exposition)
             reg.register_exposition("train_quantized",
                                     eng._quantized_exposition)
             text = reg.render_prometheus()
         finally:
-            ph._COMPILE_CACHE.clear()
-            ph._COMPILE_CACHE.update(seeded)
             eng.last_fit_info.clear()
             eng.last_fit_info.update(fit_info)
         families = set(re.findall(r"^# TYPE (\S+) \S+$", text,

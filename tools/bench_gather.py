@@ -40,11 +40,12 @@ def main():
     import jax
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     import jax.numpy as jnp
     import numpy as np
+    from mmlspark_tpu.core.backend import configure_compile_cache
     from mmlspark_tpu.ops.histogram import compute_histogram
+
+    configure_compile_cache()
 
     n, f, B, R = args.rows, args.features, args.bins, args.reps
     f4 = (f + 3) // 4

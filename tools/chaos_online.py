@@ -211,7 +211,7 @@ def make_refresh(ctx, monitor, rollout=None):
 _TRAINER_SRC = """
 import os, signal, sys
 sys.path.insert(0, {repo!r})
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # the parent may hold the chip
 import numpy as np
 root, phase = {root!r}, {phase!r}
 from mmlspark_tpu.core.telemetry import (configure_flight_recorder,

@@ -371,7 +371,7 @@ def scenario_faulty_canary(seed, verdicts):
 _KILL_CHILD_SRC = """
 import os, signal, sys
 sys.path.insert(0, {repo!r})
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # the parent may hold the chip
 from mmlspark_tpu.io.registry import ModelRegistry
 reg = ModelRegistry({root!r})
 phase = {phase!r}

@@ -26,8 +26,7 @@ def main():
     ap.add_argument("--steps", type=int, default=3)
     ap.add_argument("--out", default=None)
     ap.add_argument("--cpu", action="store_true",
-                    help="force the CPU backend via the live-config path "
-                         "(the env-var route hangs init in this image)")
+                    help="force the CPU backend")
     args = ap.parse_args()
 
     import jax
