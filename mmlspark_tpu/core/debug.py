@@ -9,8 +9,13 @@ program:
   engine: finite gradients/hessians after the objective, and bin indices
   inside the histogram range (XLA clamps/drops OOB indices *silently* —
   the memory-corruption analog a sanitizer exists to make loud).
-  ``debug_check`` is a no-op unless the program is checkified, so the
-  hot path pays nothing when debug mode is off.
+  The engine traces them only in debug mode
+  (``GrowerConfig.debug_checks``, a static argument of the boost
+  programs): a ``debug_check`` does nothing unless the program is
+  checkified, but it still lowers to a dead computation that carries
+  checkify's process-wide error number as a constant, so the HLO, and
+  with it the persistent compile cache's key, would differ on every
+  trace of the same program.
 
 Blanket ``nan_checks`` is deliberately NOT enabled: split finding masks
 empty-bin gain arithmetic with ``-inf``/``where``, so transient NaNs

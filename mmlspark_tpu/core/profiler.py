@@ -52,11 +52,13 @@ scoring burst under 3%.
 
 from __future__ import annotations
 
+import itertools
 import os
 import sys
 import threading
 import time
-from contextlib import contextmanager
+from collections import deque
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional, Tuple
 
 from .profiling import LatencyStats, StageStats
@@ -105,6 +107,11 @@ class Profiler:
     #: bounded journal ring from flooding with per-request spans);
     #: callers may force with ``journal=True``
     SPAN_JOURNAL_MS = 50.0
+    #: regions kept in memory (:meth:`spans`): a fit records about a
+    #: dozen, so the ring holds the last few hundred fits
+    SPAN_RING = 4096
+    #: newest regions carried by :meth:`snapshot`
+    SPAN_SNAPSHOT_TAIL = 64
 
     def __init__(self, enabled: Optional[bool] = None):
         if enabled is None:
@@ -115,6 +122,12 @@ class Profiler:
         self.stats = StageStats()
         self._timers: Dict[str, LatencyStats] = {}
         self._lock = threading.Lock()
+        #: closed regions, oldest first (bounded; see :meth:`region`)
+        self._spans: deque = deque(maxlen=self.SPAN_RING)
+        self._span_ids = itertools.count(1)
+        #: per-thread stack of open region ids: a region's parent is
+        #: the region that encloses it on the same thread
+        self._open = threading.local()
         #: jax.monitoring accumulation: short event name -> [n, total_s]
         self._jax_events: Dict[str, List[float]] = {}
         self._compile_seq = 0
@@ -174,17 +187,72 @@ class Profiler:
         self.timer(phase).record(seconds)
 
     @contextmanager
-    def phase(self, name: str):
-        """Scoped timer for call sites that don't already clock
-        themselves."""
+    def region(self, name: str, **attrs):
+        """A span around the wrapped block: one measurement, three
+        faces.
+
+        * an in-memory record ``{"id", "name", "start", "end",
+          "parent", "fit", "attrs"}`` on ``time.perf_counter()``'s
+          clock, appended to a bounded ring when the block ends
+          (:meth:`spans`).  ``parent`` is the id of the region that
+          encloses this one on the same thread (None at the top),
+          ``fit`` is :func:`~mmlspark_tpu.core.telemetry.
+          current_fit_span` at entry, the identifier every span of one
+          fit shares.  The ``with`` target is the record's ``attrs``
+          dict, so a caller adds what it learns inside the block
+          (``sp["bytes"] = ...``).  A region's self time is its
+          duration less the part its children cover.
+        * a ``jax.profiler.TraceAnnotation(name)`` for the block's
+          duration when jax is already imported (this module never
+          imports it), so the same span lies in the profiler's
+          ``.xplane.pb`` beside the device events, on their clock,
+          whenever anyone traces; with no trace running the annotation
+          is one atomic load.
+        * the phase histogram, as :meth:`record_phase` feeds it, so
+          ``/metrics``, ``tools/perf_report.py`` and flight records see
+          the phase with no exporter of their own.
+
+        Disabled: one attribute check, no annotation, no record."""
         if not self.enabled:
-            yield
+            yield attrs
             return
-        t0 = time.perf_counter()
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        jprof = getattr(sys.modules.get("jax"), "profiler", None)
+        note = (jprof.TraceAnnotation(name) if jprof is not None
+                else nullcontext())
+        rec = {"id": next(self._span_ids), "name": name,
+               "parent": stack[-1] if stack else None,
+               "fit": current_fit_span(), "attrs": attrs,
+               "start": time.perf_counter(), "end": None}
+        stack.append(rec["id"])
         try:
-            yield
+            with note:
+                yield attrs
         finally:
-            self.record_phase(name, time.perf_counter() - t0)
+            rec["end"] = time.perf_counter()
+            stack.pop()
+            with self._lock:
+                self._spans.append(rec)
+            self.record_phase(name, rec["end"] - rec["start"])
+
+    #: the older name: a region is the scoped timer ``phase`` was
+    phase = region
+
+    def spans(self) -> List[dict]:
+        """A copy of the ring of closed regions, oldest first."""
+        with self._lock:
+            return list(self._spans)
+
+    def jax_seconds(self, event: str) -> float:
+        """Cumulative seconds the ``jax.monitoring`` listener has seen
+        for ``event`` (short name: ``jaxpr_trace``, ``backend_compile``);
+        a region reads it before and after to learn how much of itself
+        was tracing or compiling."""
+        with self._lock:
+            ent = self._jax_events.get(event)
+            return float(ent[1]) if ent else 0.0
 
     def span(self, name: str, seconds: float, journal: bool = False,
              record: bool = True, **ids) -> None:
@@ -388,9 +456,11 @@ class Profiler:
     def snapshot(self, top_stacks: int = 50) -> dict:
         """JSON-able profile block: phases (StageStats shape — merge
         with ``telemetry.merge_snapshots``), the compile/dispatch
-        ledger, jax event accumulations, memory watermarks, and the
-        sampler's top collapsed stacks.  Embedded in flight records and
-        bench artifacts; ``tools/perf_report.py`` consumes it."""
+        ledger, jax event accumulations, memory watermarks, the newest
+        regions (:meth:`region`; their clock is this process's
+        ``perf_counter``) and the sampler's top collapsed stacks.
+        Embedded in flight records and bench artifacts;
+        ``tools/perf_report.py`` consumes it."""
         self.sample_memory()
         with self._lock:
             jax_events = {k: {"count": int(v[0]),
@@ -399,6 +469,7 @@ class Profiler:
             dispatch = {k: dict(v) for k, v in self._dispatch.items()}
             mem = {f"{d}/{k}": v for (d, k), v in self._mem.items()}
             samples = self._samples
+            spans = list(self._spans)[-self.SPAN_SNAPSHOT_TAIL:]
         return {
             "enabled": self.enabled,
             "phases": self.stats.snapshot(),
@@ -406,6 +477,7 @@ class Profiler:
             "compile_seq": self._compile_seq,
             "dispatch": dispatch,
             "memory_bytes": mem,
+            "spans": spans,
             "sampler": {"samples": samples,
                         "stacks": self.flamegraph_lines(top_stacks)},
         }

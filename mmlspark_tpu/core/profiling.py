@@ -8,14 +8,11 @@ in-package capability rather than a side tool:
 
 * :func:`trace` — context manager; wrap any region to capture a device
   trace into a directory.
-* :func:`summarize_trace` — parse the written trace (no TensorBoard
-  needed) into per-op device-time totals, the same aggregation
-  ``tools/profile_boost_step.py`` prints.
+* :func:`summarize_trace` — parse the written ``.xplane.pb`` (no
+  TensorBoard needed) into device self time by the program's named
+  scopes; ``tools/profile_boost_step.py`` prints it.
 * ``LightGBMBase.setProfileTraceDir(dir)`` — traces the whole ``fit``
   (engine hooks through :func:`maybe_trace`).
-
-The committed evidence chain in PERF.md (129 → 87 ms/tree) was produced
-with exactly these aggregations.
 
 Serving adds a second, host-side need: per-stage wall-clock counters for
 the scoring hot path (queue wait / decode / score / reply), cheap enough
@@ -33,10 +30,9 @@ report rows/s and p50/p99 without a profiler attached.
 from __future__ import annotations
 
 import glob
-import gzip
-import json
 import math
 import os
+import re
 import threading
 import time
 from bisect import bisect_left
@@ -338,42 +334,187 @@ def maybe_trace(out_dir: Optional[str]):
         yield
 
 
+#: components of an op's ``op_name`` path that jax's transforms and
+#: control flow put there; what is left is what ``jax.named_scope`` named
+_STRUCTURAL = re.compile(
+    r"^(?:\w+\(.*\)|while|body|cond|scan|closed_call|core_call|"
+    r"shard_map|branch_\d+_fun|.*<locals>.*|.*->.*)$")
+#: the stat of an "XLA Ops" event's METADATA that carries the op's
+#: ``op_name`` path on the TPU (jax 0.9 / libtpu 0.0.34; PERF.md §3)
+_SCOPE_STAT = "tf_op"
+
+
+def scope_of(op_name: str) -> str:
+    """The named scopes of an ``op_name`` path, outermost first:
+    ``jit(f)/while/body/closed_call/root_hist/reduce/psum:`` →
+    ``root_hist/reduce``; an op under no scope is named by its program
+    (``jit(f)``)."""
+    parts = op_name.rstrip(":").split("/")
+    named = [p for p in parts[:-1] if not _STRUCTURAL.match(p)]
+    return "/".join(named) if named else parts[0]
+
+
+def _varint(buf, i):
+    """``(value, next index)`` of the protobuf varint at ``buf[i]``."""
+    val = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        val |= (b & 0x7F) << shift
+        shift += 7
+        if b < 0x80:
+            return val, i
+
+
+def _fields(buf):
+    """``(field, value)`` of one protobuf message: varints as ints,
+    everything else as memoryviews (not copied, so skipping a plane's
+    lines costs nothing)."""
+    buf = memoryview(buf)
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        field, wire = key >> 3, key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+            yield field, val
+            continue
+        if wire == 2:
+            size, i = _varint(buf, i)
+        elif wire in (1, 5):
+            size = 8 if wire == 1 else 4
+        else:
+            raise ValueError(f"wire type {wire} in an xplane")
+        yield field, buf[i:i + size]
+        i += size
+
+
+def _op_name_paths(path: str) -> Dict[str, Dict[str, str]]:
+    """``{plane: {event name: op_name path}}``: the ``tf_op`` stat that
+    an xplane keeps on its EVENT METADATA.  ``jax.profiler.ProfileData``
+    hands out an event's own stats only, and the TPU's "XLA Ops" events
+    carry the path on the metadata, so this reads the XSpace wire format
+    directly (XPlane: name=2, event_metadata=4, stat_metadata=5;
+    XEventMetadata: name=2, stats=5; XStat: metadata_id=1, str_value=5,
+    ref_value=7), skipping every line."""
+    with open(path, "rb") as fh:
+        space = fh.read()
+    out: Dict[str, Dict[str, str]] = {}
+    for f, plane in _fields(space):
+        if f != 1:
+            continue
+        name, stat_names, events = "", {}, []
+        for pf, val in _fields(plane):
+            if pf == 2:
+                name = bytes(val).decode()
+            elif pf in (4, 5):      # map entries: key=1, value=2
+                entry = dict(_fields(val))
+                if 2 not in entry:
+                    continue
+                if pf == 4:
+                    events.append(entry[2])
+                else:
+                    stat_names[entry.get(1)] = bytes(
+                        dict(_fields(entry[2])).get(2, b"")).decode()
+        wanted = {k for k, v in stat_names.items() if v == _SCOPE_STAT}
+        found = {}
+        for event in events:
+            ev_name, value = "", None
+            for ef, ev in _fields(event):
+                if ef == 2:
+                    ev_name = bytes(ev).decode()
+                elif ef == 5:
+                    st = dict(_fields(ev))
+                    if st.get(1) in wanted:
+                        value = (bytes(st[5]).decode() if 5 in st
+                                 else stat_names.get(st.get(7)))
+            if value:
+                found[ev_name] = value
+        if found:
+            out[name] = found
+    return out
+
+
+def _self_times(events) -> Dict[str, float]:
+    """Seconds by name over ``(name, start, duration)`` events of one
+    line, each event's time less the time of the events nested inside it
+    (a ``while`` spans its body's ops)."""
+    stack: List[list] = []      # [name, end, self]
+    totals: Dict[str, float] = defaultdict(float)
+
+    def close(upto):
+        while stack and stack[-1][1] <= upto:
+            name, _, own = stack.pop()
+            totals[name] += max(own, 0.0)
+
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        close(start)
+        if stack:
+            stack[-1][2] -= dur
+        stack.append([name, start + dur, dur])
+    close(float("inf"))
+    return totals
+
+
 def summarize_trace(out_dir: str, top: int = 25
                     ) -> List[Tuple[float, str]]:
-    """Aggregate device-op durations from the newest perfetto JSON export
-    under ``out_dir``.  Returns ``[(total_ms, op_name), ...]`` sorted
+    """Device self time by named scope, from the newest ``.xplane.pb``
+    under ``out_dir``.  Returns ``[(total_ms, name), ...]`` sorted
     descending, with one trailing ``(total_device_ms,
-    "total_device_ms")`` summary row (the whole-trace device time —
-    what the committed PERF.md evidence compares across runs); empty
-    when no trace file exists.
+    "total_device_ms")`` summary row (the device-busy time summed over
+    the trace's devices); empty when no trace file exists.
+
+    Events come from ``jax.profiler.ProfileData``: the "XLA Ops" line of
+    every ``/device:`` plane.  An event's time is its SELF time (a
+    ``while`` and its body are not counted twice), and its name is
+    :func:`scope_of` its ``op_name`` path, which the program's
+    ``jax.named_scope`` calls feed: ``root_hist``, ``partition``,
+    ``root_hist/reduce``...  An op with no path (the ``while`` itself, a
+    parameter copy) keeps its HLO name without the number.  A trace with
+    no device plane (the CPU backend) is summarized from the host
+    threads' HLO-op events, by op.
 
     "Newest" is by mtime: the profiler names exports by timestamp
     strings whose lexicographic order diverges from chronology across
     hosts/sessions (and a re-run into the same dir must win)."""
-    paths = glob.glob(os.path.join(out_dir, "**", "*.trace.json.gz"),
+    paths = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if not paths:
         return []
     newest = max(paths, key=lambda p: (os.path.getmtime(p), p))
-    with gzip.open(newest, "rt") as fh:
-        data = json.load(fh)
-    events = data.get("traceEvents", [])
-    agg: Dict[Tuple[int, str], float] = defaultdict(float)
-    for e in events:
-        if e.get("ph") == "X" and "dur" in e:
-            agg[(e.get("pid", 0), e.get("name", "?"))] += e["dur"]
-    pid_names = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            pid_names[e.get("pid")] = e.get("args", {}).get("name", "")
-    dev_pids = [p for p, nm in pid_names.items()
-                if "TPU" in nm or "Device" in nm or "/device" in nm]
-    if not dev_pids:
-        by_pid: Dict[int, float] = defaultdict(float)
-        for (pid, _), d in agg.items():
-            by_pid[pid] += d
-        dev_pids = [max(by_pid, key=by_pid.get)] if by_pid else []
-    rows = sorted(((d / 1e3, name) for (pid, name), d in agg.items()
-                   if pid in dev_pids), reverse=True)
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(newest)
+    op_names = _op_name_paths(newest)
+    device_lines, host_lines = [], []
+    for plane in data.planes:
+        if plane.name.startswith("/device:"):
+            scopes = op_names.get(plane.name, {})
+            for line in plane.lines:
+                if line.name != "XLA Ops":
+                    continue
+                device_lines.append([
+                    (scope_of(scopes[e.name]) if e.name in scopes
+                     else _hlo_base(e.name),
+                     e.start_ns / 1e9, e.duration_ns / 1e9)
+                    for e in line.events])
+        elif not device_lines:
+            for line in plane.lines:
+                host_lines.append([
+                    (_hlo_base(e.name), e.start_ns / 1e9,
+                     e.duration_ns / 1e9) for e in line.events
+                    if any(k == "hlo_op" for k, _ in e.stats)])
+    agg: Dict[str, float] = defaultdict(float)
+    for events in device_lines or host_lines:
+        for name, secs in _self_times(events).items():
+            agg[name] += secs
+    rows = sorted(((s * 1e3, name) for name, s in agg.items()),
+                  reverse=True)
     total_ms = round(sum(ms for ms, _ in rows), 3)
     return rows[:top] + [(total_ms, "total_device_ms")]
+
+
+def _hlo_base(name: str) -> str:
+    """``%copy.470 = f32[...] copy(...)`` → ``copy``: an HLO op's name
+    without its number, for ops that no scope names."""
+    head = name.split(" = ", 1)[0].lstrip("%")
+    return re.sub(r"(?:\.\d+|\.clone)+$", "", head) or head

@@ -37,6 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.mesh import DATA_AXIS, FEATURE_AXIS
+from ..core.profiler import get_profiler
 from .grower import (GrowerConfig, TreeArrays, _grow_tree_impl,
                      apply_shrinkage, predict_tree_binned,
                      predict_tree_binned_fshard)
@@ -44,6 +45,23 @@ from .objectives import Objective
 
 
 VALID_PARALLELISM = ("serial", "data", "feature", "data+feature", "voting")
+
+#: the fit's ``train.build_step`` span (docs/observability.md): building
+#: the ``jit(shard_map(...))`` of a mesh fit.  The program is traced and
+#: compiled at its first call, which is ``train.launch``.
+_build_step = get_profiler().region("train.build_step")
+
+
+def _upload(prepare):
+    """``prepare`` under the fit's ``train.upload`` span, with the bytes
+    of the global arrays it put on the mesh."""
+    @functools.wraps(prepare)
+    def wrapped(*args, **kwargs):
+        with get_profiler().region("train.upload") as sp:
+            out = prepare(*args, **kwargs)
+            sp["bytes"] = int(sum(a.nbytes for a in out[:5]))
+        return out
+    return wrapped
 
 
 def resolve_mesh(parallelism: str, mesh: Optional[Mesh] = None) -> Mesh:
@@ -108,6 +126,7 @@ def _sharded_cfg(mesh: Mesh, cfg: GrowerConfig) -> GrowerConfig:
     })
 
 
+@_build_step
 def make_goss_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
                    k1: int, k2: int, amp: float, has_val: bool = False,
                    num_class: int = 1):
@@ -141,7 +160,8 @@ def make_goss_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
             if cfg.axis_name is not None:
                 key = jax.random.fold_in(
                     key, jax.lax.axis_index(cfg.axis_name))
-            g, h = obj.grad_hess(scores, labels, weights)
+            with jax.named_scope("gradients"):
+                g, h = obj.grad_hess(scores, labels, weights)
             g = g * (real if K == 1 else real[:, None])
             h = h * (real if K == 1 else real[:, None])
             n_local = g.shape[0]
@@ -162,7 +182,8 @@ def make_goss_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
                                 jnp.take(h, idx) * amp_vec,
                                 valid], axis=1)
                 tree, _ = _grow_tree_impl(bins_g, gh, fi, cfg)
-                scores = scores + lr * tree_pred(tree, bins)
+                with jax.named_scope("score_update"):
+                    scores = scores + lr * tree_pred(tree, bins)
                 trees = apply_shrinkage(tree, lr)
                 if has_val:
                     val_scores = val_scores + predict_tree_binned(
@@ -174,8 +195,9 @@ def make_goss_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
                                     jnp.take(h[:, k], idx) * amp_vec,
                                     valid], axis=1)
                     tree, _ = _grow_tree_impl(bins_g, gh, fi, cfg)
-                    scores = scores.at[:, k].add(
-                        lr * tree_pred(tree, bins))
+                    with jax.named_scope("score_update"):
+                        scores = scores.at[:, k].add(
+                            lr * tree_pred(tree, bins))
                     tree = apply_shrinkage(tree, lr)
                     if has_val:
                         val_scores = val_scores.at[:, k].add(
@@ -215,6 +237,7 @@ def make_goss_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
     return jax.jit(mapped, donate_argnums=(1, 8))
 
 
+@_build_step
 def make_boost_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
                     bag_sharded: bool, has_val: bool = False,
                     rf: bool = False, efb=None):
@@ -253,7 +276,8 @@ def make_boost_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
             scores, val_scores = carry
             bag, fi = xs
             bag = jnp.broadcast_to(bag, scores.shape) * real
-            g, h = obj.grad_hess(scores, labels, weights)
+            with jax.named_scope("gradients"):
+                g, h = obj.grad_hess(scores, labels, weights)
             gh = jnp.stack([g * bag, h * bag, bag], axis=1)
             # efb rides the closure: the (f, B)-sized maps replicate as
             # baked constants; per-feature expansion happens SHARD-LOCAL
@@ -261,7 +285,8 @@ def make_boost_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
             tree, row_leaf = _grow_tree_impl(bins, gh, fi, cfg, efb,
                                              binsT=binsT)
             if not rf:
-                scores = scores + lr * tree.leaf_value[row_leaf]
+                with jax.named_scope("score_update"):
+                    scores = scores + lr * tree.leaf_value[row_leaf]
                 tree = apply_shrinkage(tree, lr)
             if has_val:
                 val_scores = val_scores + predict_tree_binned(
@@ -289,6 +314,7 @@ def make_boost_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig, lr: float,
     return jax.jit(mapped, donate_argnums=(1, 8))
 
 
+@_build_step
 def make_multiclass_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig,
                          lr: float, num_class: int, bag_sharded: bool,
                          has_val: bool = False, efb=None,
@@ -310,15 +336,17 @@ def make_multiclass_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig,
             scores, val_scores = carry
             bag, fi = xs
             bag = jnp.broadcast_to(bag, (scores.shape[0],)) * real
-            g, h = obj.grad_hess(scores, labels, weights)
+            with jax.named_scope("gradients"):
+                g, h = obj.grad_hess(scores, labels, weights)
             trees_k = []
             for k in range(K):
                 gh = jnp.stack([g[:, k] * bag, h[:, k] * bag, bag], axis=1)
                 tree, row_leaf = _grow_tree_impl(bins, gh, fi, cfg, efb,
                                                  binsT=binsT)
                 if not rf:
-                    scores = scores.at[:, k].add(
-                        lr * tree.leaf_value[row_leaf])
+                    with jax.named_scope("score_update"):
+                        scores = scores.at[:, k].add(
+                            lr * tree.leaf_value[row_leaf])
                     tree = apply_shrinkage(tree, lr)
                 if has_val:
                     val_scores = val_scores.at[:, k].add(
@@ -351,6 +379,7 @@ def make_multiclass_scan(mesh: Mesh, obj: Objective, cfg: GrowerConfig,
     return jax.jit(mapped, donate_argnums=(1, 8))
 
 
+@_build_step
 def make_ranking_dart_step(mesh: Mesh, cfg: GrowerConfig, lr: float,
                            sigma: float, trunc: int):
     """One dart iteration for MESH LAMBDARANK: pairwise ΔNDCG gradients
@@ -387,6 +416,7 @@ def make_ranking_dart_step(mesh: Mesh, cfg: GrowerConfig, lr: float,
     return jax.jit(mapped)
 
 
+@_build_step
 def make_dart_step(mesh: Mesh, obj: Objective, cfg: GrowerConfig,
                    lr: float, num_class: int = 1):
     """One dart iteration over the mesh: fit a tree to the gradient at
@@ -402,7 +432,8 @@ def make_dart_step(mesh: Mesh, obj: Objective, cfg: GrowerConfig,
     K = num_class
 
     def step(bins, binsT, s_minus, labels, weights, bag, fi):
-        g, h = obj.grad_hess(s_minus, labels, weights)
+        with jax.named_scope("gradients"):
+            g, h = obj.grad_hess(s_minus, labels, weights)
         if K == 1:
             gh = jnp.stack([g * bag, h * bag, bag], axis=1)
             tree, row_leaf = _grow_tree_impl(bins, gh, fi, cfg,
@@ -473,6 +504,7 @@ def make_tree_predict(mesh: Mesh, num_leaves: int, num_class: int = 1):
     return jax.jit(mapped)
 
 
+@_build_step
 def make_ranking_scan(mesh: Mesh, cfg: GrowerConfig, lr: float,
                       sigma: float, trunc: int, has_val: bool = False,
                       goss=None, bag_sharded: bool = False,
@@ -529,7 +561,8 @@ def make_ranking_scan(mesh: Mesh, cfg: GrowerConfig, lr: float,
                 tree, row_leaf = _grow_tree_impl(bins, gh, fi, cfg,
                                                  binsT=binsT)
                 if not rf:
-                    scores = scores + lr * tree.leaf_value[row_leaf]
+                    with jax.named_scope("score_update"):
+                        scores = scores + lr * tree.leaf_value[row_leaf]
             else:
                 k1, k2, amp = goss
                 if cfg.axis_name is not None:
@@ -583,6 +616,7 @@ def make_ranking_scan(mesh: Mesh, cfg: GrowerConfig, lr: float,
     return jax.jit(mapped, donate_argnums=(1, 13))
 
 
+@_upload
 def prepare_arrays_from_shards(bins_shards, label_shards, weight_shards,
                                mesh: Mesh, num_class: int, init: float,
                                bin_dtype, shard_rows=None,
@@ -697,6 +731,7 @@ def prepare_arrays_from_shards(bins_shards, label_shards, weight_shards,
     return bins_d, lab_d, w_d, real_d, scores, rp, f_padded - f
 
 
+@_upload
 def prepare_arrays(bins: np.ndarray, labels: np.ndarray, weights: np.ndarray,
                    mesh: Mesh, num_class: int, init: float,
                    init_scores: Optional[np.ndarray] = None

@@ -328,10 +328,12 @@ class TrainParams:
 def _boost_step(bins, scores, labels, weights, bag_mask, feat_info,
                 obj: Objective, cfg: GrowerConfig, lr: float):
     """One boosting iteration for a single tree (single-class)."""
-    g, h = obj.grad_hess(scores, labels, weights)
+    with jax.named_scope("gradients"):
+        g, h = obj.grad_hess(scores, labels, weights)
     gh = jnp.stack([g * bag_mask, h * bag_mask, bag_mask], axis=1)
     tree, row_leaf = _grow_tree_impl(bins, gh, feat_info, cfg)
-    scores = scores + lr * tree.leaf_value[row_leaf]
+    with jax.named_scope("score_update"):
+        scores = scores + lr * tree.leaf_value[row_leaf]
     tree = apply_shrinkage(tree, lr)
     return tree, scores
 
@@ -443,6 +445,46 @@ def _ckpt_event(name: str, **fields) -> None:
     _tm.get_journal().emit(name, fit=_tm.current_fit_span(), **fields)
 
 
+def _dispatch_chunk(run, scores, val_scores, it: int, trees: int,
+                    t_chunk: float, **ids):
+    """One bracketed chunk dispatch, for the serial and the mesh loop
+    alike: ``run(scores, val_scores)`` under ``train.launch`` (tracing,
+    compile or cache look-up, enqueue; its attrs say how much of it was
+    which, from the ``jax.monitoring`` sums the profiler keeps), then
+    ``jax.block_until_ready`` on the chunk's trees under
+    ``train.device_wait``.  The sync is for honest chunk timing; the
+    host needs these results before the next chunk (or the final fetch)
+    anyway, so it moves a wait, it does not add one.
+
+    Also feeds the ``train.boost_chunk.*`` dispatch ledger (host glue
+    since ``t_chunk`` against device wait, the ``compile_seq`` delta
+    classifying the dispatch as cache hit or miss) and journals the
+    ``profile_span`` that ``tools/trace_report.py`` lays on the fit's
+    timeline (ISSUE 12)."""
+    p = get_profiler()
+    seq0 = p.compile_seq()
+    traced0 = p.jax_seconds("jaxpr_trace")
+    compiled0 = p.jax_seconds("backend_compile")
+    with p.region("train.launch") as sp:
+        out = run(scores, val_scores)
+        misses = p.compile_seq() - seq0
+        sp.update(
+            compile_misses=misses,
+            jaxpr_trace_s=round(p.jax_seconds("jaxpr_trace") - traced0, 6),
+            backend_compile_s=round(
+                p.jax_seconds("backend_compile") - compiled0, 6))
+    t_host = time.perf_counter()
+    with p.region("train.device_wait", it=int(it), trees=int(trees)):
+        jax.block_until_ready(out[0])
+    t_done = time.perf_counter()
+    p.dispatch("train.boost_chunk", t_host - t_chunk, t_done - t_host,
+               misses)
+    p.span("train.boost_chunk", t_done - t_chunk, journal=True,
+           it=int(it), host_ms=round((t_host - t_chunk) * 1e3, 3),
+           device_ms=round((t_done - t_host) * 1e3, 3), **ids)
+    return out
+
+
 #: cap on rows fetched to the host per chunk boundary for the telemetry
 #: train-loss gauge; larger fits are sampled with a stride (a gauge
 #: needs a stable estimate, not the exact sum)
@@ -473,49 +515,55 @@ def _monitor_chunk(it0: int, it1: int, dt_s: float, n_rows: int, K: int,
     (grower.collective_schedule) — scaled by the chunk's tree count into
     the ``collective_count``/``collective_payload_bytes`` counters and
     journaled on the ``boost_chunk`` event, so the payload a wide-data
-    voting fit saves is machine-checkable on /metrics (ISSUE 16)."""
-    iters = max(1, it1 - it0)
-    trees = iters * max(1, K)
-    ms_per_tree = dt_s * 1e3 / trees
-    rows_per_s = n_rows * iters / dt_s if dt_s > 0 else 0.0
-    train_stats.set_gauge("ms_per_tree", round(ms_per_tree, 3))
-    train_stats.set_gauge("train_rows_per_s", round(rows_per_s, 1))
-    train_stats.set_gauge("last_iteration", float(it1))
-    train_stats.incr("boost_chunks")
-    coll_count = coll_bytes = None
-    if coll_sched is not None:
-        coll_count = coll_sched["count"] * trees
-        coll_bytes = coll_sched["payload_bytes"] * trees
-        train_stats.incr("collective_count", coll_count)
-        train_stats.incr("collective_payload_bytes", coll_bytes)
-    loss = None
-    if (objective is not None and scores is not None
-            and labels is not None
-            and getattr(scores, "is_fully_addressable", True)):
-        try:
-            labels_np = np.asarray(labels)
-            stride = max(1, len(labels_np) // _MONITOR_LOSS_MAX_ROWS)
-            if stride > 1:
-                scores = scores[::stride]    # device-side slice: the
-                labels_np = labels_np[::stride]   # D2H stays bounded
-                weights = (None if weights is None
-                           else np.asarray(weights)[::stride])
-            loss = objective.train_loss(np.asarray(scores), labels_np,
-                                        weights)
-        except Exception:  # noqa: BLE001 - telemetry must never kill
-            loss = None    # the fit it observes
-    if loss is not None:
-        train_stats.set_gauge("train_loss", round(float(loss), 6))
-    ev = {"fit": _tm.current_fit_span(), "it_start": int(it0),
-          "it_end": int(it1), "ms_per_tree": round(ms_per_tree, 3),
-          "rows_per_s": round(rows_per_s, 1),
-          "hist_method": hist_method, "collective": collective}
-    if coll_count is not None:
-        ev["collective_count"] = int(coll_count)
-        ev["collective_payload_bytes"] = int(coll_bytes)
-    if loss is not None:
-        ev["train_loss"] = round(float(loss), 6)
-    _tm.get_journal().emit("boost_chunk", **ev)
+    voting fit saves is machine-checkable on /metrics (ISSUE 16).
+
+    The whole of it is the fit's ``train.monitor`` span: telemetry's own
+    cost inside the fit, ``loss_rows`` being the rows the loss gauge
+    fetched."""
+    with get_profiler().region("train.monitor", loss_rows=0) as sp:
+        iters = max(1, it1 - it0)
+        trees = iters * max(1, K)
+        ms_per_tree = dt_s * 1e3 / trees
+        rows_per_s = n_rows * iters / dt_s if dt_s > 0 else 0.0
+        train_stats.set_gauge("ms_per_tree", round(ms_per_tree, 3))
+        train_stats.set_gauge("train_rows_per_s", round(rows_per_s, 1))
+        train_stats.set_gauge("last_iteration", float(it1))
+        train_stats.incr("boost_chunks")
+        coll_count = coll_bytes = None
+        if coll_sched is not None:
+            coll_count = coll_sched["count"] * trees
+            coll_bytes = coll_sched["payload_bytes"] * trees
+            train_stats.incr("collective_count", coll_count)
+            train_stats.incr("collective_payload_bytes", coll_bytes)
+        loss = None
+        if (objective is not None and scores is not None
+                and labels is not None
+                and getattr(scores, "is_fully_addressable", True)):
+            try:
+                labels_np = np.asarray(labels)
+                stride = max(1, len(labels_np) // _MONITOR_LOSS_MAX_ROWS)
+                if stride > 1:
+                    scores = scores[::stride]    # device-side slice: the
+                    labels_np = labels_np[::stride]   # D2H stays bounded
+                    weights = (None if weights is None
+                               else np.asarray(weights)[::stride])
+                sp["loss_rows"] = int(len(labels_np))
+                loss = objective.train_loss(np.asarray(scores), labels_np,
+                                            weights)
+            except Exception:  # noqa: BLE001 - telemetry must never kill
+                loss = None    # the fit it observes
+        if loss is not None:
+            train_stats.set_gauge("train_loss", round(float(loss), 6))
+        ev = {"fit": _tm.current_fit_span(), "it_start": int(it0),
+              "it_end": int(it1), "ms_per_tree": round(ms_per_tree, 3),
+              "rows_per_s": round(rows_per_s, 1),
+              "hist_method": hist_method, "collective": collective}
+        if coll_count is not None:
+            ev["collective_count"] = int(coll_count)
+            ev["collective_payload_bytes"] = int(coll_bytes)
+        if loss is not None:
+            ev["train_loss"] = round(float(loss), 6)
+        _tm.get_journal().emit("boost_chunk", **ev)
 
 
 def _ckpt_glob(template: str) -> str:
@@ -1036,14 +1084,16 @@ def _boost_scan(bins, scores, labels, weights, bag_masks, fi_stack,
         scores, val_scores = carry
         bag, fi = xs
         bag = jnp.broadcast_to(bag, scores.shape)
-        g, h = obj.grad_hess(scores, labels, weights)
+        with jax.named_scope("gradients"):
+            g, h = obj.grad_hess(scores, labels, weights)
         gh = jnp.stack([g * bag, h * bag, bag], axis=1)
         tree, row_leaf = _grow_tree_impl(bins, gh, fi, cfg, efb,
                                          binsT=binsT)
         if not rf:
             # rf (random forest): every tree fits the gradient at the
             # CONSTANT init scores, unshrunk; averaging happens at export
-            scores = scores + lr * tree.leaf_value[row_leaf]
+            with jax.named_scope("score_update"):
+                scores = scores + lr * tree.leaf_value[row_leaf]
             tree = apply_shrinkage(tree, lr)
         if has_val:
             val_scores = val_scores + predict_tree_binned(
@@ -1137,7 +1187,8 @@ def _dart_step(bins, binsT, s_minus, labels, weights, bag, fi,
     K class trees of an iteration share one weight — so the step grows K
     trees at the shared dropped-out scores and returns them stacked
     (K, ...) with a (n, K) contribution."""
-    g, h = obj.grad_hess(s_minus, labels, weights)
+    with jax.named_scope("gradients"):
+        g, h = obj.grad_hess(s_minus, labels, weights)
     if K == 1:
         gh = jnp.stack([g * bag, h * bag, bag], axis=1)
         tree, row_leaf = _grow_tree_impl(bins, gh, fi, cfg, efb,
@@ -1190,7 +1241,8 @@ def _boost_scan_goss(bins, scores, labels, weights, keys, fi_stack,
     # SAMPLE, but predict_tree_binned walks the FULL matrix every
     # iteration, and the argsort pushes NaN rows to the sample's tail —
     # so both invariants must look at the unsampled inputs here
-    _debug.check_bins_in_range(bins, cfg.num_bins)
+    if cfg.debug_checks:
+        _debug.check_bins_in_range(bins, cfg.num_bins)
 
     def train_pred(tree):
         # scores update walks the TRAINING matrix; under EFB it holds
@@ -1202,8 +1254,10 @@ def _boost_scan_goss(bins, scores, labels, weights, keys, fi_stack,
     def body(carry, xs):
         scores, val_scores = carry
         key, fi = xs
-        g, h = obj.grad_hess(scores, labels, weights)
-        _debug.check_finite("gradients/hessians", g, h)
+        with jax.named_scope("gradients"):
+            g, h = obj.grad_hess(scores, labels, weights)
+        if cfg.debug_checks:
+            _debug.check_finite("gradients/hessians", g, h)
         n = g.shape[0]
         infl = (jnp.abs(g * h) if K == 1
                 else jnp.sum(jnp.abs(g * h), axis=1))
@@ -1221,7 +1275,8 @@ def _boost_scan_goss(bins, scores, labels, weights, keys, fi_stack,
                             jnp.take(h, idx) * amp_vec,
                             jnp.ones(k1 + k2, jnp.float32)], axis=1)
             tree, _ = _grow_tree_impl(bins_g, gh, fi, cfg, efb)
-            scores = scores + lr * train_pred(tree)
+            with jax.named_scope("score_update"):
+                scores = scores + lr * train_pred(tree)
             trees = apply_shrinkage(tree, lr)
             if has_val:
                 val_scores = val_scores + predict_tree_binned(
@@ -1233,7 +1288,8 @@ def _boost_scan_goss(bins, scores, labels, weights, keys, fi_stack,
                                 jnp.take(h[:, k], idx) * amp_vec,
                                 jnp.ones(k1 + k2, jnp.float32)], axis=1)
                 tree, _ = _grow_tree_impl(bins_g, gh, fi, cfg, efb)
-                scores = scores.at[:, k].add(lr * train_pred(tree))
+                with jax.named_scope("score_update"):
+                    scores = scores.at[:, k].add(lr * train_pred(tree))
                 tree = apply_shrinkage(tree, lr)
                 if has_val:
                     val_scores = val_scores.at[:, k].add(
@@ -1274,15 +1330,17 @@ def _boost_scan_multi(bins, scores, labels, weights, bag_masks, fi_stack,
         scores, val_scores = carry
         bag, fi = xs
         bag = jnp.broadcast_to(bag, (scores.shape[0],))
-        g, h = obj.grad_hess(scores, labels, weights)
+        with jax.named_scope("gradients"):
+            g, h = obj.grad_hess(scores, labels, weights)
         trees_k = []
         for k in range(K):
             gh = jnp.stack([g[:, k] * bag, h[:, k] * bag, bag], axis=1)
             tree, row_leaf = _grow_tree_impl(bins, gh, fi, cfg, efb,
                                              binsT=binsT)
             if not rf:
-                scores = scores.at[:, k].add(
-                    lr * tree.leaf_value[row_leaf])
+                with jax.named_scope("score_update"):
+                    scores = scores.at[:, k].add(
+                        lr * tree.leaf_value[row_leaf])
                 tree = apply_shrinkage(tree, lr)
             if has_val:
                 val_scores = val_scores.at[:, k].add(predict_tree_binned(
@@ -1334,32 +1392,35 @@ def _fetch_host_trees(chunks: List[TreeArrays], num_leaves: int,
 
     ``chunks``: stacked (C_i, ...) TreeArrays pytrees as produced by the
     scan steps — one packed transfer per chunk (typically one per fit)."""
-    if not chunks:
-        return [], np.zeros(0, np.int64)
-    packed = np.concatenate(
-        [np.asarray(_pack_trees_stacked(c)) for c in chunks])
-    L, m = num_leaves, num_leaves - 1
-    W = chunks[0].node_cat_bits.shape[-1]
-    offs = np.cumsum([1] + [m] * 9 + [L] * 3 + [m * W] * 2)
-    cols = [packed[:, a:b] for a, b in zip([0] + list(offs), offs)]
-    nls = cols[0][:, 0].astype(np.int64)
-    out = []
-    for i in range(packed.shape[0]):
-        bits = (cols[13][i].astype(np.uint32)
-                | (cols[14][i].astype(np.uint32) << np.uint32(16)))
-        tree = TreeArrays(
-            node_feat=cols[1][i].astype(np.int32),
-            node_bin=cols[2][i].astype(np.int32),
-            node_left=cols[3][i].astype(np.int32),
-            node_right=cols[4][i].astype(np.int32),
-            node_gain=cols[5][i], node_value=cols[6][i],
-            node_weight=cols[7][i], node_count=cols[8][i],
-            node_is_cat=cols[9][i].astype(np.int32),
-            node_cat_bits=bits.reshape(m, W),
-            leaf_value=cols[10][i], leaf_weight=cols[11][i],
-            leaf_count=cols[12][i], num_leaves=nls[i])
-        out.append(host_tree_from_arrays(tree, mapper, mapper.missing_bin))
-    return out, nls
+    with get_profiler().region("train.fetch_trees", bytes=0) as sp:
+        if not chunks:
+            return [], np.zeros(0, np.int64)
+        packed = np.concatenate(
+            [np.asarray(_pack_trees_stacked(c)) for c in chunks])
+        sp["bytes"] = int(packed.nbytes)
+        L, m = num_leaves, num_leaves - 1
+        W = chunks[0].node_cat_bits.shape[-1]
+        offs = np.cumsum([1] + [m] * 9 + [L] * 3 + [m * W] * 2)
+        cols = [packed[:, a:b] for a, b in zip([0] + list(offs), offs)]
+        nls = cols[0][:, 0].astype(np.int64)
+        out = []
+        for i in range(packed.shape[0]):
+            bits = (cols[13][i].astype(np.uint32)
+                    | (cols[14][i].astype(np.uint32) << np.uint32(16)))
+            tree = TreeArrays(
+                node_feat=cols[1][i].astype(np.int32),
+                node_bin=cols[2][i].astype(np.int32),
+                node_left=cols[3][i].astype(np.int32),
+                node_right=cols[4][i].astype(np.int32),
+                node_gain=cols[5][i], node_value=cols[6][i],
+                node_weight=cols[7][i], node_count=cols[8][i],
+                node_is_cat=cols[9][i].astype(np.int32),
+                node_cat_bits=bits.reshape(m, W),
+                leaf_value=cols[10][i], leaf_weight=cols[11][i],
+                leaf_count=cols[12][i], num_leaves=nls[i])
+            out.append(host_tree_from_arrays(tree, mapper,
+                                             mapper.missing_bin))
+        return out, nls
 
 
 def _truncate_no_growth(host_trees: List[HostTree], nls: np.ndarray, K: int,
@@ -1454,37 +1515,41 @@ def _capture_reference_profile(booster: Booster, bins, mapper,
     prediction-margin sketch from a bin-representative predict pass.
     Advisory — a capture failure logs and leaves
     ``booster.reference_profile`` None (drift monitoring off), it never
-    fails the fit."""
+    fails the fit.  Charged to every fit: the ``train.reference_profile``
+    span, ``rows`` being the rows sketched."""
     if os.environ.get(REF_PROFILE_ENV, "1") == "0" or mapper is None:
         return
-    try:
-        from ..core.sketch import build_reference_profile
-        if isinstance(bins, (list, tuple)):
-            bins = np.concatenate([np.asarray(b) for b in bins], axis=0)
-        bins = np.asarray(bins)
-        if bins.ndim != 2 or bins.shape[1] != mapper.num_features:
-            return
-        sample = bins
-        if sample.shape[0] > _REF_PROFILE_MARGIN_ROWS:
-            idx = np.random.default_rng(0).choice(
-                sample.shape[0], size=_REF_PROFILE_MARGIN_ROWS,
-                replace=False)
-            idx.sort()
-            sample = sample[idx]
-        reps = _bin_representatives(mapper)
-        Xr = np.empty(sample.shape, np.float32)
-        for j, rep in enumerate(reps):
-            Xr[:, j] = rep[sample[:, j].astype(np.int64)]
-        margins = np.asarray(booster.predict_margin(Xr))
-        booster.reference_profile = build_reference_profile(
-            bins, mapper, margins, feature_names=feature_names,
-            meta={"trees": len(booster.trees),
-                  "num_class": booster.num_class,
-                  "fit_span": _tm.current_fit_span()})
-        train_stats.incr("ref_profiles")
-    except Exception:  # noqa: BLE001 - the profile is advisory
-        log.exception("reference-profile capture failed; drift "
-                      "monitoring will be unavailable for this model")
+    with get_profiler().region("train.reference_profile",
+                               rows=0) as sp:
+        try:
+            from ..core.sketch import build_reference_profile
+            if isinstance(bins, (list, tuple)):
+                bins = np.concatenate([np.asarray(b) for b in bins], axis=0)
+            bins = np.asarray(bins)
+            if bins.ndim != 2 or bins.shape[1] != mapper.num_features:
+                return
+            sp["rows"] = int(bins.shape[0])
+            sample = bins
+            if sample.shape[0] > _REF_PROFILE_MARGIN_ROWS:
+                idx = np.random.default_rng(0).choice(
+                    sample.shape[0], size=_REF_PROFILE_MARGIN_ROWS,
+                    replace=False)
+                idx.sort()
+                sample = sample[idx]
+            reps = _bin_representatives(mapper)
+            Xr = np.empty(sample.shape, np.float32)
+            for j, rep in enumerate(reps):
+                Xr[:, j] = rep[sample[:, j].astype(np.int64)]
+            margins = np.asarray(booster.predict_margin(Xr))
+            booster.reference_profile = build_reference_profile(
+                bins, mapper, margins, feature_names=feature_names,
+                meta={"trees": len(booster.trees),
+                      "num_class": booster.num_class,
+                      "fit_span": _tm.current_fit_span()})
+            train_stats.incr("ref_profiles")
+        except Exception:  # noqa: BLE001 - the profile is advisory
+            log.exception("reference-profile capture failed; drift "
+                          "monitoring will be unavailable for this model")
 
 
 def train(*args, **kwargs) -> Booster:
@@ -1504,35 +1569,65 @@ def train(*args, **kwargs) -> Booster:
     nested = _tm.current_fit_span() is not None
     if nested:
         return _train_impl(*args, **kwargs)
+
+    def _arg(i: int, name: str):
+        return args[i] if len(args) > i else kwargs.get(name)
+
     span = _tm.new_trace_id()
     _tm.set_current_fit_span(span)
     t0 = time.perf_counter()
     _tm.get_journal().emit("fit_begin", fit=span)
     try:
-        booster = _train_impl(*args, **kwargs)
-    except BaseException as e:
-        _tm.get_journal().emit("fit_failed", fit=span,
-                               error=type(e).__name__)
-        if not isinstance(e, KeyboardInterrupt):
-            # self-contained post-mortem: journal tail (boost_chunk /
-            # ckpt_* history), metrics and thread stacks at the moment
-            # the fit died — the flight record IS the crash report
-            _tm.record_flight("fit_failed",
-                              {"fit": span, "error": repr(e)})
+        # the root of the fit's spans (docs/observability.md): every
+        # phase below is its child, and what no child covers is its
+        # self time
+        with get_profiler().region("train.fit") as sp:
+            try:
+                booster = _train_impl(*args, **kwargs)
+            except BaseException as e:
+                _tm.get_journal().emit("fit_failed", fit=span,
+                                       error=type(e).__name__)
+                if not isinstance(e, KeyboardInterrupt):
+                    # self-contained post-mortem: journal tail
+                    # (boost_chunk / ckpt_* history), metrics and thread
+                    # stacks at the moment the fit died — the flight
+                    # record IS the crash report
+                    _tm.record_flight("fit_failed",
+                                      {"fit": span, "error": repr(e)})
+                raise
+            bins, mesh = _arg(0, "bins"), _arg(13, "mesh")
+            _capture_reference_profile(booster, bins, _arg(3, "mapper"),
+                                       _arg(6, "feature_names"))
+            sp.update(_fit_attrs(booster, bins, mesh))
+            _tm.get_journal().emit(
+                "fit_end", fit=span,
+                dur_s=round(time.perf_counter() - t0, 3),
+                trees=len(booster.trees))
+    finally:
         _tm.set_current_fit_span(None)
-        raise
-    def _arg(i: int, name: str):
-        return args[i] if len(args) > i else kwargs.get(name)
-
-    _capture_reference_profile(booster, _arg(0, "bins"),
-                               _arg(3, "mapper"),
-                               _arg(6, "feature_names"))
-    _tm.get_journal().emit(
-        "fit_end", fit=span,
-        dur_s=round(time.perf_counter() - t0, 3),
-        trees=len(booster.trees))
-    _tm.set_current_fit_span(None)
     return booster
+
+
+def _fit_attrs(booster: Booster, bins, mesh) -> dict:
+    """What the ``train.fit`` span says of its fit: the trees returned,
+    the table's shape, the devices it ran on, and the collectives the
+    grower's schedule counts for those trees (``last_fit_info``, per
+    tree, times the trees)."""
+    shards = bins if isinstance(bins, (list, tuple)) else [bins]
+    shapes = [np.shape(b) for b in shards if b is not None]
+    trees = len(booster.trees)
+
+    def per_tree(key: str) -> int:
+        return int(last_fit_info.get(key, 0)) * trees
+
+    return {
+        "trees": trees,
+        "rows": int(sum(sh[0] for sh in shapes)),
+        "features": int(shapes[0][1]) if shapes else 0,
+        "devices": int(mesh.devices.size) if mesh is not None else 1,
+        "collective_count": per_tree("collective_count_per_tree"),
+        "collective_bytes": per_tree("collective_payload_bytes_per_tree"),
+    }
 
 
 def train_incremental(bins: np.ndarray, labels: np.ndarray,
@@ -1674,7 +1769,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         max_cat_threshold=params.max_cat_threshold,
         max_cat_to_onehot=params.max_cat_to_onehot,
         quantized_bits=qbits, quantized_seed=params.seed,
-        quantized_max_code=qmc, quantized_wire=qwire)
+        quantized_max_code=qmc, quantized_wire=qwire,
+        debug_checks=_debug.debug_enabled())
     coll_sched = _collective_sched_for(cfg, mesh, n, f)
     _record_fit_resolution(cfg, collective, coll_downgrade, coll_sched,
                            quantized_downgrade=qdown)
@@ -1806,16 +1902,19 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         efb_dev, efb_host, bundled = _build_efb(bins, mapper, params, f)
         if efb_dev is not None:
             bins_host_final = bundled
-    bins_d = jnp.asarray(bins_host_final, mapper.bin_dtype)
-    labels_d = jnp.asarray(labels,
-                           jnp.int32 if K > 1 else jnp.float32)
-    weights_d = jnp.asarray(w, jnp.float32)
-    scores0 = np.full((n, K) if K > 1 else (n,), init, np.float32)
-    if init_scores is not None:
-        iscores = np.asarray(init_scores, np.float32)
-        scores0 = scores0 + (iscores if scores0.ndim == iscores.ndim
-                             else iscores[:, None])
-    scores = jnp.asarray(scores0)
+    with get_profiler().region("train.upload") as sp:
+        bins_d = jnp.asarray(bins_host_final, mapper.bin_dtype)
+        labels_d = jnp.asarray(labels,
+                               jnp.int32 if K > 1 else jnp.float32)
+        weights_d = jnp.asarray(w, jnp.float32)
+        scores0 = np.full((n, K) if K > 1 else (n,), init, np.float32)
+        if init_scores is not None:
+            iscores = np.asarray(init_scores, np.float32)
+            scores0 = scores0 + (iscores if scores0.ndim == iscores.ndim
+                                 else iscores[:, None])
+        scores = jnp.asarray(scores0)
+        sp["bytes"] = int(bins_d.nbytes + labels_d.nbytes
+                          + weights_d.nbytes + scores.nbytes)
 
     has_val = val_bins is not None and val_metric is not None
     if has_val:
@@ -2054,19 +2153,20 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         # on OOB indexing or non-finite gradients instead of training
         # silently on garbage; identity wrappers when debug mode is off.
         # Static args bind via partial so checkify only sees array args.
-        run_scan = _debug.checked(functools.partial(
-            _boost_scan, obj=objective, cfg=cfg, lr=params.learning_rate,
-            has_val=has_val, rf=use_rf, efb=efb_dev))
-        if use_goss:
-            run_goss = _debug.checked(functools.partial(
-                _boost_scan_goss, obj=objective, cfg=cfg,
-                lr=params.learning_rate, k1=k1, k2=k2, amp=goss_amp,
-                has_val=has_val, K=K, efb=efb_dev))
-        if K > 1:
-            run_multi = _debug.checked(functools.partial(
-                _boost_scan_multi, obj=objective, cfg=cfg,
-                lr=params.learning_rate, K=K, has_val=has_val,
-                efb=efb_dev, rf=use_rf))
+        with get_profiler().region("train.build_step"):
+            run_scan = _debug.checked(functools.partial(
+                _boost_scan, obj=objective, cfg=cfg, lr=params.learning_rate,
+                has_val=has_val, rf=use_rf, efb=efb_dev))
+            if use_goss:
+                run_goss = _debug.checked(functools.partial(
+                    _boost_scan_goss, obj=objective, cfg=cfg,
+                    lr=params.learning_rate, k1=k1, k2=k2, amp=goss_amp,
+                    has_val=has_val, K=K, efb=efb_dev))
+            if K > 1:
+                run_multi = _debug.checked(functools.partial(
+                    _boost_scan_multi, obj=objective, cfg=cfg,
+                    lr=params.learning_rate, K=K, has_val=has_val,
+                    efb=efb_dev, rf=use_rf))
         cb_list: List[TreeArrays] = []
         it = 0
         if ckpt:
@@ -2193,27 +2293,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
                                 jax.random.PRNGKey(params.bagging_seed),
                                 params.num_iterations)
             else:
-                # profiler dispatch bracketing (ISSUE 12): host glue
-                # until the jitted chunk returns vs device wait until
-                # its results materialize, with the compile-seq delta
-                # classifying the dispatch as cache hit or miss
-                _p = get_profiler()
-                _seq0 = _p.compile_seq()
-                trees_st, scores, val_scores, val_hist = run_chunk(
-                    scores, val_scores)
-                _t_host = time.perf_counter()
-                # sync for honest chunk timing; the host needs these
-                # results before the next chunk (or the final fetch)
-                # anyway, so this moves a wait, it does not add one
-                jax.block_until_ready(trees_st)
-                _t_done = time.perf_counter()
-                _p.dispatch("train.boost_chunk", _t_host - t_chunk,
-                            _t_done - _t_host,
-                            _p.compile_seq() - _seq0)
-                _p.span("train.boost_chunk", _t_done - t_chunk,
-                        journal=True, it=int(it),
-                        host_ms=round((_t_host - t_chunk) * 1e3, 3),
-                        device_ms=round((_t_done - _t_host) * 1e3, 3))
+                trees_st, scores, val_scores, val_hist = _dispatch_chunk(
+                    run_chunk, scores, val_scores, it, C * K, t_chunk)
             trees_chunks.append(trees_st)
             _monitor_chunk(it, it + C, time.perf_counter() - t_chunk,
                            n, K, cfg.hist_method, objective, scores,
@@ -2258,21 +2339,10 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         if ckpt:
             _ckpt_clear(ckpt)
 
-    trees, nls = _fetch_host_trees(trees_chunks, params.num_leaves, mapper)
-    trees, nls = trees[:stop_iter * K], nls[:stop_iter * K]
-    trees, stop_iter = _truncate_no_growth(trees, nls, K, stop_iter,
-                                           params.verbosity)
-    if use_dart:
-        # bake the final dart weights into the exported trees (one scale
-        # per ITERATION, shared by its K class trees)
-        for t, s in zip(trees, np.repeat(scales, K)):
-            t.leaf_value = t.leaf_value * s
-            t.internal_value = t.internal_value * s
-            t.shrinkage = s
-    elif use_rf:
-        _rf_average_trees(trees, K)
-    return _finalize_booster(trees, K, init, params, objective, mapper,
-                             feature_names, f, stop_iter)
+    return _export_booster(trees_chunks, K, stop_iter, init, params,
+                           objective, mapper, feature_names, f,
+                           dart_scales=scales if use_dart else None,
+                           rf=use_rf)
 
 
 def _train_distributed_sharded(bins_shards, label_shards, weight_shards,
@@ -2374,7 +2444,8 @@ def _train_distributed_sharded(bins_shards, label_shards, weight_shards,
         max_cat_threshold=params.max_cat_threshold,
         max_cat_to_onehot=params.max_cat_to_onehot,
         quantized_bits=qbits, quantized_seed=params.seed,
-        quantized_max_code=qmc, quantized_wire=qwire)
+        quantized_max_code=qmc, quantized_wire=qwire,
+        debug_checks=_debug.debug_enabled())
 
     from .budget import check_fit_budget
     f_sh = next(b.shape[1] for b in bins_shards if b is not None)
@@ -2626,16 +2697,9 @@ def _train_distributed_ranking(bins, labels, w, mapper, objective, params,
         if trees_list:
             chunks_d = [jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *trees_list)]
-        trees, nls = _fetch_host_trees(chunks_d, params.num_leaves,
-                                       mapper)
-        trees, stop_iter = _truncate_no_growth(trees, nls, 1, T,
-                                               params.verbosity)
-        for t_, s_ in zip(trees, scales):
-            t_.leaf_value = t_.leaf_value * s_
-            t_.internal_value = t_.internal_value * s_
-            t_.shrinkage = s_
-        return _finalize_booster(trees, 1, init, params, objective,
-                                 mapper, feature_names, f, stop_iter)
+        return _export_booster(chunks_d, 1, T, init, params, objective,
+                               mapper, feature_names, f,
+                               dart_scales=scales)
 
     goss_rk = None
     if params.boosting == "goss":
@@ -2727,14 +2791,8 @@ def _train_distributed_ranking(bins, labels, w, mapper, objective, params,
             break
         it += C
 
-    trees, nls = _fetch_host_trees(chunks, params.num_leaves, mapper)
-    trees, nls = trees[:stop_iter], nls[:stop_iter]
-    trees, stop_iter = _truncate_no_growth(trees, nls, 1, stop_iter,
-                                           params.verbosity)
-    if use_rf_rk:
-        _rf_average_trees(trees, 1)
-    return _finalize_booster(trees, 1, init, params, objective, mapper,
-                             feature_names, f, stop_iter)
+    return _export_booster(chunks, 1, stop_iter, init, params, objective,
+                           mapper, feature_names, f, rf=use_rf_rk)
 
 
 def _rf_margins(init, vh_row, tree_idx: int):
@@ -2797,6 +2855,31 @@ def _finalize_booster(trees, K, init, params, objective, mapper,
         init_score=0.0, feature_names=feature_names,
         feature_infos=mapper.feature_infos(),
         max_feature_idx=f - 1, params=engine_params)
+
+
+def _export_booster(chunks, K, stop_iter, init, params, objective, mapper,
+                    feature_names, f, dart_scales=None,
+                    rf: bool = False) -> Booster:
+    """The tail every trainer shares: the device trees to the host
+    (``train.fetch_trees``), then ``train.finalize``: cut to
+    ``stop_iter`` iterations and to the last iteration that grew, bake
+    in the dart weights (``dart_scales``: one per ITERATION, shared by
+    its K class trees) or the forest average (``rf``), and build the
+    Booster."""
+    trees, nls = _fetch_host_trees(chunks, params.num_leaves, mapper)
+    with get_profiler().region("train.finalize"):
+        trees, nls = trees[:stop_iter * K], nls[:stop_iter * K]
+        trees, stop_iter = _truncate_no_growth(trees, nls, K, stop_iter,
+                                               params.verbosity)
+        if dart_scales is not None:
+            for t, s in zip(trees, np.repeat(dart_scales, K)):
+                t.leaf_value = t.leaf_value * s
+                t.internal_value = t.internal_value * s
+                t.shrinkage = s
+        elif rf:
+            _rf_average_trees(trees, K)
+        return _finalize_booster(trees, K, init, params, objective,
+                                 mapper, feature_names, f, stop_iter)
 
 
 def _train_distributed_dart(bins, labels, w, mapper, objective, params,
@@ -2902,15 +2985,8 @@ def _train_distributed_dart(bins, labels, w, mapper, objective, params,
     if trees_list:
         trees_chunks = [jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *trees_list)]
-    trees, nls = _fetch_host_trees(trees_chunks, L, mapper)
-    trees, stop_iter = _truncate_no_growth(trees, nls, K, T,
-                                           params.verbosity)
-    for t, s in zip(trees, np.repeat(scales, K)):
-        t.leaf_value = t.leaf_value * s
-        t.internal_value = t.internal_value * s
-        t.shrinkage = s
-    return _finalize_booster(trees, K, init, params, objective, mapper,
-                             feature_names, f, stop_iter)
+    return _export_booster(trees_chunks, K, T, init, params, objective,
+                           mapper, feature_names, f, dart_scales=scales)
 
 
 def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
@@ -3289,19 +3365,9 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
                         bags = jnp.asarray(bags_host)
                     fi_stack = jnp.asarray(fi_host)
         else:
-            _p = get_profiler()
-            _seq0 = _p.compile_seq()
-            trees_st, scores, val_scores, val_hist = run_step(
-                scores, val_scores)
-            _t_host = time.perf_counter()
-            jax.block_until_ready(trees_st)
-            _t_done = time.perf_counter()
-            _p.dispatch("train.boost_chunk", _t_host - t_chunk,
-                        _t_done - _t_host, _p.compile_seq() - _seq0)
-            _p.span("train.boost_chunk", _t_done - t_chunk,
-                    journal=True, it=int(it), mesh=True,
-                    host_ms=round((_t_host - t_chunk) * 1e3, 3),
-                    device_ms=round((_t_done - _t_host) * 1e3, 3))
+            trees_st, scores, val_scores, val_hist = _dispatch_chunk(
+                run_step, scores, val_scores, it, C * K, t_chunk,
+                mesh=True)
         chunks.append(trees_st)
         # objective=None: the gang's score vector is sharded (not fully
         # addressable on any one controller), so train loss is skipped
@@ -3357,11 +3423,5 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
         if jax.process_index() == 0:
             _ckpt_clear(ckpt)
 
-    trees, nls = _fetch_host_trees(chunks, params.num_leaves, mapper)
-    trees, nls = trees[:stop_iter * K], nls[:stop_iter * K]
-    trees, stop_iter = _truncate_no_growth(trees, nls, K, stop_iter,
-                                           params.verbosity)
-    if use_rf_m:
-        _rf_average_trees(trees, K)
-    return _finalize_booster(trees, K, init, params, objective, mapper,
-                             feature_names, f, stop_iter)
+    return _export_booster(chunks, K, stop_iter, init, params, objective,
+                           mapper, feature_names, f, rf=use_rf_m)

@@ -150,6 +150,14 @@ class GrowerConfig:
     quantized_seed: int = 0
     quantized_max_code: int = 0
     quantized_wire: str = "none"
+    #: trace the sanitizer's invariants (core/debug.py) into the program;
+    #: the engine sets it from debug mode at config build.  Static, and
+    #: off means nothing of them is traced: checkify numbers each check
+    #: from a process-wide counter, and that number, baked into the HLO
+    #: as a constant, gave every re-trace of one program (a mesh fit
+    #: re-traces per fit) another persistent-cache key, so the cache
+    #: missed and the fit compiled for real (PERF.md Findings, PR 25)
+    debug_checks: bool = False
 
     @property
     def cat_words(self) -> int:
@@ -440,6 +448,7 @@ def _wire_cast_psum(h, cfg: GrowerConfig):
     return jax.lax.psum(h, cfg.axis_name)
 
 
+@jax.named_scope("reduce")
 def _reduce_hist(h, cfg: GrowerConfig):
     """Cross-shard reduction of a local histogram: ``lax.psum`` or the
     on-chip Pallas ring (ops/pallas_collectives.py) per
@@ -479,6 +488,7 @@ def _take_cand(hist, cand):
     return jnp.take_along_axis(hist, cand[:, :, None, None], axis=1)
 
 
+@jax.named_scope("reduce")
 def _reduce_select(hist_local, cand, cfg: GrowerConfig):
     """Reduce ONLY the voted candidate columns across the data mesh: the
     voted-column ring (ops/pallas_collectives.ring_allreduce_select)
@@ -618,6 +628,7 @@ def find_best_split_voting(hist_local, parent_g, parent_h, parent_c,
                           feat_info, depth_ok, num_mask, cat_allowed, cfg)
 
 
+@jax.named_scope("split_scan")
 def find_best_split_voting_pair(hist_l, hist_r, tot_l, tot_r, feat_info,
                                 depth_ok, cfg: GrowerConfig, deq=None):
     """Batched-frontier voting for the two children of one grow step:
@@ -663,6 +674,7 @@ def _bucket_sizes(n: int, cfg: GrowerConfig):
     return sizes
 
 
+@jax.named_scope("partition")
 def _partition_switch(row_order, col, off, cnt, thr, use_cat, cat_bits,
                       n, sizes, cfg: GrowerConfig):
     """Partition the split leaf's contiguous ``row_order`` segment into
@@ -788,20 +800,22 @@ def _segment_hist(bins, gh, row_order, off, cnt, n, sizes,
             seg = jax.lax.dynamic_slice(row_order, (off,), (size,))
             valid = jnp.arange(size, dtype=jnp.int32) < cnt
             rows = jnp.minimum(seg, n - 1)
-            if bins_pk is not None:
-                u = jnp.take(bins_pk, rows, axis=0)       # (size, f4) u32
-                parts = jnp.stack(
-                    [(u >> (8 * k)) & jnp.uint32(0xFF) for k in range(4)],
-                    axis=-1)
-                b_sub = parts.reshape(size, -1)[:, :f_cols] \
-                    .astype(jnp.int32)
-            else:
-                b_sub = jnp.take(bins, rows, axis=0)
-            gh_sub = jnp.take(gh, rows, axis=0) * \
-                valid.astype(gh.dtype)[:, None]
-            return compute_histogram(b_sub, gh_sub, cfg.num_bins,
-                                     method=cfg.hist_method,
-                                     max_code=cfg.quantized_max_code)
+            with jax.named_scope("row_gather"):
+                if bins_pk is not None:
+                    u = jnp.take(bins_pk, rows, axis=0)   # (size, f4) u32
+                    parts = jnp.stack(
+                        [(u >> (8 * k)) & jnp.uint32(0xFF)
+                         for k in range(4)], axis=-1)
+                    b_sub = parts.reshape(size, -1)[:, :f_cols] \
+                        .astype(jnp.int32)
+                else:
+                    b_sub = jnp.take(bins, rows, axis=0)
+                gh_sub = jnp.take(gh, rows, axis=0) * \
+                    valid.astype(gh.dtype)[:, None]
+            with jax.named_scope("segment_hist"):
+                return compute_histogram(b_sub, gh_sub, cfg.num_bins,
+                                         method=cfg.hist_method,
+                                         max_code=cfg.quantized_max_code)
         return fn
 
     branch = jnp.searchsorted(jnp.asarray(sizes, jnp.int32), cnt,
@@ -897,6 +911,7 @@ def _global_totals(g, h, c, cfg: GrowerConfig):
     return g, h, c
 
 
+@jax.named_scope("split_scan")
 def _find_split(hist, pg, ph, pc, fi, depth_ok, cfg: GrowerConfig,
                 deq=None):
     """Best split over ``hist``.  ``deq`` (quantized mode): ``hist`` is
@@ -1026,12 +1041,15 @@ def make_feat_info(f: int, feature_mask=None, is_cat=None, nbins=None):
 
 def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                     binsT=None):
-    # debug-mode invariants (no-ops unless the calling program is
-    # checkified): every training path funnels through here, so corrupt
-    # bins / non-finite gradients are caught regardless of entry point
-    from ..core import debug as _debug
-    _debug.check_bins_in_range(bins, cfg.num_bins)
-    _debug.check_finite("gradients/hessians", gh)
+    # debug-mode invariants: every training path funnels through here,
+    # so corrupt bins / non-finite gradients are caught regardless of
+    # entry point.  Traced only when the config asks (GrowerConfig.
+    # debug_checks): unchecked they do nothing, but each would still
+    # leave its process-wide serial number in the HLO
+    if cfg.debug_checks:
+        from ..core import debug as _debug
+        _debug.check_bins_in_range(bins, cfg.num_bins)
+        _debug.check_finite("gradients/hessians", gh)
     # quantized-gradient mode (ISSUE 17): discretize this tree's gh to
     # integer grid codes ONCE; every histogram below accumulates exact
     # int32, the sibling subtraction is bit-exact in integers, and the
@@ -1085,7 +1103,8 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
             and bins.dtype == jnp.uint8):
         bins_pk = pack_bins_u32(bins)
 
-    hist0 = _hist(bins, gh, cfg, efb)
+    with jax.named_scope("root_hist"):
+        hist0 = _hist(bins, gh, cfg, efb)
     g0, h0, c0 = _global_totals(*tot_deq(*_totals_from_hist(hist0)), cfg)
     depth0_ok = (cfg.max_depth <= 0) | (0 < cfg.max_depth)
     bg0, bf0, bb0, bc0, bits0 = _find_split(
@@ -1196,8 +1215,9 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 if cfg.axis_name is not None:
                     # counts are bounded by n, which the quantized wire
                     # policy keeps within the wire dtype — ride it too
-                    tot = _wire_cast_psum(jnp.stack([cnt_l_p, cnt_r_p]),
-                                          cfg)
+                    with jax.named_scope("reduce"):
+                        tot = _wire_cast_psum(
+                            jnp.stack([cnt_l_p, cnt_r_p]), cfg)
                     use_right = tot[1] <= tot[0]
                 else:
                     use_right = cnt_r_p <= cnt_l_p
@@ -1216,10 +1236,11 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                     # voting keeps per-leaf histograms local; only voted
                     # candidate slices are reduced inside _find_split
                     hist_small = _reduce_hist(hist_small, cfg)
-                parent_hist = state.leaf_hist[l]
-                hist_r = jnp.where(use_right, hist_small,
-                                   parent_hist - hist_small)
-                hist_l = parent_hist - hist_r
+                with jax.named_scope("cache_update"):
+                    parent_hist = state.leaf_hist[l]
+                    hist_r = jnp.where(use_right, hist_small,
+                                       parent_hist - hist_small)
+                    hist_l = parent_hist - hist_r
                 row_leaf = state.row_leaf
                 leaf_start = state.leaf_start.at[new_id].set(off + cnt_l_p)
                 leaf_cnt = state.leaf_cnt.at[l].set(cnt_l_p) \
@@ -1298,17 +1319,19 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 leaf_count=t.leaf_count.at[l].set(c_l).at[new_id].set(c_r),
                 num_leaves=t.num_leaves + 1,
             )
+            with jax.named_scope("cache_update"):
+                # slice-gated: a full-buffer where() would re-traverse the
+                # (L, f, B, 3) state — exactly the copy being avoided
+                leaf_hist = state.leaf_hist \
+                    .at[l].set(jnp.where(ds, hist_l, state.leaf_hist[l])) \
+                    .at[new_id].set(jnp.where(ds, hist_r,
+                                              state.leaf_hist[new_id]))
             return _GrowState(
                 row_leaf=row_leaf,
                 row_order=row_order,
                 leaf_start=leaf_start,
                 leaf_cnt=leaf_cnt,
-                # slice-gated: a full-buffer where() would re-traverse the
-                # (L, f, B, 3) state — exactly the copy being avoided
-                leaf_hist=state.leaf_hist
-                    .at[l].set(jnp.where(ds, hist_l, state.leaf_hist[l]))
-                    .at[new_id].set(jnp.where(ds, hist_r,
-                                              state.leaf_hist[new_id])),
+                leaf_hist=leaf_hist,
                 leaf_g=state.leaf_g.at[l].set(g_l).at[new_id].set(g_r),
                 leaf_h=state.leaf_h.at[l].set(h_l).at[new_id].set(h_r),
                 leaf_c=state.leaf_c.at[l].set(c_l).at[new_id].set(c_r),

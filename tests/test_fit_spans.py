@@ -1,0 +1,427 @@
+"""The fit's own phases as spans (ISSUE 25): ``Profiler.region`` (one
+measurement, three faces: in-memory record, profiler annotation, phase
+histogram), the spans every fit emits, and the named scopes its device
+program carries."""
+
+import glob
+import json
+import os
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mmlspark_tpu.core import telemetry
+from mmlspark_tpu.core.profiler import Profiler, get_profiler
+from mmlspark_tpu.gbdt import LightGBMClassifier, engine
+from mmlspark_tpu.gbdt.binning import fit_bin_mapper
+from mmlspark_tpu.gbdt.grower import GrowerConfig, make_feat_info
+from mmlspark_tpu.gbdt.objectives import BinaryObjective, get_objective
+
+#: span -> how often a fit of one chunk emits it
+FIT_SPANS = {
+    "train.upload": 1, "train.build_step": 1, "train.launch": 1,
+    "train.device_wait": 1, "train.monitor": 1, "train.fetch_trees": 1,
+    "train.finalize": 1, "train.reference_profile": 1,
+}
+DEVICE_SCOPES = ("root_hist", "row_gather", "segment_hist", "partition",
+                 "split_scan", "cache_update", "reduce", "score_update",
+                 "gradients")
+
+
+# ---------------------------------------------------------------- region
+
+
+class TestRegion:
+    def test_nesting_records_parent_and_closes_children_first(self):
+        p = Profiler(enabled=True)
+        with p.region("outer", rows=3) as attrs:
+            with p.region("inner"):
+                pass
+            with p.region("inner"):
+                pass
+            attrs["trees"] = 2
+        inner1, inner2, outer = p.spans()
+        assert [s["name"] for s in (inner1, inner2, outer)] == \
+            ["inner", "inner", "outer"]
+        assert outer["parent"] is None
+        assert inner1["parent"] == inner2["parent"] == outer["id"]
+        assert len({inner1["id"], inner2["id"], outer["id"]}) == 3
+        assert outer["attrs"] == {"rows": 3, "trees": 2}
+        assert outer["start"] <= inner1["start"] <= inner1["end"] \
+            <= inner2["start"] <= inner2["end"] <= outer["end"]
+
+    def test_parent_is_per_thread(self):
+        p = Profiler(enabled=True)
+        inside = threading.Event()
+        release = threading.Event()
+
+        def other():
+            with p.region("other.thread"):
+                inside.set()
+                assert release.wait(10)
+
+        t = threading.Thread(target=other)
+        t.start()
+        assert inside.wait(10)
+        with p.region("main.thread"):
+            pass
+        release.set()
+        t.join(10)
+        assert not t.is_alive()
+        by_name = {s["name"]: s for s in p.spans()}
+        # open at the same time, on two threads: neither is the other's
+        assert by_name["main.thread"]["parent"] is None
+        assert by_name["other.thread"]["parent"] is None
+
+    def test_spans_of_one_fit_share_its_id(self):
+        p = Profiler(enabled=True)
+        telemetry.set_current_fit_span("feedface00000001")
+        try:
+            with p.region("a"):
+                with p.region("b"):
+                    pass
+        finally:
+            telemetry.set_current_fit_span(None)
+        with p.region("c"):
+            pass
+        fits = {s["name"]: s["fit"] for s in p.spans()}
+        assert fits == {"a": "feedface00000001", "b": "feedface00000001",
+                        "c": None}
+
+    def test_ring_is_bounded_and_spans_is_a_copy(self):
+        p = Profiler(enabled=True)
+        for i in range(p.SPAN_RING + 10):
+            with p.region("r", i=i):
+                pass
+        spans = p.spans()
+        assert len(spans) == p.SPAN_RING
+        assert spans[-1]["attrs"]["i"] == p.SPAN_RING + 9   # newest kept
+        assert spans[0]["attrs"]["i"] == 10                 # oldest gone
+        spans.clear()
+        assert len(p.spans()) == p.SPAN_RING
+
+    def test_feeds_the_phase_histogram_and_the_snapshot(self):
+        p = Profiler(enabled=True)
+        n = p.SPAN_SNAPSHOT_TAIL + 3
+        for i in range(n):
+            with p.region("train.something", i=i):
+                pass
+        snap = p.snapshot()
+        assert snap["phases"]["stages"]["train.something"]["count"] == n
+        # the snapshot carries the newest few, JSON-able (flight records
+        # embed it)
+        assert [s["attrs"]["i"] for s in snap["spans"]] == \
+            list(range(3, n))
+        json.dumps(snap)
+
+    def test_a_block_that_raises_is_still_recorded(self):
+        p = Profiler(enabled=True)
+        with pytest.raises(KeyError):
+            with p.region("outer"):
+                with p.region("fails"):
+                    raise KeyError("x")
+        assert [s["name"] for s in p.spans()] == ["fails", "outer"]
+        with p.region("after"):
+            pass
+        assert p.spans()[-1]["parent"] is None    # the stack unwound
+
+    def test_disabled_records_nothing_and_enters_no_annotation(
+            self, monkeypatch):
+        entered = []
+
+        class Spy:
+            def __init__(self, name):
+                entered.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+        p = Profiler(enabled=False)
+        with p.region("off", rows=1) as attrs:
+            attrs["more"] = 2            # callers need no second path
+        assert p.spans() == [] and entered == []
+        assert p.snapshot()["phases"]["stages"] == {}
+        p.configure(enabled=True)
+        with p.region("on"):
+            pass
+        assert entered == ["on"] and len(p.spans()) == 1
+
+    def test_phase_is_the_same_scoped_timer(self):
+        p = Profiler(enabled=True)
+        with p.phase("x.y"):
+            pass
+        assert p.spans()[0]["name"] == "x.y"
+
+    def test_annotation_lies_in_the_profilers_trace(self, tmp_path):
+        """Under ``jax.profiler.trace`` the region is in the
+        ``.xplane.pb`` host plane under its own name, on the clock the
+        device events have."""
+        from jax.profiler import ProfileData
+        p = Profiler(enabled=True)
+        with jax.profiler.trace(str(tmp_path)):
+            with p.region("train.region_under_test"):
+                jnp.ones(8).block_until_ready()
+        path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True)
+        found = [(e.start_ns, e.duration_ns)
+                 for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name == "train.region_under_test"]
+        assert len(found) == 1
+        span, = p.spans()
+        # the same measurement: the two durations agree to a millisecond
+        assert found[0][1] / 1e9 == pytest.approx(
+            span["end"] - span["start"], abs=1e-3)
+
+    def test_jax_seconds_reads_the_monitoring_sums(self):
+        p = Profiler(enabled=True)
+        assert p.jax_seconds("backend_compile") == 0.0
+        p._on_jax_duration("/jax/core/compile/backend_compile_duration",
+                           0.25)
+        p._on_jax_duration("/jax/core/compile/backend_compile_duration",
+                           0.5)
+        assert p.jax_seconds("backend_compile") == pytest.approx(0.75)
+
+
+# ------------------------------------------------------------- fit spans
+
+
+def _table(n=60000, f=12, seed=0):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, f)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(np.float32)
+    return X, y
+
+
+@pytest.fixture(scope="module")
+def fit_inputs():
+    X, y = _table()
+    # large enough that the phases, not the glue between them, are the fit
+    est = LightGBMClassifier(numIterations=6, numLeaves=31, verbosity=0)
+    mapper = fit_bin_mapper(X, max_bin=est.getMaxBin(), seed=est.getSeed())
+    return {"bins": mapper.transform_packed(X),
+            "labels": est._prepare_labels(y), "mapper": mapper,
+            "objective": get_objective(est.getObjective(), num_class=1,
+                                       **est._objective_kwargs()),
+            "params": est._train_params()}
+
+
+def _mesh4():
+    from jax.sharding import Mesh
+    from mmlspark_tpu.core.mesh import DATA_AXIS, FEATURE_AXIS
+    return Mesh(np.asarray(jax.devices()[:4]).reshape(4, 1),
+                (DATA_AXIS, FEATURE_AXIS))
+
+
+def _fit(inp, mesh=None):
+    """One ``engine.train`` call; returns (booster, its root, its other
+    spans)."""
+    prof = get_profiler()
+    before = {s["id"] for s in prof.spans()}
+    booster = engine.train(inp["bins"], inp["labels"], None, inp["mapper"],
+                           inp["objective"], inp["params"], mesh=mesh)
+    new = [s for s in prof.spans() if s["id"] not in before]
+    root, = [s for s in new if s["name"] == "train.fit"]
+    return booster, root, [s for s in new if s is not root]
+
+
+@pytest.mark.parametrize("devices", [1, 4], ids=["serial", "mesh4"])
+def test_fit_emits_every_span_once_inside_its_root(fit_inputs, devices):
+    mesh = _mesh4() if devices == 4 else None
+    _fit(fit_inputs, mesh)                   # warm: compiles are set-up
+    # the children leave little of a fit unnamed; the best of three warm
+    # fits, because the suite's other workers share these cores
+    for _ in range(3):
+        booster, root, spans = _fit(fit_inputs, mesh)
+        covered = sum(s["end"] - s["start"] for s in spans)
+        if covered >= 0.95 * (root["end"] - root["start"]):
+            break
+    assert covered >= 0.95 * (root["end"] - root["start"])
+    counts = {}
+    for s in spans:
+        counts[s["name"]] = counts.get(s["name"], 0) + 1
+    assert counts == FIT_SPANS
+    for s in spans:
+        assert s["parent"] == root["id"] and s["fit"] == root["fit"]
+        assert root["start"] <= s["start"] <= s["end"] <= root["end"]
+    assert root["fit"] is not None and root["parent"] is None
+    n, f = fit_inputs["bins"].shape
+    a = root["attrs"]
+    assert (a["trees"], a["rows"], a["features"], a["devices"]) == \
+        (len(booster.trees), n, f, devices)
+    by_name = {s["name"]: s for s in spans}
+    assert by_name["train.upload"]["attrs"]["bytes"] >= n * f
+    assert by_name["train.fetch_trees"]["attrs"]["bytes"] > 0
+    assert by_name["train.reference_profile"]["attrs"]["rows"] == n
+    assert by_name["train.device_wait"]["attrs"] == {"it": 0, "trees": 6}
+    if devices == 1:
+        assert (a["collective_count"], a["collective_bytes"]) == (0, 0)
+        assert by_name["train.launch"]["attrs"]["compile_misses"] == 0
+        assert by_name["train.monitor"]["attrs"]["loss_rows"] == n
+    else:
+        # one psum of the histograms per split, and the counts' psums
+        assert a["collective_count"] >= 6 * (31 - 1)
+        assert a["collective_bytes"] >= 6 * 30 * f * 256 * 3 * 4
+
+
+def test_chunked_fit_emits_launch_wait_monitor_per_chunk(fit_inputs):
+    calls = []
+    prof = get_profiler()
+    before = {s["id"] for s in prof.spans()}
+    engine.train(fit_inputs["bins"], fit_inputs["labels"], None,
+                 fit_inputs["mapper"], fit_inputs["objective"],
+                 fit_inputs["params"].__class__(
+                     **{**fit_inputs["params"].__dict__,
+                        "num_iterations": 10}),
+                 callbacks=[lambda it, trees: calls.append(it)])
+    names = [s["name"] for s in prof.spans() if s["id"] not in before]
+    assert len(calls) == 10
+    # callbacks bound the chunk at 8 iterations: chunks of 8 and 2
+    for per_chunk in ("train.launch", "train.device_wait",
+                      "train.monitor"):
+        assert names.count(per_chunk) == 2
+    for per_fit in ("train.fit", "train.upload", "train.fetch_trees",
+                    "train.finalize", "train.reference_profile"):
+        assert names.count(per_fit) == 1
+
+
+def test_launch_counts_its_own_compiles():
+    """``compile_misses`` is the ``compile_seq`` delta over the launch,
+    and the seconds beside it are the monitoring sums' deltas."""
+    prof = get_profiler()
+
+    @jax.jit
+    def fresh(x):                     # never compiled before this test
+        return jnp.cos(x) * 3.25 + 1.5
+
+    def run(scores, val_scores):
+        return fresh(scores), scores, val_scores, None
+
+    x = jnp.arange(16.0)
+    for want in (1, 0):               # a miss, then a hit
+        seq0 = prof.compile_seq()
+        before = {s["id"] for s in prof.spans()}
+        engine._dispatch_chunk(run, x, x, 0, 1, 0.0)
+        launch, wait = [s for s in prof.spans() if s["id"] not in before]
+        assert (launch["name"], wait["name"]) == \
+            ("train.launch", "train.device_wait")
+        assert launch["attrs"]["compile_misses"] == \
+            prof.compile_seq() - seq0 == want
+        assert (launch["attrs"]["backend_compile_s"] > 0) == bool(want)
+        assert launch["attrs"]["jaxpr_trace_s"] >= 0
+
+
+def test_disabled_profiler_a_fit_records_no_span(fit_inputs, monkeypatch):
+    prof = get_profiler()
+    entered = []
+    real = jax.profiler.TraceAnnotation
+
+    def spy(name, **kw):
+        entered.append(name)
+        return real(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", spy)
+    before = len(prof.spans())
+    prof.configure(enabled=False)
+    try:
+        booster = engine.train(
+            fit_inputs["bins"], fit_inputs["labels"], None,
+            fit_inputs["mapper"], fit_inputs["objective"],
+            fit_inputs["params"])
+    finally:
+        prof.configure(enabled=True)
+    assert len(booster.trees) == 6
+    assert len(prof.spans()) == before
+    assert [n for n in entered if n.startswith("train.")] == []
+
+
+# ---------------------------------------------------------- device scopes
+
+
+@pytest.fixture(scope="module")
+def lowered_op_names():
+    """``op_name`` metadata of the compiled serial scan and of the mesh
+    scan's lowering (the mesh one carries the collectives)."""
+    import re
+    n, f = 2000, 8
+    obj = BinaryObjective()
+    obj.prepare(np.zeros(n), np.ones(n))
+    cfg = GrowerConfig(num_leaves=7, num_bins=256, hist_method="segment")
+    fis = jnp.asarray(np.broadcast_to(make_feat_info(f), (2, f, 3)))
+    args = (jnp.zeros((n, f), jnp.uint8), jnp.zeros(n), jnp.zeros(n),
+            jnp.ones(n), jnp.ones((2, 1)), fis,
+            jnp.zeros((1, f), jnp.uint8), jnp.zeros(1))
+    serial = engine._boost_scan.lower(
+        *args, obj=obj, cfg=cfg, lr=0.1, has_val=False).compile().as_text()
+    from mmlspark_tpu.gbdt.distributed import (make_boost_scan,
+                                               prepare_arrays)
+    mesh = _mesh4()
+    step = make_boost_scan(mesh, obj, cfg, 0.1, bag_sharded=False)
+    bins_d, lab_d, w_d, real_d, scores, _, _ = prepare_arrays(
+        np.zeros((n, f), np.uint8), np.zeros(n, np.float32),
+        np.ones(n, np.float32), mesh, 1, 0.0)
+    dn = 4
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    vb = jax.device_put(jnp.zeros((dn, f), jnp.uint8),
+                        NamedSharding(mesh, P("data", None)))
+    vs = jax.device_put(jnp.zeros(dn), NamedSharding(mesh, P("data")))
+    meshed = step.lower(bins_d, scores, lab_d, w_d, real_d,
+                        jnp.ones((2, 1)), fis, vb, vs).compile().as_text()
+    pat = re.compile(r'op_name="([^"]+)"')
+    return {"serial": pat.findall(serial), "mesh": pat.findall(meshed)}
+
+
+def test_a_rebuilt_mesh_step_lowers_to_the_same_program():
+    """A mesh fit builds and traces its step anew; what it lowers to
+    must not depend on how many programs the process traced before (a
+    checkify error number did), or the persistent compile cache misses
+    on every fit."""
+    from mmlspark_tpu.gbdt.distributed import (make_boost_scan,
+                                               prepare_arrays)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    n, f = 1000, 6
+    obj = BinaryObjective()
+    obj.prepare(np.zeros(n), np.ones(n))
+    cfg = GrowerConfig(num_leaves=5, num_bins=256, hist_method="segment")
+    mesh = _mesh4()
+    fis = jnp.asarray(np.broadcast_to(make_feat_info(f), (1, f, 3)))
+    bins_d, lab_d, w_d, real_d, scores, _, _ = prepare_arrays(
+        np.zeros((n, f), np.uint8), np.zeros(n, np.float32),
+        np.ones(n, np.float32), mesh, 1, 0.0)
+    vb = jax.device_put(jnp.zeros((4, f), jnp.uint8),
+                        NamedSharding(mesh, P("data", None)))
+    vs = jax.device_put(jnp.zeros(4), NamedSharding(mesh, P("data")))
+    texts = set()
+    for _ in range(3):
+        step = make_boost_scan(mesh, obj, cfg, 0.1, bag_sharded=False)
+        texts.add(step.lower(bins_d, scores, lab_d, w_d, real_d,
+                             jnp.ones((1, 1)), fis, vb, vs).as_text())
+    assert len(texts) == 1
+    # and the sanitizer's checks are in when the config asks for them
+    import dataclasses
+    checked = make_boost_scan(
+        mesh, obj, dataclasses.replace(cfg, debug_checks=True), 0.1,
+        bag_sharded=False)
+    assert checked.lower(bins_d, scores, lab_d, w_d, real_d,
+                         jnp.ones((1, 1)), fis, vb, vs).as_text() \
+        not in texts
+
+
+@pytest.mark.parametrize("scope", DEVICE_SCOPES)
+def test_device_scope_is_in_the_compiled_steps_metadata(lowered_op_names,
+                                                        scope):
+    from mmlspark_tpu.core.profiling import scope_of
+    where = "mesh" if scope == "reduce" else "serial"
+    scopes = {scope_of(name) for name in lowered_op_names[where]}
+    assert any(scope in s.split("/") for s in scopes), sorted(scopes)
+    if scope == "reduce":
+        # the collectives carry it, nested in the stage that reduces
+        assert "root_hist/reduce" in scopes

@@ -269,14 +269,28 @@ class TestStageStatsSnapshotConsistency:
 
 
 def _write_trace(dir_path, fname, ops, mtime):
+    """A hand-made ``.xplane.pb``: one TPU plane whose "XLA Ops" line
+    holds ``ops`` = ``(hlo name, op_name path or None, start_us,
+    dur_us)``; the path rides the event METADATA's ``tf_op`` stat, as on
+    the chip."""
+    from jax.profiler import ProfileData
     os.makedirs(dir_path, exist_ok=True)
-    events = [{"ph": "M", "name": "process_name", "pid": 1,
-               "args": {"name": "TPU:0 /device"}}]
-    events += [{"ph": "X", "pid": 1, "name": name, "dur": dur_us,
-                "ts": 0} for name, dur_us in ops]
+    events, metadata = [], []
+    for i, (name, op_name, start_us, dur_us) in enumerate(ops, 1):
+        events.append(f"events {{ metadata_id: {i} "
+                      f"offset_ps: {int(start_us * 1e6)} "
+                      f"duration_ps: {int(dur_us * 1e6)} }}")
+        stat = (f'stats {{ metadata_id: 1 str_value: "{op_name}" }}'
+                if op_name else "")
+        metadata.append(f'event_metadata {{ key: {i} value {{ id: {i} '
+                        f'name: "{name}" {stat} }} }}')
+    text = ('planes { id: 1 name: "/device:TPU:0" '
+            'lines { id: 1 name: "XLA Ops" ' + " ".join(events) + " } "
+            + " ".join(metadata)
+            + ' stat_metadata { key: 1 value { id: 1 name: "tf_op" } } }')
     path = os.path.join(dir_path, fname)
-    with gzip.open(path, "wt") as fh:
-        json.dump({"traceEvents": events}, fh)
+    with open(path, "wb") as fh:
+        fh.write(ProfileData.text_proto_to_serialized_xspace(text))
     os.utime(path, (mtime, mtime))
     return path
 
@@ -286,11 +300,14 @@ class TestSummarizeTrace:
         from mmlspark_tpu.core.profiling import summarize_trace
         now = time.time()
         # lexicographically LAST but OLD — the pre-fix code picked this
-        _write_trace(str(tmp_path), "zzz_old.trace.json.gz",
-                     [("stale_op", 9_000_000)], now - 3600)
+        _write_trace(str(tmp_path), "zzz_old.xplane.pb",
+                     [("%stale_op.1 = f32[] add()", None, 0, 9_000_000)],
+                     now - 3600)
         # lexicographically first but NEWEST — must win
-        _write_trace(str(tmp_path), "aaa_new.trace.json.gz",
-                     [("fresh_op", 2000), ("other_op", 1000)], now)
+        _write_trace(str(tmp_path), "aaa_new.xplane.pb",
+                     [("%fresh_op.1 = f32[] add()", None, 0, 2000),
+                      ("%other_op.2 = f32[] add()", None, 2000, 1000)],
+                     now)
         rows = summarize_trace(str(tmp_path))
         names = [n for _, n in rows]
         assert "fresh_op" in names and "stale_op" not in names
@@ -302,6 +319,30 @@ class TestSummarizeTrace:
     def test_empty_dir_returns_empty(self, tmp_path):
         from mmlspark_tpu.core.profiling import summarize_trace
         assert summarize_trace(str(tmp_path)) == []
+
+    def test_groups_self_time_by_named_scope(self, tmp_path):
+        """A ``while`` spans its body's ops: its time is counted once,
+        as theirs, under the scopes ``jax.named_scope`` named; ops of
+        one scope add up whatever their HLO names."""
+        from mmlspark_tpu.core.profiling import summarize_trace
+        body = "jit(f)/while/body/closed_call/"
+        _write_trace(str(tmp_path), "t.xplane.pb", [
+            ("%while.7 = (f32[]) while()", None, 0, 10_000),
+            ("%fusion.1 = f32[] fusion()",
+             body + "root_hist/dot_general:", 1000, 3000),
+            ("%all-reduce.2 = f32[] all-reduce()",
+             body + "root_hist/reduce/psum:", 4000, 2000),
+            ("%fusion.3 = f32[] fusion()",
+             body + "cond/branch_2_fun/root_hist/jit(_take)/gather:",
+             6000, 1000),
+            ("%copy.4 = f32[] copy()", body + "add:", 7000, 500),
+        ], time.time())
+        got = dict((n, ms) for ms, n in summarize_trace(str(tmp_path)))
+        assert got["root_hist"] == pytest.approx(4.0)
+        assert got["root_hist/reduce"] == pytest.approx(2.0)
+        assert got["jit(f)"] == pytest.approx(0.5)       # under no scope
+        assert got["while"] == pytest.approx(3.5)        # its own time
+        assert got["total_device_ms"] == pytest.approx(10.0)
 
 
 # ---------------------------------------------------------------- journal

@@ -1,20 +1,17 @@
 """Capture a jax.profiler trace of GBDT boost steps on the live backend.
 
 Writes a perfetto/tensorboard trace under ``artifacts/trace_<backend>/`` and
-prints a per-op summary so the hot spots are visible without a UI
-(VERDICT r1 item #2 / r2 item #2 committed-evidence requirement).
+prints device self time by the grower's named scopes
+(``core.profiling.summarize_trace``), so the hot spots are visible
+without a UI.
 
 Usage: python tools/profile_boost_step.py [--rows 400000] [--steps 3]
 """
 
 import argparse
-import glob
-import gzip
-import json
 import os
 import sys
 import time
-from collections import defaultdict
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -36,6 +33,7 @@ def main():
 
     import jax.numpy as jnp
     import numpy as np
+    from mmlspark_tpu.core.profiling import summarize_trace
     from mmlspark_tpu.gbdt.grower import (GrowerConfig, grow_tree,
                                           make_feat_info)
     from mmlspark_tpu.gbdt.objectives import BinaryObjective
@@ -87,48 +85,14 @@ def main():
             tree, scores = boost_step(bins, binsT, scores)
         jax.block_until_ready((tree, scores))
     print(f"trace written to {out_dir}")
-    summarize(out_dir, args.steps)
-
-
-def summarize(out_dir, steps):
-    """Parse the trace proto-agnostic way: use the .trace.json.gz perfetto
-    export if present, aggregate device-op durations."""
-    paths = glob.glob(os.path.join(out_dir, "**", "*.trace.json.gz"),
-                      recursive=True)
-    if not paths:
-        print("no perfetto json in trace dir; inspect with tensorboard")
+    rows = summarize_trace(out_dir)
+    if not rows:
+        print("no .xplane.pb in the trace dir")
         return
-    with gzip.open(sorted(paths)[-1], "rt") as fh:
-        data = json.load(fh)
-    events = data.get("traceEvents", [])
-    # device-thread durations by op name
-    agg = defaultdict(float)
-    for e in events:
-        if e.get("ph") == "X" and "dur" in e:
-            name = e.get("name", "?")
-            pid = e.get("pid", 0)
-            agg[(pid, name)] += e["dur"]
-    # find the busiest pid (device)
-    by_pid = defaultdict(float)
-    for (pid, name), d in agg.items():
-        by_pid[pid] += d
-    pid_names = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "process_name":
-            pid_names[e.get("pid")] = e.get("args", {}).get("name", "")
-    dev_pids = [p for p, nm in pid_names.items()
-                if "TPU" in nm or "Device" in nm or "/device" in nm]
-    cand = dev_pids or [max(by_pid, key=by_pid.get)]
-    rows = []
-    for pid in cand:
-        for (p, name), d in agg.items():
-            if p == pid:
-                rows.append((d, name))
-    rows.sort(reverse=True)
-    print(f"top device ops over {steps} steps "
-          f"(pid={cand}, total {sum(r[0] for r in rows)/1e3:.1f} ms):")
-    for d, name in rows[:25]:
-        print(f"  {d/1e3/steps:9.2f} ms/step  {name[:100]}")
+    print(f"device self time by named scope over {args.steps} steps "
+          f"(total {rows[-1][0]:.1f} ms):")
+    for ms, name in rows[:-1]:
+        print(f"  {ms / args.steps:9.2f} ms/step  {name[:100]}")
 
 
 if __name__ == "__main__":
