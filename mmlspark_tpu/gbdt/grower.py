@@ -1169,12 +1169,12 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
 
         # The step body runs UNCONDITIONALLY with its effects gated by
         # ``do_split`` (see the merge at the end) instead of under
-        # ``lax.cond``: XLA materializes copies of the untouched carry
-        # buffers at every cond join, and the (L, f, B, 3) leaf_hist made
-        # that ~half the per-split cost at bench scale (PERF.md round 4).
+        # ``lax.cond``, whose join copies the untouched carry buffers.
         # Inactive steps neutralize themselves: the partition/histogram
         # run with cnt forced to 0 (identity permutation, empty segment),
-        # and every state write merges through ``ds``.
+        # and every state write merges through ``ds``.  That alone does
+        # not make the (L, f, B, 3) leaf_hist update in place: see
+        # ``cache_update`` below for what the TPU's compiler needs.
         def do(state: _GrowState, ds) -> _GrowState:
             feat = state.best_feat[l]
             thr = state.best_bin[l]
@@ -1320,12 +1320,25 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 num_leaves=t.num_leaves + 1,
             )
             with jax.named_scope("cache_update"):
-                # slice-gated: a full-buffer where() would re-traverse the
-                # (L, f, B, 3) state — exactly the copy being avoided
+                # XLA updates a loop's carry in place only if no read of
+                # the OLD buffer can come after the first write to it.
+                # Row ``l`` is gated on its own old value, an operand of
+                # that first write.  Row ``new_id`` is gated on zeros, not
+                # on the old ``leaf_hist[new_id]``: nothing orders that
+                # read before the first write, so the v5e's compiler kept
+                # the old buffer alive and copied the whole (L, f, B, 3)
+                # cache before the first update and back after the second
+                # (2 x 1.57 GB a split at Epsilon's shape; PERF.md
+                # Findings, PR 26).  Invariant that makes zeros the same
+                # value: slot ``new_id = i + 1`` is first written at step
+                # ``i`` (earlier steps wrote slots ``0..i``), so it still
+                # holds the zeros it was created with.  The CPU's compiler
+                # copies the cache either way; tests/test_mosaic_aot.py
+                # reads the v5e's compiled program instead.
                 leaf_hist = state.leaf_hist \
                     .at[l].set(jnp.where(ds, hist_l, state.leaf_hist[l])) \
                     .at[new_id].set(jnp.where(ds, hist_r,
-                                              state.leaf_hist[new_id]))
+                                              jnp.zeros_like(hist_r)))
             return _GrowState(
                 row_leaf=row_leaf,
                 row_order=row_order,

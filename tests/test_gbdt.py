@@ -1,5 +1,8 @@
 """GBDT engine: grower invariants, end-to-end quality, persistence."""
 
+import json
+import os
+
 import numpy as np
 import pandas as pd
 import pytest
@@ -523,3 +526,81 @@ class TestPassThroughArgs:
             np.testing.assert_array_equal(x.split_feature, z.split_feature)
             np.testing.assert_allclose(x.leaf_value, z.leaf_value,
                                        rtol=1e-6, atol=1e-7)
+
+
+# ------------------------------------------- the histogram cache's slots
+
+_GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "golden")
+_GROWERS = {
+    "serial": {},
+    "masked": {"compact_rows": False},
+    "quantized": {"quantized_bits": 8, "quantized_max_code": 127},
+}
+
+
+def _grow_fixed(kind, **cfg_kw):
+    """One tree at a fixed seed through ``grow_tree`` itself (no
+    estimator in between), by the named grower."""
+    import jax.numpy as jnp
+    from mmlspark_tpu.gbdt.grower import (GrowerConfig, grow_tree,
+                                          make_feat_info)
+    rng = np.random.default_rng(26)
+    n, f, B = 2500, 9, 64
+    bins = rng.integers(0, B, size=(n, f)).astype(np.uint8)
+    y = ((bins[:, 0] > 30) * 1.0 + (bins[:, 3] > 11) * (bins[:, 5] < 40)
+         + rng.normal(scale=0.2, size=n)).astype(np.float32)
+    gh = np.stack([y - y.mean(), np.ones(n, np.float32),
+                   np.ones(n, np.float32)], axis=1).astype(np.float32)
+    kw = dict(num_leaves=24, num_bins=B, min_data_in_leaf=5,
+              hist_method="dot16")
+    kw.update(_GROWERS[kind])
+    kw.update(cfg_kw)
+    tree, row_leaf = grow_tree(jnp.asarray(bins), jnp.asarray(gh),
+                               make_feat_info(f), GrowerConfig(**kw))
+    return ({k: np.asarray(v) for k, v in tree._asdict().items()},
+            np.asarray(row_leaf))
+
+
+class TestHistCacheSlots:
+    """The split loop writes the new leaf's cache row without reading it
+    first (that read kept the whole cache's old buffer alive and cost two
+    whole-cache copies a split on the TPU: PERF.md Findings, PR 26).
+    What that rests on: slot ``i + 1`` is first written at step ``i``,
+    and a step that does not split leaves nothing behind that a later
+    step reads.  The compiled program itself is guarded where a TPU
+    compiler is (tests/test_mosaic_aot.py)."""
+
+    @pytest.mark.parametrize("kind", sorted(_GROWERS))
+    def test_growth_that_stops_early_equals_the_short_loop(self, kind):
+        """Most of the 254 steps are inactive here; the tree must be the
+        one a loop of exactly the reached length grows."""
+        long_t, long_rl = _grow_fixed(kind, num_leaves=255,
+                                      min_data_in_leaf=300)
+        nl = int(long_t["num_leaves"])
+        assert 3 <= nl <= 8, nl
+        short_t, short_rl = _grow_fixed(kind, num_leaves=nl,
+                                        min_data_in_leaf=300)
+        assert int(short_t["num_leaves"]) == nl
+        np.testing.assert_array_equal(long_rl, short_rl)
+        for k, v in short_t.items():
+            if v.ndim == 0:
+                continue
+            np.testing.assert_array_equal(long_t[k][:v.shape[0]], v, k)
+            # the steps that did not split wrote nothing
+            assert not long_t[k][v.shape[0]:].any(), k
+
+    @pytest.mark.parametrize("kind", sorted(_GROWERS))
+    def test_tree_equals_the_parent_commits_record(self, kind):
+        """tests/golden/grower_<kind>.json was taken from the commit
+        before the ungated write (e9b88e5): structure exact, values to
+        1e-6."""
+        with open(os.path.join(_GOLDEN_DIR, f"grower_{kind}.json")) as fh:
+            want = json.load(fh)
+        got, _ = _grow_fixed(kind)
+        assert int(got["num_leaves"]) == want["num_leaves"]
+        for k in ("node_feat", "node_bin", "node_left", "node_right",
+                  "leaf_count", "node_count"):
+            np.testing.assert_array_equal(got[k], np.asarray(want[k]), k)
+        for k in ("leaf_value", "node_value", "leaf_weight"):
+            np.testing.assert_allclose(got[k], np.asarray(want[k]),
+                                       rtol=0, atol=1e-6, err_msg=k)

@@ -1,10 +1,13 @@
-"""Pallas kernels through Mosaic WITHOUT a chip.
+"""Pallas kernels through Mosaic, and the grower through the TPU's XLA,
+WITHOUT a chip.
 
 libtpu is installed here, so jax can describe a v5e 2x2 topology and
 ahead-of-time compile for it: lowering and Mosaic compilation run in full,
 nothing executes.  This is where a kernel author learns for free that the
-compiler refuses something; numbers, numerics and hangs still need
-chip_smoke.py on the chip.  Skipped where no TPU compiler is available.
+compiler refuses something, and where the compiled program's text says
+what the chip will do that the CPU's compiler does not (a loop's carry
+copied whole); numbers, numerics and hangs still need chip_smoke.py on
+the chip.  Skipped where no TPU compiler is available.
 """
 
 import jax
@@ -71,3 +74,66 @@ def test_fused_gather_is_refused_with_the_recorded_message(v5e):
             _sds((56, 4096), jnp.uint8, one),
             _sds((2048, 3), jnp.float32, one),
             _sds((2048,), jnp.int32, one))
+
+
+# ------------------------------------- the split loop's carry, in place
+
+_CACHE_N, _CACHE_F, _CACHE_L, _CACHE_B = 40_000, 200, 255, 256
+
+
+def _compile_grower(v5e, case):
+    """The v5e's compiled program for one way of running the grower at
+    40 000 x 200, 255 leaves, dot16 (11-16 s each)."""
+    from mmlspark_tpu.gbdt.grower import (GrowerConfig, _grow_tree_impl,
+                                          grow_tree)
+    n, f = _CACHE_N, _CACHE_F
+    one = SingleDeviceSharding(v5e[0])
+    base = dict(num_leaves=_CACHE_L, num_bins=_CACHE_B, hist_method="dot16",
+                min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0)
+    if case in ("serial", "masked"):
+        cfg = GrowerConfig(compact_rows=(case == "serial"), **base)
+        return grow_tree.lower(
+            _sds((n, f), jnp.uint8, one), _sds((n, 3), jnp.float32, one),
+            _sds((f, 3), jnp.float32, one), cfg).compile()
+    if case == "shard_map":
+        cfg = GrowerConfig(axis_name=DATA_AXIS, data_axis_size=len(v5e),
+                           **base)
+        mesh = Mesh(np.asarray(v5e), (DATA_AXIS,))
+        rows, rep = P(DATA_AXIS, None), P()
+        fn = jax.shard_map(
+            lambda b, g, fi: _grow_tree_impl(b, g, fi, cfg)[0],
+            mesh=mesh, in_specs=(rows, rows, rep), out_specs=rep,
+            check_vma=False)
+        return jax.jit(fn).lower(
+            _sds((n, f), jnp.uint8, NamedSharding(mesh, rows)),
+            _sds((n, 3), jnp.float32, NamedSharding(mesh, rows)),
+            _sds((f, 3), jnp.float32, NamedSharding(mesh, rep))).compile()
+    assert case == "boost_scan"
+    from mmlspark_tpu.gbdt import engine
+    from mmlspark_tpu.gbdt.objectives import BinaryObjective
+    obj = BinaryObjective()
+    obj.prepare(np.zeros(8), np.ones(8))
+    trees = 2
+    return engine._boost_scan.lower(
+        _sds((n, f), jnp.uint8, one), _sds((n,), jnp.float32, one),
+        _sds((n,), jnp.float32, one), _sds((n,), jnp.float32, one),
+        _sds((trees, 1), jnp.float32, one),
+        _sds((trees, f, 3), jnp.float32, one),
+        _sds((1, f), jnp.uint8, one), _sds((1,), jnp.float32, one),
+        obj=obj, cfg=GrowerConfig(**base), lr=0.1,
+        has_val=False).compile()
+
+
+@pytest.mark.parametrize("case",
+                         ["serial", "shard_map", "masked", "boost_scan"])
+def test_split_loop_updates_the_histogram_cache_in_place(v5e, case):
+    """A split changes 2 of the cache's 255 rows; the v5e's compiler must
+    not copy the other 253.  It did, twice a split, while a read of the
+    OLD carry (``leaf_hist[new_id]``) could come after the first update
+    of it: 2.46 s of an Epsilon-shaped tree's 10.6 (PERF.md Findings,
+    PR 26).  The CPU's compiler copies the carry either way, so only this
+    compile can tell."""
+    from mmlspark_tpu.core.profiling import compiled_copies
+    cache = (_CACHE_L, _CACHE_F, _CACHE_B, 3)
+    copies = compiled_copies(_compile_grower(v5e, case))
+    assert [c for c in copies if c[1] == cache] == []

@@ -345,6 +345,59 @@ class TestSummarizeTrace:
         assert got["total_device_ms"] == pytest.approx(10.0)
 
 
+class TestCompiledCopies:
+    """``compiled_copies``: which copies a compiled program holds, read
+    from its text before anything runs."""
+
+    TEXT = """\
+HloModule jit_grow_tree, is_scheduled=true
+
+%fused_computation.9 (param_0: bf16[8192,200,48]) -> bf16[8192,200,48] {
+  %param_0 = bf16[8192,200,48]{2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %copy.12 = bf16[8192,200,48]{1,2,0:T(8,128)(2,1)} copy(%param_0)
+}
+
+%body (p: (s32[], f32[255,200,256,3])) -> (s32[], f32[255,200,256,3]) {
+  %copy.369 = f32[255,200,256,3]{3,2,1,0:T(8,128)} copy(%get-tuple-element.3981), metadata={op_name="jit(grow_tree)/while/body"}
+  %fusion.228 = f32[255,200,256,3]{3,2,1,0:T(8,128)} fusion(%copy.369, %x), kind=kLoop, calls=%fused_computation.1
+  %copy-start.3 = (u8[40000,200]{1,0}, u8[40000,200]{1,0}, u32[]) copy-start(%bins)
+  %copy-done.3 = u8[40000,200]{1,0} copy-done(%copy-start.3)
+  %copy.5 = s32[] copy(%i)
+  %copy.6 = token[] copy(%tok)
+}
+"""
+
+    def test_names_shapes_and_bytes_largest_first(self):
+        from mmlspark_tpu.core.profiling import compiled_copies
+        got = compiled_copies(self.TEXT)
+        assert got == [
+            ("copy.12", (8192, 200, 48), 8192 * 200 * 48 * 2),
+            ("copy.369", (255, 200, 256, 3), 255 * 200 * 256 * 3 * 4),
+            ("copy-start.3", (40000, 200), 40000 * 200),
+            ("copy.5", (), 4),
+        ]
+        # the fusion that consumes a copy and copy-done are not copies
+        assert all("fusion" not in n and "done" not in n for n, _, _ in got)
+
+    def test_min_bytes_keeps_the_copies_worth_asking_about(self):
+        from mmlspark_tpu.core.profiling import compiled_copies
+        got = compiled_copies(self.TEXT, min_bytes=100 << 20)
+        assert [n for n, _, _ in got] == ["copy.12", "copy.369"]
+
+    def test_reads_a_compiled_executable(self):
+        """Takes what ``.lower(...).compile()`` returns as well as its
+        text (which copies the CPU's compiler places is its business)."""
+        import jax
+        import jax.numpy as jnp
+        from mmlspark_tpu.core.profiling import compiled_copies
+        compiled = jax.jit(lambda x: x.T.reshape(-1)).lower(
+            jax.ShapeDtypeStruct((64, 32), jnp.float32)).compile()
+        rows = compiled_copies(compiled)
+        assert all(isinstance(n, str) and b == 4 * int(np.prod(sh))
+                   for n, sh, b in rows)
+        assert compiled_copies(compiled, min_bytes=1 << 30) == []
+
+
 # ---------------------------------------------------------------- journal
 
 
