@@ -18,10 +18,18 @@ the binned ``uint8``/``int32`` matrix is what ships to the TPU.
 
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import List, Optional
 
 import numpy as np
+
+#: a categorical column whose binned values stay under this is binned by
+#: table lookup (64 M int32 slots at most), above it by binary search
+_CAT_LUT_MAX = 1 << 26
+#: rows a thread bins at a time in ``transform_packed``'s categorical pass
+_CAT_BLOCK_ROWS = 1 << 20
 
 
 @dataclass
@@ -155,8 +163,20 @@ class BinMapper:
         native.bin_columns(Xc, bext, nb, base, lo, scale, use_table,
                            self.missing_bin, out)
         if self.has_categorical:
-            for j in np.nonzero(self.categorical)[0]:
-                out[:, j] = self._transform_cat(X[:, j], int(j))
+            # blocks of rows in threads, every categorical column of a
+            # block while it is warm: 26 whole-column passes over 3e7 rows
+            # were most of a click log's binning
+            cols = [int(j) for j in np.nonzero(self.categorical)[0]]
+            luts = {j: self._cat_lut(j) for j in cols}
+
+            def block(a):
+                b = min(a + _CAT_BLOCK_ROWS, X.shape[0])
+                for j in cols:
+                    out[a:b, j] = self._transform_cat(X[a:b, j], j,
+                                                      lut=luts[j])
+
+            with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
+                list(pool.map(block, range(0, X.shape[0], _CAT_BLOCK_ROWS)))
         return out
 
     def _transform_torch(self, X: np.ndarray, dt: np.dtype) -> np.ndarray:
@@ -199,11 +219,31 @@ class BinMapper:
                 out[nan_mask, j] = self.missing_bin
         return out
 
-    def _transform_cat(self, col: np.ndarray, j: int) -> np.ndarray:
+    def _cat_lut(self, j: int) -> Optional[np.ndarray]:
+        """Raw category value -> bin as one array lookup: ``lut[v]`` for
+        ``0 <= v < len(lut) - 1``, the last slot (every other value)
+        holding the missing bin.  None where the binned values reach too
+        high for a table (hashed ids near 2^31): binary search then."""
+        cats = np.asarray(self.cat_values[j]).astype(np.int64)
+        top = int(cats.max()) + 1 if len(cats) else 0
+        if top > _CAT_LUT_MAX:
+            return None
+        lut = np.full(top + 1, self.missing_bin, np.int32)
+        lut[cats] = np.arange(len(cats), dtype=np.int32)
+        return lut
+
+    def _transform_cat(self, col: np.ndarray, j: int,
+                       lut: Optional[np.ndarray] = None) -> np.ndarray:
+        vals = np.nan_to_num(col, nan=-1.0).astype(np.int64)
+        if lut is None:
+            lut = self._cat_lut(j)
+        if lut is not None:
+            other = len(lut) - 1
+            vals[(vals < 0) | (vals > other)] = other
+            return lut[vals]
         cats = self.cat_values[j]                       # bin -> raw value
         order = np.argsort(cats)
         sorted_cats = cats[order]
-        vals = np.nan_to_num(col, nan=-1.0).astype(np.int64)
         pos = np.searchsorted(sorted_cats, vals)
         pos = np.clip(pos, 0, len(sorted_cats) - 1)
         hit = sorted_cats[pos] == vals

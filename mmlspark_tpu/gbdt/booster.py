@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.profiler import get_profiler
 from .grower import TreeArrays
 from .binning import BinMapper
 
@@ -145,24 +146,27 @@ def host_tree_from_arrays(tree: TreeArrays, mapper: BinMapper,
     cat_boundaries = [0]
     cat_words: List[np.ndarray] = []
     if is_cat.any():
-        for i in np.flatnonzero(is_cat):
-            f_i = int(feat[i])
-            cats = mapper.cat_values[f_i]
-            bits = cat_bits[i]
-            left_bins = [b for b in range(len(cats))
-                         if (bits[b >> 5] >> (b & 31)) & 1]
-            left_cats = sorted(int(cats[b]) for b in left_bins)
-            missing_left = bool(
-                (bits[missing_bin >> 5] >> (missing_bin & 31)) & 1)
-            nwords = (max(left_cats, default=0) // 32) + 1
-            words = np.zeros(nwords, np.uint32)
-            for c in left_cats:
-                words[c >> 5] |= np.uint32(1) << np.uint32(c & 31)
-            dt[i] = 1 | (2 if missing_left else 0)
-            thr[i] = float(num_cat)       # index into cat_boundaries
-            cat_words.append(words)
-            cat_boundaries.append(cat_boundaries[-1] + nwords)
-            num_cat += 1
+        # bin bitsets -> LightGBM's bitsets over RAW category values: a
+        # split on a column of ten million values exports up to 316 000
+        # words, so the words are set by numpy, not by a Python loop
+        with get_profiler().region("train.cat_bitsets", words=0) as sp:
+            shifts = np.arange(32, dtype=np.uint32)
+            for i in np.flatnonzero(is_cat):
+                cats = mapper.cat_values[int(feat[i])]
+                in_set = ((cat_bits[i][:, None] >> shifts) & 1
+                          ).astype(bool).reshape(-1)
+                left_cats = np.asarray(cats, np.int64)[in_set[:len(cats)]]
+                nwords = int(left_cats.max(initial=0) // 32) + 1
+                words = np.zeros(nwords, np.uint32)
+                np.bitwise_or.at(
+                    words, left_cats >> 5,
+                    np.uint32(1) << (left_cats & 31).astype(np.uint32))
+                dt[i] = 1 | (2 if in_set[missing_bin] else 0)
+                thr[i] = float(num_cat)       # index into cat_boundaries
+                cat_words.append(words)
+                cat_boundaries.append(cat_boundaries[-1] + nwords)
+                num_cat += 1
+            sp["words"] = int(cat_boundaries[-1])
     return HostTree(
         split_feature=feat.astype(np.int32),
         threshold=thr,
@@ -758,7 +762,9 @@ def _arr_line(name: str, arr: np.ndarray) -> str:
         vals = " ".join(np.format_float_positional(
             v, precision=17, trim="0") for v in arr)
     else:
-        vals = " ".join(str(int(v)) for v in arr)
+        # a forest with categorical splits on wide columns holds tens of
+        # millions of bitset words: no Python-level loop over them
+        vals = " ".join(map(str, np.asarray(arr).tolist()))
     return f"{name}={vals}\n"
 
 

@@ -11,9 +11,12 @@ src/treelearner/serial_tree_learner.cpp, UNVERIFIED; SURVEY.md §7.)
 The model below counts the resident arrays of one device's shard for the
 dominant training path (the DataPartition grower inside the chunked
 scan), plus the largest transient the bucket-ladder compaction
-materializes.  It deliberately over-counts slightly (gradients and their
-gh-stack both appear) — a guard that errs a few percent high beats an
-OOM at iteration 40.
+materializes and the temporaries of the MXU histogram build.  Against the
+peaks measured on a v5e it reads 1.03x at 400 000 x 2000, 0.99x at
+1 183 747 x 968 and 1.07x at 30 000 000 x 39 (PERF.md;
+tests/test_budget.py holds the three cells).  It deliberately over-counts
+slightly (gradients and their gh-stack both appear) — a guard that errs
+a few percent high beats an OOM at iteration 40.
 """
 
 from __future__ import annotations
@@ -22,6 +25,9 @@ import os
 from typing import Dict, Optional
 
 import numpy as np
+
+#: rows the dot16 histogram build takes at a time (ops/histogram.py)
+HIST_CHUNK_ROWS = 8192
 
 
 def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
@@ -38,6 +44,8 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
                         num_class, chunk)
     costs: Dict[str, int] = {}
     costs["bins"] = n * f * bin_itemsize
+    # the (f, n) transposed copy the scans keep for split-column reads
+    costs["bins_transposed"] = n * f * bin_itemsize
     # scores + labels + weights + real/bag mask + row_order
     costs["row_vectors"] = n * 4 * (K + 4)
     # grad/hess (n, K) each + the (n, 3) gh stack the grower consumes
@@ -49,6 +57,12 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
     n_pow = 1 << (n - 1).bit_length() if n > 1 else 1
     bucket = max(min_bucket, n_pow)
     costs["bucket_transient"] = bucket * (f * bin_itemsize + 12)
+    # the MXU histogram build's temporaries for one chunk of rows: per
+    # feature 16 x 3 products in f32 and again as bf16 operands, and the
+    # 16-wide one-hot in bf16 (the TPU runtime reserves them with the
+    # program: 5.2 of the 9.18 GB measured at 400 000 x 2000, PERF.md)
+    costs["hist_build"] = min(n, HIST_CHUNK_ROWS) * f * 16 * (3 * 4 + 3 * 2
+                                                             + 2)
     # stacked per-chunk trees (C*K trees x ~14 L-sized f32/i32 fields)
     costs["chunk_trees"] = C * K * L * 14 * 4
     if bagging:

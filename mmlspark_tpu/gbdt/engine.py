@@ -1363,8 +1363,9 @@ def _pack_trees_stacked(stacked: TreeArrays) -> jnp.ndarray:
 
     A small device→host transfer costs its round trip whatever its
     size, so the whole forest crosses in ONE transfer instead of 12 per
-    tree.  int fields fit f32 exactly
-    (node/feature/bin ids ≪ 2^24); counts are already f32 on device.
+    tree.  int fields fit f32 exactly (node/feature/bin ids ≪ 2^24);
+    row counts are int32 and pass 2^24 on a large table, so they cross
+    like the bitset words, as two 16-bit halves.
     Packing happens *inside* jit so trees produced under shard_map (multi-
     device, replicated) are legal inputs — XLA inserts the resharding.
     """
@@ -1374,21 +1375,25 @@ def _pack_trees_stacked(stacked: TreeArrays) -> jnp.ndarray:
     # u32 words don't fit f32 exactly; ship two u16 halves (both exact)
     bits_lo = f32(bits & jnp.uint32(0xFFFF))
     bits_hi = f32(bits >> jnp.uint32(16))
+    node_count = stacked.node_count.astype(jnp.int32)
+    leaf_count = stacked.leaf_count.astype(jnp.int32)
     return jnp.concatenate([
         f32(stacked.num_leaves)[:, None],
         f32(stacked.node_feat), f32(stacked.node_bin),
         f32(stacked.node_left), f32(stacked.node_right),
         stacked.node_gain, stacked.node_value,
-        stacked.node_weight, stacked.node_count,
+        stacked.node_weight, f32(node_count & 0xFFFF),
         f32(stacked.node_is_cat),
-        stacked.leaf_value, stacked.leaf_weight, stacked.leaf_count,
-        bits_lo, bits_hi,
+        stacked.leaf_value, stacked.leaf_weight, f32(leaf_count & 0xFFFF),
+        bits_lo, bits_hi, f32(node_count >> 16), f32(leaf_count >> 16),
     ], axis=1)
 
 
-def _fetch_host_trees(chunks: List[TreeArrays], num_leaves: int,
-                      mapper: BinMapper) -> Tuple[List[HostTree], np.ndarray]:
-    """Batched device→host transfers → per-tree HostTrees + leaf counts.
+def _fetch_host_trees(chunks: List[TreeArrays], num_leaves: int
+                      ) -> Tuple[List[TreeArrays], np.ndarray]:
+    """Batched device→host transfers → per-tree host ``TreeArrays`` + leaf
+    counts (``host_tree_from_arrays`` makes HostTrees of them, in
+    ``train.finalize``).
 
     ``chunks``: stacked (C_i, ...) TreeArrays pytrees as produced by the
     scan steps — one packed transfer per chunk (typically one per fit)."""
@@ -1400,7 +1405,7 @@ def _fetch_host_trees(chunks: List[TreeArrays], num_leaves: int,
         sp["bytes"] = int(packed.nbytes)
         L, m = num_leaves, num_leaves - 1
         W = chunks[0].node_cat_bits.shape[-1]
-        offs = np.cumsum([1] + [m] * 9 + [L] * 3 + [m * W] * 2)
+        offs = np.cumsum([1] + [m] * 9 + [L] * 3 + [m * W] * 2 + [m, L])
         cols = [packed[:, a:b] for a, b in zip([0] + list(offs), offs)]
         nls = cols[0][:, 0].astype(np.int64)
         out = []
@@ -1413,13 +1418,16 @@ def _fetch_host_trees(chunks: List[TreeArrays], num_leaves: int,
                 node_left=cols[3][i].astype(np.int32),
                 node_right=cols[4][i].astype(np.int32),
                 node_gain=cols[5][i], node_value=cols[6][i],
-                node_weight=cols[7][i], node_count=cols[8][i],
+                node_weight=cols[7][i],
+                node_count=(cols[8][i].astype(np.int64)
+                            + (cols[15][i].astype(np.int64) << 16)),
                 node_is_cat=cols[9][i].astype(np.int32),
                 node_cat_bits=bits.reshape(m, W),
                 leaf_value=cols[10][i], leaf_weight=cols[11][i],
-                leaf_count=cols[12][i], num_leaves=nls[i])
-            out.append(host_tree_from_arrays(tree, mapper,
-                                             mapper.missing_bin))
+                leaf_count=(cols[12][i].astype(np.int64)
+                            + (cols[16][i].astype(np.int64) << 16)),
+                num_leaves=nls[i])
+            out.append(tree)
         return out, nls
 
 
@@ -1508,6 +1516,46 @@ def _bin_representatives(mapper: BinMapper) -> List[np.ndarray]:
     return reps
 
 
+def _bin_space_forest(booster: Booster, mapper: BinMapper) -> Booster:
+    """``booster`` with every categorical split's bitset over BIN indices
+    (one word per 32 bins) in place of raw category values, for walking
+    rows whose categorical columns hold their bin.  The same routing as the
+    raw-value forest on the bins' own values, but a forest on a column of
+    ten million values holds tens of millions of raw-value words, which
+    the margin pass below would upload and leave on the device, as much
+    as the seed's trees make it (PERF.md Findings, PR 27).  A forest
+    without categorical splits is returned as it is."""
+    import copy
+    import dataclasses
+    if not any(t.num_cat for t in booster.trees):
+        return booster
+    W = (mapper.num_total_bins + 31) // 32
+    trees = []
+    for t in booster.trees:
+        if not t.num_cat:
+            trees.append(t)
+            continue
+        words = np.zeros(t.num_cat * W, np.uint32)
+        for i in np.flatnonzero(t.decision_type & 1):
+            k = int(t.threshold[i])
+            raw = t.cat_threshold[t.cat_boundaries[k]:t.cat_boundaries[k + 1]]
+            cats = np.asarray(mapper.cat_values[int(t.split_feature[i])],
+                              np.int64)
+            at = np.minimum(cats >> 5, len(raw) - 1)
+            left = np.flatnonzero(
+                ((cats >> 5) < len(raw))
+                & ((raw[at] >> (cats & 31).astype(np.uint32)) & 1 > 0))
+            np.bitwise_or.at(words, k * W + (left >> 5),
+                             np.uint32(1) << (left & 31).astype(np.uint32))
+        trees.append(dataclasses.replace(
+            t, cat_threshold=words,
+            cat_boundaries=np.arange(t.num_cat + 1, dtype=np.int32) * W))
+    out = copy.copy(booster)
+    out.trees = trees
+    out.invalidate_cache()
+    return out
+
+
 def _capture_reference_profile(booster: Booster, bins, mapper,
                                feature_names) -> None:
     """Attach the fit-time data-quality baseline (ISSUE 15): per-feature
@@ -1539,8 +1587,14 @@ def _capture_reference_profile(booster: Booster, bins, mapper,
             reps = _bin_representatives(mapper)
             Xr = np.empty(sample.shape, np.float32)
             for j, rep in enumerate(reps):
+                if mapper.is_categorical(j):
+                    # its bin, for the bin-space forest; the trailing bin
+                    # (every other value) stays NaN and goes right
+                    rep = np.where(np.isnan(rep), np.nan,
+                                   np.arange(len(rep), dtype=np.float64))
                 Xr[:, j] = rep[sample[:, j].astype(np.int64)]
-            margins = np.asarray(booster.predict_margin(Xr))
+            margins = np.asarray(
+                _bin_space_forest(booster, mapper).predict_margin(Xr))
             booster.reference_profile = build_reference_profile(
                 bins, mapper, margins, feature_names=feature_names,
                 meta={"trees": len(booster.trees),
@@ -1598,7 +1652,7 @@ def train(*args, **kwargs) -> Booster:
             bins, mesh = _arg(0, "bins"), _arg(13, "mesh")
             _capture_reference_profile(booster, bins, _arg(3, "mapper"),
                                        _arg(6, "feature_names"))
-            sp.update(_fit_attrs(booster, bins, mesh))
+            sp.update(_fit_attrs(booster, bins, mesh, _arg(3, "mapper")))
             _tm.get_journal().emit(
                 "fit_end", fit=span,
                 dur_s=round(time.perf_counter() - t0, 3),
@@ -1608,11 +1662,14 @@ def train(*args, **kwargs) -> Booster:
     return booster
 
 
-def _fit_attrs(booster: Booster, bins, mesh) -> dict:
+def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
     """What the ``train.fit`` span says of its fit: the trees returned,
     the table's shape, the devices it ran on, and the collectives the
     grower's schedule counts for those trees (``last_fit_info``, per
-    tree, times the trees)."""
+    tree, times the trees).  A fit on a table with categorical columns
+    also says how many they are, how many of its trees' internal nodes
+    are categorical splits, and the u32 words of their raw-value
+    bitsets; a numeric fit carries none of the three."""
     shards = bins if isinstance(bins, (list, tuple)) else [bins]
     shapes = [np.shape(b) for b in shards if b is not None]
     trees = len(booster.trees)
@@ -1620,7 +1677,7 @@ def _fit_attrs(booster: Booster, bins, mesh) -> dict:
     def per_tree(key: str) -> int:
         return int(last_fit_info.get(key, 0)) * trees
 
-    return {
+    attrs = {
         "trees": trees,
         "rows": int(sum(sh[0] for sh in shapes)),
         "features": int(shapes[0][1]) if shapes else 0,
@@ -1628,6 +1685,13 @@ def _fit_attrs(booster: Booster, bins, mesh) -> dict:
         "collective_count": per_tree("collective_count_per_tree"),
         "collective_bytes": per_tree("collective_payload_bytes_per_tree"),
     }
+    if mapper is not None and mapper.has_categorical:
+        attrs.update(
+            cat_features=int(mapper.categorical.sum()),
+            cat_splits=int(sum(t.num_cat for t in booster.trees)),
+            cat_bitset_words=int(sum(len(t.cat_threshold)
+                                     for t in booster.trees)))
+    return attrs
 
 
 def train_incremental(bins: np.ndarray, labels: np.ndarray,
@@ -2866,11 +2930,15 @@ def _export_booster(chunks, K, stop_iter, init, params, objective, mapper,
     in the dart weights (``dart_scales``: one per ITERATION, shared by
     its K class trees) or the forest average (``rf``), and build the
     Booster."""
-    trees, nls = _fetch_host_trees(chunks, params.num_leaves, mapper)
+    trees, nls = _fetch_host_trees(chunks, params.num_leaves)
     with get_profiler().region("train.finalize"):
         trees, nls = trees[:stop_iter * K], nls[:stop_iter * K]
         trees, stop_iter = _truncate_no_growth(trees, nls, K, stop_iter,
                                                params.verbosity)
+        # real-valued thresholds and, for categorical splits, bitsets over
+        # raw values (``train.cat_bitsets``), for the trees that stay
+        trees = [host_tree_from_arrays(t, mapper, mapper.missing_bin)
+                 for t in trees]
         if dart_scales is not None:
             for t, s in zip(trees, np.repeat(dart_scales, K)):
                 t.leaf_value = t.leaf_value * s

@@ -165,6 +165,12 @@ class GrowerConfig:
         return max(1, (self.num_bins + 31) // 32)
 
 
+#: a tree's row counts are int32 and exact up to this many rows a shard
+#: (they were float32 sums, exact to 2^24, before PR 27); a driver that
+#: holds exported counts to the raw rows reads it before a large fit
+EXACT_COUNT_ROWS = 2 ** 31 - 1
+
+
 class TreeArrays(NamedTuple):
     """One grown tree.  Children encoding matches LightGBM: a child value
     ``c >= 0`` is an internal node index, ``c < 0`` is leaf ``~c``."""
@@ -175,12 +181,12 @@ class TreeArrays(NamedTuple):
     node_gain: jnp.ndarray    # (L-1,) f32
     node_value: jnp.ndarray   # (L-1,) f32 internal output (shrinkage applied)
     node_weight: jnp.ndarray  # (L-1,) f32 sum of hessians
-    node_count: jnp.ndarray   # (L-1,) f32 row count
+    node_count: jnp.ndarray   # (L-1,) i32 row count, exact
     node_is_cat: jnp.ndarray  # (L-1,) i32 1 = categorical split
     node_cat_bits: jnp.ndarray  # (L-1, W) u32 bin-bitset: bit set -> left
     leaf_value: jnp.ndarray   # (L,) f32 (shrinkage applied)
     leaf_weight: jnp.ndarray  # (L,) f32
-    leaf_count: jnp.ndarray   # (L,) f32
+    leaf_count: jnp.ndarray   # (L,) i32
     num_leaves: jnp.ndarray   # () i32 actual leaves grown
 
 
@@ -195,7 +201,7 @@ class _GrowState(NamedTuple):
     leaf_hist: jnp.ndarray    # (L, f, B, 3)
     leaf_g: jnp.ndarray       # (L,)
     leaf_h: jnp.ndarray       # (L,)
-    leaf_c: jnp.ndarray       # (L,)
+    leaf_c: jnp.ndarray       # (L,) i32
     leaf_depth: jnp.ndarray   # (L,) i32
     leaf_parent: jnp.ndarray  # (L,) i32 (-1 for root)
     leaf_is_right: jnp.ndarray  # (L,) bool
@@ -295,6 +301,7 @@ def _cat_split_gains(hist, parent_g, parent_h, parent_c, cat_allowed,
     return gains_cat, order, use_onehot
 
 
+@jax.named_scope("cat_scan")
 def _find_best_cat_split(hist, parent_g, parent_h, parent_c, cat_allowed,
                          feat_nbins, cfg: GrowerConfig):
     """Best categorical split: per-feature gradient-ratio-sorted subset scan
@@ -542,9 +549,10 @@ def _voting_votes(hist_local, feat_info, depth_ok, num_mask, cat_allowed,
                                       depth_ok, cfg)
     score_f = jnp.max(gains_loc, axis=1)
     if cfg.use_categorical:
-        gains_cat_loc, _, _ = _cat_split_gains(
-            hist_local, s_loc[0], s_loc[1], s_loc[2], cat_allowed,
-            feat_info[:, 2], cfg)
+        with jax.named_scope("cat_scan"):
+            gains_cat_loc, _, _ = _cat_split_gains(
+                hist_local, s_loc[0], s_loc[1], s_loc[2], cat_allowed,
+                feat_info[:, 2], cfg)
         score_f = jnp.maximum(score_f, jnp.max(gains_cat_loc, axis=1))
     _, votes = jax.lax.top_k(score_f, min(cfg.voting_k, f))
     return votes
@@ -878,36 +886,36 @@ def _segment_hist_dist(bins, gh, row_order, off, cnt, n, sizes,
 
 def _leaf_of_position(leaf_start, leaf_cnt, n):
     """(n,) leaf id per row_order position, from the leaves' contiguous
-    segments: scatter each non-empty leaf's id at its start position, then
-    forward-fill with an associative last-set-wins scan."""
+    segments: a mark at each non-empty leaf's start, a running count of
+    the marks (the position's rank among the segments), and the leaf of
+    that rank by start.  (An ``associative_scan`` forward fill gave the
+    same ids and cost the v5e's compiler more than 40 minutes and 36 GB
+    at 3e7 rows: PERF.md Findings, PR 27.)"""
     idx = jnp.where(leaf_cnt > 0, leaf_start, n)   # empty leaves dropped
-    k1 = jnp.full(n, -1, jnp.int32).at[idx].set(
-        leaf_start.astype(jnp.int32), mode="drop")
-    payload = jnp.zeros(n, jnp.int32).at[idx].set(
-        jnp.arange(leaf_start.shape[0], dtype=jnp.int32), mode="drop")
-
-    def comb(a, b):
-        k1a, pa = a
-        k1b, pb = b
-        t = k1b >= k1a
-        return jnp.where(t, k1b, k1a), jnp.where(t, pb, pa)
-
-    _, leaf_of_p = jax.lax.associative_scan(comb, (k1, payload))
-    return leaf_of_p
+    by_start = jnp.argsort(idx).astype(jnp.int32)
+    marks = jnp.zeros(n, jnp.int32).at[idx].set(1, mode="drop")
+    return by_start[jnp.cumsum(marks) - 1]
 
 
 def _totals_from_hist(hist):
-    """Leaf totals via any one feature's bins (they partition the rows)."""
+    """Leaf totals via any one feature's bins (they partition the rows).
+    The count is summed as int32: a float32 sum stops being exact at 2^24
+    rows, and a table of 3e7 rows exported counts that were off by one or
+    two at its eleven largest nodes (PERF.md Findings, PR 27).  Each bin's
+    own count is a float32 below 2^24, hence exact."""
     s = jnp.sum(hist[0], axis=0)             # (3,)
-    return s[0], s[1], s[2]
+    return s[0], s[1], jnp.sum(hist[0, :, 2].astype(jnp.int32))
 
 
 def _global_totals(g, h, c, cfg: GrowerConfig):
     """Leaf totals are global quantities; under voting the histograms stay
     local, so the (3,) totals are psum-reduced explicitly."""
     if _is_voting(cfg):
-        tot = jax.lax.psum(jnp.stack([g, h, c]), cfg.axis_name)
-        return tot[0], tot[1], tot[2]
+        # one float32 triple on the wire, as before: under voting the
+        # count is exact below 2^24 rows a node
+        tot = jax.lax.psum(jnp.stack([g, h, c.astype(jnp.float32)]),
+                           cfg.axis_name)
+        return tot[0], tot[1], jnp.round(tot[2]).astype(jnp.int32)
     return g, h, c
 
 
@@ -918,6 +926,7 @@ def _find_split(hist, pg, ph, pc, fi, depth_ok, cfg: GrowerConfig,
     raw int32 codes; voting forwards it so the candidate slab crosses
     the wire low-bit, every other path dequantizes up front — the gain
     math is unchanged f32 by construction."""
+    pc = pc.astype(jnp.float32)      # the leaf's exact int32 row count
     if _is_voting(cfg):
         return find_best_split_voting(hist, pg, ph, pc, fi, depth_ok, cfg,
                                       deq=deq)
@@ -1065,8 +1074,7 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
         if qscale is None:
             return g, h, c
         return (g.astype(jnp.float32) * qscale[0],
-                h.astype(jnp.float32) * qscale[1],
-                c.astype(jnp.float32))
+                h.astype(jnp.float32) * qscale[1], c)
 
     n = bins.shape[0]
     # under EFB bins holds G bundle columns; histograms, feat_info and
@@ -1119,13 +1127,13 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
         node_gain=jnp.zeros(L - 1, jnp.float32),
         node_value=jnp.zeros(L - 1, jnp.float32),
         node_weight=jnp.zeros(L - 1, jnp.float32),
-        node_count=jnp.zeros(L - 1, jnp.float32),
+        node_count=jnp.zeros(L - 1, jnp.int32),
         node_is_cat=jnp.zeros(L - 1, jnp.int32),
         node_cat_bits=jnp.zeros((L - 1, W), jnp.uint32),
         leaf_value=jnp.zeros(L, jnp.float32).at[0].set(
             _leaf_output(g0, h0, cfg)),
         leaf_weight=jnp.zeros(L, jnp.float32).at[0].set(h0),
-        leaf_count=jnp.zeros(L, jnp.float32).at[0].set(c0),
+        leaf_count=jnp.zeros(L, jnp.int32).at[0].set(c0),
         num_leaves=jnp.asarray(1, jnp.int32),
     )
     if cfg.compact_rows:
@@ -1150,7 +1158,7 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                             ).at[0].set(hist0),
         leaf_g=jnp.zeros(L, jnp.float32).at[0].set(g0),
         leaf_h=jnp.zeros(L, jnp.float32).at[0].set(h0),
-        leaf_c=jnp.zeros(L, jnp.float32).at[0].set(c0),
+        leaf_c=jnp.zeros(L, jnp.int32).at[0].set(c0),
         leaf_depth=jnp.zeros(L, jnp.int32),
         leaf_parent=jnp.full(L, -1, jnp.int32),
         leaf_is_right=jnp.zeros(L, bool),
@@ -1278,9 +1286,10 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 ((bg_l, bf_l, bb_l, bc_l, bits_l),
                  (bg_r, bf_r, bb_r, bc_r, bits_r)) = \
                     find_best_split_voting_pair(
-                        hist_l, hist_r, (g_l, h_l, c_l),
-                        (g_r, h_r, c_r), feat_info, depth_ok, cfg,
-                        deq=deq)
+                        hist_l, hist_r,
+                        (g_l, h_l, c_l.astype(jnp.float32)),
+                        (g_r, h_r, c_r.astype(jnp.float32)),
+                        feat_info, depth_ok, cfg, deq=deq)
             else:
                 bg_l, bf_l, bb_l, bc_l, bits_l = _find_split(
                     hist_l, g_l, h_l, c_l, feat_info, depth_ok, cfg,

@@ -423,12 +423,6 @@ def _hist_dot16(bins, gh, num_bins, row_chunk, acc_dtype=jnp.float32):
     n_hi = (num_bins + 15) // 16
     gh = gh.astype(acc_dtype)
     chunk = min(row_chunk, n)
-    pad = (-n) % chunk
-    if pad:
-        bins = jnp.pad(bins, ((0, pad), (0, 0)))
-        gh = jnp.pad(gh, ((0, pad), (0, 0)))
-    bins_c = bins.reshape(-1, chunk, f)
-    gh_c = gh.reshape(-1, chunk, GH_CHANNELS)
     lo_iota = jnp.arange(16)
     hi_iota = jnp.arange(n_hi)
 
@@ -452,6 +446,22 @@ def _hist_dot16(bins, gh, num_bins, row_chunk, acc_dtype=jnp.float32):
             f, n_hi * 16, GH_CHANNELS)
         return acc + out[:, :num_bins], None
 
-    init = jnp.zeros((f, num_bins, GH_CHANNELS), acc_dtype)
-    out, _ = jax.lax.scan(step, init, (bins_c, gh_c))
+    # Whole chunks are sliced out of the table inside the loop and the
+    # tail (fewer rows than a chunk) is padded alone and added last: the
+    # sums, and their order, of scanning a padded, reshaped copy of the
+    # table.  That scan cost the v5e's compiler 20 s and 1 GB of host
+    # memory per million rows whenever n was not a power of two (15
+    # minutes and over 30 GB at 3e7 rows: PERF.md Findings, PR 27).
+    def body(i, acc):
+        b = jax.lax.dynamic_slice(bins, (i * chunk, 0), (chunk, f))
+        g = jax.lax.dynamic_slice(gh, (i * chunk, 0), (chunk, GH_CHANNELS))
+        return step(acc, (b, g))[0]
+
+    out = jax.lax.fori_loop(
+        0, n // chunk, body, jnp.zeros((f, num_bins, GH_CHANNELS), acc_dtype))
+    head = (n // chunk) * chunk
+    if head < n:
+        pad = ((0, chunk - (n - head)), (0, 0))
+        out = step(out, (jnp.pad(bins[head:], pad),
+                         jnp.pad(gh[head:], pad)))[0]
     return out

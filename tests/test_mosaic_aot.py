@@ -90,8 +90,9 @@ def _compile_grower(v5e, case):
     one = SingleDeviceSharding(v5e[0])
     base = dict(num_leaves=_CACHE_L, num_bins=_CACHE_B, hist_method="dot16",
                 min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0)
-    if case in ("serial", "masked"):
-        cfg = GrowerConfig(compact_rows=(case == "serial"), **base)
+    if case in ("serial", "masked", "categorical"):
+        cfg = GrowerConfig(compact_rows=(case != "masked"),
+                           use_categorical=(case == "categorical"), **base)
         return grow_tree.lower(
             _sds((n, f), jnp.uint8, one), _sds((n, 3), jnp.float32, one),
             _sds((f, 3), jnp.float32, one), cfg).compile()
@@ -124,8 +125,8 @@ def _compile_grower(v5e, case):
         has_val=False).compile()
 
 
-@pytest.mark.parametrize("case",
-                         ["serial", "shard_map", "masked", "boost_scan"])
+@pytest.mark.parametrize("case", ["serial", "shard_map", "masked",
+                                  "boost_scan", "categorical"])
 def test_split_loop_updates_the_histogram_cache_in_place(v5e, case):
     """A split changes 2 of the cache's 255 rows; the v5e's compiler must
     not copy the other 253.  It did, twice a split, while a read of the
