@@ -11,9 +11,11 @@ in-package capability rather than a side tool:
 * :func:`summarize_trace` — parse the written ``.xplane.pb`` (no
   TensorBoard needed) into device self time by the program's named
   scopes; ``tools/profile_boost_step.py`` prints it.
-* :func:`compiled_copies` — the ``copy`` instructions of a compiled
-  program, read from its text: what a loop's carry costs when it is not
-  updated in place, known before anything runs.
+* :func:`compiled_instructions` / :func:`compiled_copies` — the
+  instructions of a compiled program over a byte threshold (its ``copy``
+  instructions), read from its text: what a loop's carry costs when it
+  is not updated in place, which temporaries a kernel's formulation
+  makes, known before anything runs.
 * ``LightGBMBase.setProfileTraceDir(dir)`` — traces the whole ``fit``
   (engine hooks through :func:`maybe_trace`).
 
@@ -523,41 +525,62 @@ def _hlo_base(name: str) -> str:
     return re.sub(r"(?:\.\d+|\.clone)+$", "", head) or head
 
 
-#: bytes per element of the HLO element types a copy may carry
+#: bytes per element of the HLO element types an instruction may carry
 _HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
                  "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
                  "s32": 4, "u32": 4, "f32": 4,
                  "s64": 8, "u64": 8, "f64": 8}
-#: ``%copy.470 = f32[255,2000,256,3]{3,2,1,0:T(8,128)} copy(...)``; an
-#: asynchronous ``copy-start`` yields a tuple whose first element is the
-#: destination, so the first shape after ``=`` is the one that moves
-_COPY_LINE = re.compile(
+#: ``%copy.470 = f32[255,2000,256,3]{3,2,1,0:T(8,128)} copy(...)``: name,
+#: element type, dimensions, opcode.  An asynchronous ``copy-start``
+#: yields a tuple whose first element is the destination, so the first
+#: shape after ``=`` is the one that is written
+_HLO_LINE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\(?(\w+)\[([\d,]*)\][^=]*?"
-    r"\scopy(?:-start)?\(")
+    r"\s([a-z][\w\-]*)\(")
 
 
-def compiled_copies(compiled, min_bytes: int = 0
-                    ) -> List[Tuple[str, Tuple[int, ...], int]]:
-    """The ``copy`` instructions of a compiled program that move at least
-    ``min_bytes``: ``[(name, shape, bytes), ...]``, largest first.
+def compiled_instructions(compiled, min_bytes: int = 0,
+                          opcodes: Optional[Tuple[str, ...]] = None
+                          ) -> List[Tuple[str, Tuple[int, ...], int]]:
+    """The instructions of a compiled program whose result holds at least
+    ``min_bytes``: ``[(name, shape, bytes), ...]``, largest first;
+    ``opcodes`` keeps only those HLO opcodes.
 
     ``compiled`` is what ``jax.jit(f).lower(...).compile()`` returns (or
-    its ``as_text()``), for the backend that will run it: a loop's carry
-    that XLA cannot update in place shows here as a copy of the carry's
-    shape in the loop's body, before anything runs (PERF.md Findings,
-    PR 26: two ``f32[255,2000,256,3]`` copies a split were 23% of a
-    fit).  Fused computations are scanned too, so a copy folded into a
-    fusion still counts.  The CPU's compiler places copies differently;
-    ask the compiler of the device you mean (``jax.experimental.
-    topologies`` describes a TPU that is not attached)."""
+    its ``as_text()``), for the backend that will run it.  Fused
+    computations are scanned too, so an array that lives only inside a
+    fusion (never in HBM) is listed like one that is written out: ask
+    ``memory_analysis()`` for what the program holds, and this for what
+    it computes (PERF.md Findings, PR 28: an ``f32[8192,2000,16,3]``
+    broadcast per chunk of the histogram build).  Parameters and
+    ``get-tuple-element`` name an array, they do not make one, and are
+    left out."""
     text = compiled if isinstance(compiled, str) else compiled.as_text()
     out = []
     for line in text.splitlines():
-        m = _COPY_LINE.match(line)
+        m = _HLO_LINE.match(line)
         if m is None or m.group(2) not in _HLO_ITEMSIZE:
+            continue
+        op = m.group(4)
+        if op in ("parameter", "get-tuple-element") or (
+                opcodes is not None and op not in opcodes):
             continue
         shape = tuple(int(d) for d in m.group(3).split(",") if d)
         nbytes = math.prod(shape) * _HLO_ITEMSIZE[m.group(2)]
         if nbytes >= min_bytes:
             out.append((m.group(1), shape, nbytes))
     return sorted(out, key=lambda r: -r[2])
+
+
+def compiled_copies(compiled, min_bytes: int = 0
+                    ) -> List[Tuple[str, Tuple[int, ...], int]]:
+    """The ``copy`` instructions of a compiled program that move at least
+    ``min_bytes`` (:func:`compiled_instructions`): a loop's carry that
+    XLA cannot update in place shows here as a copy of the carry's shape
+    in the loop's body, before anything runs (PERF.md Findings, PR 26:
+    two ``f32[255,2000,256,3]`` copies a split were 23% of a fit).  The
+    CPU's compiler places copies differently; ask the compiler of the
+    device you mean (``jax.experimental.topologies`` describes a TPU that
+    is not attached)."""
+    return compiled_instructions(compiled, min_bytes,
+                                 opcodes=("copy", "copy-start"))

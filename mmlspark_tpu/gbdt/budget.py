@@ -11,10 +11,13 @@ src/treelearner/serial_tree_learner.cpp, UNVERIFIED; SURVEY.md §7.)
 The model below counts the resident arrays of one device's shard for the
 dominant training path (the DataPartition grower inside the chunked
 scan), plus the largest transient the bucket-ladder compaction
-materializes and the temporaries of the MXU histogram build.  Against the
-peaks measured on a v5e it reads 1.03x at 400 000 x 2000, 0.99x at
-1 183 747 x 968 and 1.07x at 30 000 000 x 39 (PERF.md;
-tests/test_budget.py holds the three cells).  It deliberately over-counts
+materializes and what the MXU histogram build holds beside it: XLA's
+formulation its one-hot temporaries, the Mosaic build (PR 28) only the
+bucket's transposed copy.  Against the peaks measured on a v5e it reads
+0.98x at 400 000 x 2000 and 0.96x at 1 183 747 x 968 with the kernel
+(1.03x and 0.99x of the older peaks with XLA's build), and 1.48x at
+30 000 000 x 39, where XLA keeps no second copy of a narrow table
+(PERF.md; tests/test_budget.py holds the cells).  It deliberately over-counts
 slightly (gradients and their gh-stack both appear) — a guard that errs
 a few percent high beats an OOM at iteration 40.
 """
@@ -34,11 +37,15 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
                        num_leaves: int, num_class: int = 1,
                        chunk: int = 64, bin_itemsize: int = 1,
                        bagging: bool = False, n_val_local: int = 0,
-                       min_bucket: int = 2048) -> Dict[str, int]:
+                       min_bucket: int = 2048,
+                       hist_on_chip: bool = False) -> Dict[str, int]:
     """Per-device resident-bytes breakdown for one training fit.
 
     ``n_local``: this device's row count (global rows / data-mesh size).
-    Returns a dict of named costs plus ``"total"``.
+    ``hist_on_chip``: the fit's histogram call sites all compile the
+    build that keeps its one-hots in VMEM (``grower.
+    hist_build_schedule``).  Returns a dict of named costs plus
+    ``"total"``.
     """
     n, f, B, L, K, C = (n_local, num_features, num_bins, num_leaves,
                         num_class, chunk)
@@ -63,6 +70,10 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
     # program: 5.2 of the 9.18 GB measured at 400 000 x 2000, PERF.md)
     costs["hist_build"] = min(n, HIST_CHUNK_ROWS) * f * 16 * (3 * 4 + 3 * 2
                                                              + 2)
+    if hist_on_chip:
+        # the kernel holds nothing of that: what stays is the bucket a
+        # second time, transposed on its way in (it reads rows-minor)
+        costs["hist_build"] = bucket * f * bin_itemsize
     # stacked per-chunk trees (C*K trees x ~14 L-sized f32/i32 fields)
     costs["chunk_trees"] = C * K * L * 14 * 4
     if bagging:
@@ -98,7 +109,8 @@ def check_fit_budget(n_local: int, num_features: int, num_bins: int,
                      num_leaves: int, num_class: int = 1, chunk: int = 64,
                      bin_itemsize: int = 1, bagging: bool = False,
                      n_val_local: int = 0, data_shards: int = 1,
-                     verbosity: int = 1) -> Dict[str, int]:
+                     verbosity: int = 1,
+                     hist_on_chip: bool = False) -> Dict[str, int]:
     """Estimate, log, and fail FAST when the fit cannot fit.
 
     Raises ``MemoryError`` with the breakdown and concrete remediations
@@ -107,7 +119,7 @@ def check_fit_budget(n_local: int, num_features: int, num_bins: int,
     """
     costs = estimate_fit_bytes(
         n_local, num_features, num_bins, num_leaves, num_class, chunk,
-        bin_itemsize, bagging, n_val_local)
+        bin_itemsize, bagging, n_val_local, hist_on_chip=hist_on_chip)
     cap = device_capacity_bytes()
     if verbosity > 0:
         import logging

@@ -29,9 +29,9 @@ from ..core.profiling import StageStats
 from .binning import BinMapper, fit_bin_mapper
 from .booster import Booster, HostTree, host_tree_from_arrays
 from .grower import (EFBArrays, GrowerConfig, TreeArrays, apply_shrinkage,
-                     collective_schedule, grow_tree, predict_tree_binned,
-                     predict_tree_binned_any, predict_tree_binned_efb,
-                     _grow_tree_impl)
+                     collective_schedule, grow_tree, hist_build_schedule,
+                     predict_tree_binned, predict_tree_binned_any,
+                     predict_tree_binned_efb, _grow_tree_impl)
 from .objectives import Objective, MulticlassObjective
 
 
@@ -143,7 +143,8 @@ last_fit_info: Dict[str, str] = {}
 def _record_fit_resolution(cfg, collective: str,
                            downgrade: str = "none",
                            sched: Optional[dict] = None,
-                           quantized_downgrade: str = "none") -> None:
+                           quantized_downgrade: str = "none",
+                           hist_sched: Optional[dict] = None) -> None:
     last_fit_info.clear()
     last_fit_info.update(histogram_method=cfg.hist_method,
                          collective=collective,
@@ -166,6 +167,22 @@ def _record_fit_resolution(cfg, collective: str,
         if sched.get("quantized_scale_bytes"):
             last_fit_info.update(quantized_scale_bytes_per_tree=str(
                 sched["quantized_scale_bytes"]))
+    if hist_sched is not None:
+        # which build of the histogram the fit's programs compile, and
+        # at how many of a tree's call sites (root + bucket rungs) its
+        # one-hot product stays on the chip (grower.hist_build_schedule)
+        last_fit_info.update(
+            hist_build=hist_sched["build"],
+            hist_build_rungs=f"{hist_sched['fused']}/{hist_sched['sites']}")
+
+
+def _hist_sched_for(cfg, mesh, n: int) -> dict:
+    """The histogram builds of this fit's call sites, at the rows one
+    shard holds (the bucket ladder is built over those)."""
+    if mesh is not None:
+        from ..core.mesh import DATA_AXIS
+        n = -(-n // max(1, int(mesh.shape[DATA_AXIS])))
+    return hist_build_schedule(cfg, n)
 
 
 def _collective_sched_for(cfg, mesh, n: int, f: int) -> dict:
@@ -1666,10 +1683,12 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
     """What the ``train.fit`` span says of its fit: the trees returned,
     the table's shape, the devices it ran on, and the collectives the
     grower's schedule counts for those trees (``last_fit_info``, per
-    tree, times the trees).  A fit on a table with categorical columns
-    also says how many they are, how many of its trees' internal nodes
-    are categorical splits, and the u32 words of their raw-value
-    bitsets; a numeric fit carries none of the three."""
+    tree, times the trees), and which build of the histogram its
+    programs compiled at how many of a tree's call sites.  A fit on a
+    table with categorical columns also says how many they are, how many
+    of its trees' internal nodes are categorical splits, and the u32
+    words of their raw-value bitsets; a numeric fit carries none of the
+    three."""
     shards = bins if isinstance(bins, (list, tuple)) else [bins]
     shapes = [np.shape(b) for b in shards if b is not None]
     trees = len(booster.trees)
@@ -1684,6 +1703,8 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
         "devices": int(mesh.devices.size) if mesh is not None else 1,
         "collective_count": per_tree("collective_count_per_tree"),
         "collective_bytes": per_tree("collective_payload_bytes_per_tree"),
+        "hist_build": last_fit_info.get("hist_build", ""),
+        "hist_build_rungs": last_fit_info.get("hist_build_rungs", ""),
     }
     if mapper is not None and mapper.has_categorical:
         attrs.update(
@@ -1836,8 +1857,9 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         quantized_max_code=qmc, quantized_wire=qwire,
         debug_checks=_debug.debug_enabled())
     coll_sched = _collective_sched_for(cfg, mesh, n, f)
+    hist_sched = _hist_sched_for(cfg, mesh, n)
     _record_fit_resolution(cfg, collective, coll_downgrade, coll_sched,
-                           quantized_downgrade=qdown)
+                           quantized_downgrade=qdown, hist_sched=hist_sched)
 
     if params.boosting not in ("gbdt", "goss", "dart", "rf"):
         raise NotImplementedError(
@@ -1913,7 +1935,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         bagging=_bagging,
         n_val_local=(-(-val_bins.shape[0] // _dn)
                      if val_bins is not None else 0),
-        data_shards=_dn, verbosity=params.verbosity)
+        data_shards=_dn, verbosity=params.verbosity,
+        hist_on_chip=hist_sched["fused"] == hist_sched["sites"])
     if use_mesh:
         if ranking_info is not None:
             if init_scores is not None:
@@ -2513,10 +2536,11 @@ def _train_distributed_sharded(bins_shards, label_shards, weight_shards,
 
     from .budget import check_fit_budget
     f_sh = next(b.shape[1] for b in bins_shards if b is not None)
+    hist_sched = hist_build_schedule(cfg, max(sizes))
     _record_fit_resolution(
         cfg, collective, coll_downgrade,
         _collective_sched_for(cfg, mesh, sum(sizes), f_sh),
-        quantized_downgrade=qdown)
+        quantized_downgrade=qdown, hist_sched=hist_sched)
     _bagging = params.bagging_freq > 0 and params.bagging_fraction < 1.0
     _chunk = params.num_iterations
     if _bagging:
@@ -2537,7 +2561,8 @@ def _train_distributed_sharded(bins_shards, label_shards, weight_shards,
         bagging=_bagging,
         n_val_local=(-(-val_bins.shape[0] // int(mesh.shape["data"]))
                      if val_bins is not None else 0),
-        data_shards=int(mesh.shape["data"]), verbosity=params.verbosity)
+        data_shards=int(mesh.shape["data"]), verbosity=params.verbosity,
+        hist_on_chip=hist_sched["fused"] == hist_sched["sites"])
     shard_data = {"bins_shards": list(bins_shards),
                   "label_shards": list(label_shards),
                   "weight_shards": list(weight_shards),

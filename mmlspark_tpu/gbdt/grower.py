@@ -817,8 +817,13 @@ def _segment_hist(bins, gh, row_order, off, cnt, n, sizes,
                     b_sub = parts.reshape(size, -1)[:, :f_cols] \
                         .astype(jnp.int32)
                 else:
-                    b_sub = jnp.take(bins, rows, axis=0)
-                gh_sub = jnp.take(gh, rows, axis=0) * \
+                    # rows are clamped above: "clip" says so, and spares
+                    # the bucket the fill mode's select, a whole pass
+                    # over it that also kept XLA from handing the
+                    # histogram kernel its rows-minor layout straight
+                    # from the gather (PERF.md Findings, PR 28)
+                    b_sub = jnp.take(bins, rows, axis=0, mode="clip")
+                gh_sub = jnp.take(gh, rows, axis=0, mode="clip") * \
                     valid.astype(gh.dtype)[:, None]
             with jax.named_scope("segment_hist"):
                 return compute_histogram(b_sub, gh_sub, cfg.num_bins,
@@ -952,6 +957,23 @@ def _find_split(hist, pg, ph, pc, fi, depth_ok, cfg: GrowerConfig,
             return (gain, feat, b, jnp.asarray(0, jnp.int32),
                     jnp.zeros(cfg.cat_words, jnp.uint32))
     return find_best_split(hist, pg, ph, pc, fi, depth_ok, cfg)
+
+
+def hist_build_schedule(cfg: GrowerConfig, n_rows: int) -> dict:
+    """Which build of the histogram a tree's call sites compile, from the
+    shapes alone: the root (``n_rows`` rows a shard) and, where rows are
+    compacted, each rung of the bucket ladder.  ``build`` names the
+    implementations (``ops.histogram.histogram_build``; several joined by
+    ``+`` if the sites differ), ``fused`` counts the sites whose one-hot
+    product stays on the chip, ``sites`` all of them."""
+    from ..ops.histogram import histogram_build
+    sites = [n_rows] + (_bucket_sizes(n_rows, cfg) if cfg.compact_rows
+                        else [])
+    names = [histogram_build(cfg.hist_method, s, cfg.num_bins,
+                             _is_quantized(cfg)) for s in sites]
+    return {"build": "+".join(sorted(set(names))),
+            "fused": sum(n == "dot16/mosaic" for n in names),
+            "sites": len(sites)}
 
 
 def collective_schedule(cfg: GrowerConfig, f: int, *,

@@ -16,6 +16,12 @@ one primitive TPUs dislike, so three formulations are provided:
     ``loᵀ @ (hi ⊗ gh)`` that run on the MXU with 16× less transient memory
     than a naive 256-wide one-hot.  FLOPs are identical to the naive one-hot
     (n·B per channel) but the working set stays in VMEM-sized chunks.
+    On the TPU, for float gradients and at most 256 bins, the one-hot
+    operands are made where the contraction consumes them
+    (``pallas_histogram.histogram_dot16``): XLA's own program writes them
+    to HBM, 840 bytes a cell whose bin is one byte (PERF.md, PR 28).
+    :func:`_hist_dot16` stays as the definition of the result, and as the
+    build of every other case.
 
 ``onehot``
     Naive one-hot einsum, row/feature chunked.  Reference implementation for
@@ -286,6 +292,31 @@ def _auto_method(n_rows: Optional[int] = None) -> str:
     return "dot16" if backend == "tpu" else "segment"
 
 
+def _dot16_on_chip(num_bins: int, quantized: bool) -> bool:
+    """Whether a ``dot16`` call site compiles the Mosaic build: on the
+    TPU, float gradients (this stack refuses the int32 kernel: ``Bad
+    lhs/rhs type vector<128x128xi32>``), nibbles that cover the bins.
+    Rows and features do not enter: at every rung and root of the
+    benchmark's cells the kernel takes 0.07–0.15 ns a cell where XLA's
+    formulation takes 0.30–1.33 (the sweep on the chip: PERF.md
+    Findings, PR 28)."""
+    return (jax.default_backend() == "tpu" and not quantized
+            and num_bins <= 256)
+
+
+def histogram_build(method: str, n_rows: int, num_bins: int,
+                    quantized: bool) -> str:
+    """The implementation :func:`compute_histogram` compiles for a call
+    site of ``n_rows`` rows: the resolved method, and for ``dot16`` which
+    of its two builds (``dot16/mosaic``, ``dot16/xla``)."""
+    if method == "auto":
+        method = _auto_method(n_rows)
+    if method == "dot16":
+        return ("dot16/mosaic" if _dot16_on_chip(num_bins, quantized)
+                else "dot16/xla")
+    return method
+
+
 def compute_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
                       method: str = "auto",
                       row_chunk: int = 8192,
@@ -324,6 +355,11 @@ def compute_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
     if method == "segment":
         return _hist_segment(bins, gh, num_bins, acc_dtype)
     if method == "dot16":
+        if _dot16_on_chip(num_bins, quantized):
+            from .pallas_histogram import histogram_dot16
+            # the TPU's XLA keeps a uint8 table rows-minor: no copy here
+            return histogram_dot16(bins.T, gh, num_bins,
+                                   interpret=pallas_interpret())
         return _hist_dot16(bins, gh, num_bins, row_chunk, acc_dtype)
     if method == "onehot":
         return _hist_onehot(bins, gh, num_bins, row_chunk, acc_dtype)
@@ -426,8 +462,7 @@ def _hist_dot16(bins, gh, num_bins, row_chunk, acc_dtype=jnp.float32):
     lo_iota = jnp.arange(16)
     hi_iota = jnp.arange(n_hi)
 
-    def step(acc, args):
-        b, g = args                      # (c, f) int, (c, 3) f32
+    def step(acc, b, g):                 # (c, f) int, (c, 3) f32
         b = b.astype(jnp.int32)          # bins may arrive uint8
         lo = b % 16                      # (c, f)
         hi = b // 16
@@ -444,24 +479,32 @@ def _hist_dot16(bins, gh, num_bins, row_chunk, acc_dtype=jnp.float32):
         out = out.reshape(f, 16, n_hi, GH_CHANNELS)
         out = jnp.transpose(out, (0, 2, 1, 3)).reshape(
             f, n_hi * 16, GH_CHANNELS)
-        return acc + out[:, :num_bins], None
+        return acc + out[:, :num_bins]
 
-    # Whole chunks are sliced out of the table inside the loop and the
-    # tail (fewer rows than a chunk) is padded alone and added last: the
-    # sums, and their order, of scanning a padded, reshaped copy of the
-    # table.  That scan cost the v5e's compiler 20 s and 1 GB of host
-    # memory per million rows whenever n was not a power of two (15
-    # minutes and over 30 GB at 3e7 rows: PERF.md Findings, PR 27).
+    return _sum_over_row_chunks(
+        step, bins, gh, chunk,
+        jnp.zeros((f, num_bins, GH_CHANNELS), acc_dtype))
+
+
+def _sum_over_row_chunks(step, bins, gh, chunk, init):
+    """``acc = step(acc, bins[rows], gh[rows])`` over chunks of ``chunk``
+    rows.  Whole chunks are sliced out of the table inside the loop and
+    the tail (fewer rows than a chunk) is padded alone and added last:
+    the sums, and their order, of scanning a padded, reshaped copy of the
+    table.  That scan cost the v5e's compiler 20 s and 1 GB of host
+    memory per million rows whenever n was not a power of two (15
+    minutes and over 30 GB at 3e7 rows: PERF.md Findings, PR 27)."""
+    n, f = bins.shape
+
     def body(i, acc):
         b = jax.lax.dynamic_slice(bins, (i * chunk, 0), (chunk, f))
-        g = jax.lax.dynamic_slice(gh, (i * chunk, 0), (chunk, GH_CHANNELS))
-        return step(acc, (b, g))[0]
+        g = jax.lax.dynamic_slice(gh, (i * chunk, 0),
+                                  (chunk, gh.shape[1]))
+        return step(acc, b, g)
 
-    out = jax.lax.fori_loop(
-        0, n // chunk, body, jnp.zeros((f, num_bins, GH_CHANNELS), acc_dtype))
+    out = jax.lax.fori_loop(0, n // chunk, body, init)
     head = (n // chunk) * chunk
     if head < n:
         pad = ((0, chunk - (n - head)), (0, 0))
-        out = step(out, (jnp.pad(bins[head:], pad),
-                         jnp.pad(gh[head:], pad)))[0]
+        out = step(out, jnp.pad(bins[head:], pad), jnp.pad(gh[head:], pad))
     return out

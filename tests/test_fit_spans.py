@@ -272,6 +272,59 @@ def test_fit_emits_every_span_once_inside_its_root(fit_inputs, devices):
         assert a["collective_bytes"] >= 6 * 30 * f * 256 * 3 * 4
 
 
+@pytest.mark.parametrize("method,build", [("auto", "native"),
+                                          ("dot16", "dot16/xla")])
+def test_fit_names_the_histogram_build_it_compiled(fit_inputs, method,
+                                                   build):
+    """``hist_build`` / ``hist_build_rungs`` on ``train.fit`` and in
+    ``last_fit_info``: the implementation the fit's programs compiled,
+    and at how many of a tree's call sites (the root and each bucket
+    rung) the one-hot product stays on the chip: none on the CPU."""
+    from mmlspark_tpu.gbdt.grower import GrowerConfig, _bucket_sizes
+    params = fit_inputs["params"].__class__(
+        **{**fit_inputs["params"].__dict__, "histogram_method": method})
+    _, root, _ = _fit({**fit_inputs, "params": params})
+    n = fit_inputs["bins"].shape[0]
+    sites = 1 + len(_bucket_sizes(n, GrowerConfig()))
+    a = root["attrs"]
+    assert (a["hist_build"], a["hist_build_rungs"]) == (build, f"0/{sites}")
+    assert (engine.last_fit_info["hist_build"],
+            engine.last_fit_info["hist_build_rungs"]) == (build,
+                                                          f"0/{sites}")
+
+
+@pytest.mark.parametrize("case,want", [
+    ("epsilon", ("dot16/mosaic", 10, 10)),      # root + rungs 2^11..2^19
+    ("epsilon a chip of four", ("dot16/mosaic", 8, 8)),
+    ("masked", ("dot16/mosaic", 1, 1)),         # every split a full pass
+    ("quantized", ("dot16/xla", 0, 10)),        # int32 kernel is refused
+    ("bundles over 256 bins", ("dot16/xla", 0, 10)),
+    ("another method", ("segment", 0, 10)),
+])
+def test_hist_build_schedule_counts_the_fused_call_sites(monkeypatch, case,
+                                                         want):
+    """What the two attrs are made from, as the TPU decides it."""
+    import mmlspark_tpu.ops.histogram as H
+    from mmlspark_tpu.gbdt.grower import GrowerConfig, hist_build_schedule
+    monkeypatch.setattr(H.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(H, "_SWEEP_CACHE", {})
+    cfg = GrowerConfig(num_leaves=255, num_bins=255)
+    n = 400_000
+    if case == "epsilon a chip of four":
+        n = 100_000
+    elif case == "masked":
+        cfg = cfg.__class__(**{**cfg.__dict__, "compact_rows": False})
+    elif case == "quantized":
+        cfg = cfg.__class__(**{**cfg.__dict__, "quantized_bits": 8,
+                               "quantized_max_code": 127})
+    elif case == "bundles over 256 bins":
+        cfg = cfg.__class__(**{**cfg.__dict__, "num_bins": 400})
+    elif case == "another method":
+        cfg = cfg.__class__(**{**cfg.__dict__, "hist_method": "segment"})
+    got = hist_build_schedule(cfg, n)
+    assert (got["build"], got["fused"], got["sites"]) == want
+
+
 def test_chunked_fit_emits_launch_wait_monitor_per_chunk(fit_inputs):
     calls = []
     prof = get_profiler()

@@ -327,6 +327,163 @@ class TestPallasFused:
                                        rtol=1e-5, atol=1e-7)
 
 
+def _bf16(x):
+    return np.asarray(jnp.asarray(x, jnp.float32).astype(jnp.bfloat16)
+                      .astype(jnp.float32), np.float64)
+
+
+def _dot16_case(kind, n, f, B, seed):
+    """(bins uint8, gh float32) of one case of the on-chip dot16 build."""
+    rng = np.random.default_rng(seed)
+    bins = rng.integers(0, B, size=(n, f)).astype(np.uint8)
+    gh = np.concatenate([rng.normal(size=(n, 2)), np.ones((n, 1))],
+                        axis=1).astype(np.float32)
+    if kind == "masked":            # rows outside the leaf: zero triple
+        gh *= (rng.random(n) < 0.4)[:, None].astype(np.float32)
+    elif kind == "bucket":
+        # a bucket as grower._segment_hist gathers it: the segment's
+        # tail holds sentinels clamped to the table's last row, whose
+        # gradients the valid mask zeroes
+        table, cnt = bins, (2 * n) // 3
+        seg = np.concatenate([rng.permutation(n)[:cnt],
+                              np.full(n - cnt, n, np.int64)])
+        rows = np.minimum(seg, n - 1)
+        bins = table[rows]
+        gh = gh[rows] * (np.arange(n) < cnt)[:, None].astype(np.float32)
+    elif kind == "rounding":
+        # 1 + 2^-9 is not a bfloat16: an f32-operand build and a
+        # bf16-operand build differ in the third digit
+        gh[:, 0] = np.float32(1.0 + 2.0 ** -9)
+        gh[:, 1] = np.float32(0.3)
+    return bins, gh
+
+
+_DOT16_CASES = [
+    # kind, rows, features, bins: F in {1, 7, 8, 39, 50}, rows off and on
+    # the kernel's 128-row lane tile and 256-row test chunk
+    ("plain", 256, 8, 256), ("plain", 300, 1, 255), ("plain", 777, 7, 16),
+    ("plain", 1024, 39, 255), ("plain", 513, 50, 256),
+    ("masked", 640, 39, 255), ("masked", 100, 8, 16),
+    ("bucket", 512, 50, 255), ("bucket", 333, 7, 256),
+    ("rounding", 768, 8, 255), ("rounding", 1000, 39, 256),
+    ("rounding", 130, 1, 16),
+]
+
+
+class TestDot16OnChip:
+    """The dot16 build whose one-hot product stays on the chip
+    (pallas_histogram.histogram_dot16; interpret mode here, Mosaic on the
+    TPU: tests/test_mosaic_aot.py compiles it): today's result, to the
+    order of the float32 sums."""
+
+    @pytest.fixture
+    def on_tpu(self, monkeypatch):
+        """compute_histogram as it decides on the TPU, its kernel in
+        interpret mode."""
+        import mmlspark_tpu.ops.histogram as H
+        monkeypatch.setattr(H.jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(H, "pallas_interpret", lambda: True)
+        return H
+
+    @pytest.mark.parametrize("kind,n,f,B", _DOT16_CASES)
+    def test_fused_build_matches_segment(self, kind, n, f, B):
+        from mmlspark_tpu.ops.histogram import _hist_dot16
+        from mmlspark_tpu.ops.pallas_histogram import histogram_dot16
+        bins, gh = _dot16_case(kind, n, f, B, seed=n + f)
+        got = np.asarray(histogram_dot16(
+            jnp.asarray(bins.T), jnp.asarray(gh), B, chunk=256,
+            interpret=True), np.float64)
+        assert got.shape == (f, B, 3)
+        # counts: exact
+        np.testing.assert_array_equal(
+            got[..., 2], _ref_hist(bins, gh.astype(np.float64), B)[..., 2])
+        # grad and hess: the float64 sums of the bf16-rounded operands,
+        # to float32 summation; and within the bf16-operand bound of the
+        # unrounded ones (half an ulp: at most 2^-8 of each cell's mass)
+        want = _ref_hist(bins, _bf16(gh), B)
+        mass = _ref_hist(bins, np.abs(gh).astype(np.float64), B)
+        assert np.all(np.abs(got - want) <= 1e-6 * mass + 1e-6)
+        exact = _ref_hist(bins, gh.astype(np.float64), B)
+        assert np.all(np.abs(got - exact) <= 2.0 ** -8 * mass + 1e-6)
+        if kind == "rounding":
+            # the rounding is there: the unrounded sums are NOT met to
+            # float32 summation, so the operands were bfloat16
+            assert np.max(np.abs(got - exact)[..., 0]) > 1e-4 * n / B
+        # today's formulation on the same bf16 operands (the CPU's XLA
+        # multiplies f32 exactly): summation order apart
+        xla = np.asarray(_hist_dot16(jnp.asarray(bins), jnp.asarray(
+            _bf16(gh), jnp.float32), B, 256))
+        np.testing.assert_allclose(got, xla, rtol=1e-5, atol=1e-5)
+        seg = np.asarray(compute_histogram(
+            bins, _bf16(gh).astype(np.float32), B, method="segment"))
+        np.testing.assert_allclose(got, seg, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("chunk", [128, 512, 8192])
+    def test_row_chunk_does_not_change_the_sums(self, chunk):
+        """1000 rows in chunks that do not divide them, and in one chunk
+        (the default: the table's rows rounded up to the lane tile)."""
+        from mmlspark_tpu.ops.pallas_histogram import histogram_dot16
+        bins, gh = _dot16_case("masked", 1000, 39, 255, seed=3)
+        got = np.asarray(histogram_dot16(
+            jnp.asarray(bins.T), jnp.asarray(gh), 255, chunk=chunk,
+            interpret=True))
+        want = _ref_hist(bins, _bf16(gh), 255)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    def test_dot16_on_the_tpu_is_the_fused_build(self, on_tpu, monkeypatch):
+        """``compute_histogram(method='dot16')`` and ``auto`` reach the
+        kernel on the TPU, with the signature and the result they had."""
+        import mmlspark_tpu.ops.pallas_histogram as PH
+        calls = []
+        real = PH.histogram_dot16
+        monkeypatch.setattr(PH, "histogram_dot16", lambda *a, **k: (
+            calls.append(a[0].shape), real(*a, **k))[1])
+        monkeypatch.setattr(on_tpu, "_native_available", lambda: False)
+        bins, gh = _dot16_case("plain", 300, 7, 255, seed=5)
+        want = _ref_hist(bins, _bf16(gh), 255)
+        for method in ("dot16", "auto"):
+            got = np.asarray(compute_histogram(bins, gh, 255, method=method))
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        assert calls == [(7, 300), (7, 300)]
+        assert on_tpu.histogram_build("auto", 300, 255, False) == \
+            "dot16/mosaic"
+
+    @pytest.mark.parametrize("why,B,dtype", [
+        ("integer gradients", 255, np.int16),
+        ("more bins than two nibbles hold", 300, np.float32)])
+    def test_other_cases_keep_todays_path(self, on_tpu, monkeypatch, why, B,
+                                          dtype):
+        """Quantized gradients (the int32 kernel is refused by this
+        stack) and EFB bundles over 256 bins: XLA's formulation, as
+        before, and the kernel is not entered."""
+        import mmlspark_tpu.ops.pallas_histogram as PH
+
+        def refuse(*a, **k):
+            raise AssertionError(f"kernel entered with {why}")
+        monkeypatch.setattr(PH, "histogram_dot16", refuse)
+        rng = np.random.default_rng(0)
+        bins = rng.integers(0, B, size=(400, 5)).astype(np.int32)
+        gh = rng.integers(-100, 100, size=(400, 3)).astype(dtype)
+        got = np.asarray(compute_histogram(bins, gh, B, method="dot16"))
+        assert got.dtype == (np.int32 if dtype == np.int16 else np.float32)
+        np.testing.assert_allclose(
+            got, _ref_hist(bins, gh.astype(np.float64), B), atol=1e-3)
+        assert on_tpu.histogram_build(
+            "dot16", 400, B, dtype == np.int16) == "dot16/xla"
+
+    def test_cpu_keeps_xla_for_an_explicit_dot16(self):
+        import mmlspark_tpu.ops.histogram as H
+        assert H.histogram_build("dot16", 4096, 255, False) == "dot16/xla"
+        assert H.histogram_build("auto", 4096, 255, False) in (
+            "native", "segment")
+
+    def test_kernel_refuses_more_than_256_bins(self):
+        from mmlspark_tpu.ops.pallas_histogram import histogram_dot16
+        with pytest.raises(ValueError, match="256"):
+            histogram_dot16(jnp.zeros((8, 128), jnp.uint8),
+                            jnp.zeros((128, 3)), 300, interpret=True)
+
+
 class TestSweepSanitize:
     """_auto_method must never rank a 0.0-clamped sweep reading (ISSUE 10
     satellite): a slope that clamped to zero sat below the dispatch-noise

@@ -81,14 +81,13 @@ def test_fused_gather_is_refused_with_the_recorded_message(v5e):
 _CACHE_N, _CACHE_F, _CACHE_L, _CACHE_B = 40_000, 200, 255, 256
 
 
-def _compile_grower(v5e, case):
+def _compile_grower(v5e, case, n=_CACHE_N, f=_CACHE_F, num_bins=_CACHE_B):
     """The v5e's compiled program for one way of running the grower at
     40 000 x 200, 255 leaves, dot16 (11-16 s each)."""
     from mmlspark_tpu.gbdt.grower import (GrowerConfig, _grow_tree_impl,
                                           grow_tree)
-    n, f = _CACHE_N, _CACHE_F
     one = SingleDeviceSharding(v5e[0])
-    base = dict(num_leaves=_CACHE_L, num_bins=_CACHE_B, hist_method="dot16",
+    base = dict(num_leaves=_CACHE_L, num_bins=num_bins, hist_method="dot16",
                 min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0)
     if case in ("serial", "masked", "categorical"):
         cfg = GrowerConfig(compact_rows=(case != "masked"),
@@ -138,3 +137,63 @@ def test_split_loop_updates_the_histogram_cache_in_place(v5e, case):
     cache = (_CACHE_L, _CACHE_F, _CACHE_B, 3)
     copies = compiled_copies(_compile_grower(v5e, case))
     assert [c for c in copies if c[1] == cache] == []
+
+
+# --------------------------- the histogram build's one-hots, on the chip
+
+
+@pytest.fixture
+def decides_as_on_the_tpu(monkeypatch):
+    """``compute_histogram`` picks a build by ``jax.default_backend()``,
+    which is the CPU here while the compile targets the v5e: the test
+    steers it, and the kernel then goes through Mosaic, not interpret
+    mode."""
+    import mmlspark_tpu.ops.histogram as H
+    monkeypatch.setattr(H.jax, "default_backend", lambda: "tpu")
+
+
+def _wider_than_the_bins(compiled, f):
+    """Instructions that hold ``(rows, F, 16)`` elements or more for some
+    2048 rows or more: the one-hots and their products, which XLA's
+    formulation of dot16 writes to HBM (``f32[8192,2000,16,3]`` and its
+    kin, 840 bytes a cell: PERF.md Findings, PR 28).  The histogram
+    cache, ``(leaves, F, bins, 3)``, has 255 rows."""
+    import math
+    from mmlspark_tpu.core.profiling import compiled_instructions
+    return [(name, shape) for name, shape, _ in compiled_instructions(
+        compiled, min_bytes=2048 * f * 16)
+        if len(shape) >= 3 and shape[0] >= 2048 and shape[1] == f
+        and math.prod(shape[2:]) >= 16]
+
+
+@pytest.mark.parametrize("n,f", [(32768, 2000), (32768, 968), (65536, 39)])
+def test_dot16_build_keeps_its_one_hots_on_the_chip(v5e,
+                                                    decides_as_on_the_tpu,
+                                                    n, f):
+    """``compute_histogram(method="dot16")`` at a bucket of each wide cell
+    and of the click log: a Mosaic call on the uint8 bins, no array wider
+    than they are, and temporaries under the table's own size (XLA's
+    formulation: 4.80 GB at the first shape)."""
+    from mmlspark_tpu.ops.histogram import compute_histogram
+    one = SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(
+        lambda b, g: compute_histogram(b, g, 255, method="dot16")).lower(
+        _sds((n, f), jnp.uint8, one), _sds((n, 3), jnp.float32, one)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _wider_than_the_bins(compiled, f) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < n * f
+
+
+def test_boost_scan_at_epsilons_shape_holds_no_one_hot(
+        v5e, decides_as_on_the_tpu):
+    """The whole fit's program at 400 000 x 2000, 255 leaves and bins: a
+    kernel at the root and in every bucket rung, no ``(rows, F, 16)``
+    array anywhere, and 6.1 GB of temporaries (the cache, the row-major
+    table and the largest bucket, twice each) where XLA's formulation
+    held 9.9 (about a minute to compile)."""
+    compiled = _compile_grower(v5e, "boost_scan", n=400_000, f=2000,
+                               num_bins=255)
+    assert compiled.as_text().count("tpu_custom_call") >= 10
+    assert _wider_than_the_bins(compiled, 2000) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.5e9

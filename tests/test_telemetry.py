@@ -384,6 +384,24 @@ HloModule jit_grow_tree, is_scheduled=true
         got = compiled_copies(self.TEXT, min_bytes=100 << 20)
         assert [n for n, _, _ in got] == ["copy.12", "copy.369"]
 
+    @pytest.mark.parametrize("min_bytes,opcodes,want", [
+        # every instruction that makes an array; a parameter names one
+        (100 << 20, None, ["copy.12", "copy.369", "fusion.228"]),
+        (0, ("fusion",), ["fusion.228"]),
+        (0, ("copy-done",), ["copy-done.3"]),
+        (1 << 40, None, []),
+    ])
+    def test_instructions_over_a_threshold_by_opcode(self, min_bytes,
+                                                     opcodes, want):
+        """``compiled_instructions``: the general form (PR 28 reads the
+        histogram build's ``(rows, F, 16)`` temporaries with it)."""
+        from mmlspark_tpu.core.profiling import compiled_instructions
+        got = compiled_instructions(self.TEXT, min_bytes, opcodes)
+        assert [n for n, _, _ in got] == want
+        assert all(b == int(np.prod(sh)) * (2 if n == "copy.12" else
+                                            1 if "done" in n else 4)
+                   for n, sh, b in got)
+
     def test_reads_a_compiled_executable(self):
         """Takes what ``.lower(...).compile()`` returns as well as its
         text (which copies the CPU's compiler places is its business)."""
