@@ -50,7 +50,7 @@ def main():
                          "missing accelerator is an error")
     ap.add_argument("--pass-through", default="",
                     help="passThroughArgs forwarded to the estimator "
-                         "(A/B knobs, e.g. 'packed_gather=true'); empty "
+                         "(A/B knobs, e.g. 'collective=ring'); empty "
                          "for the official configuration")
     ap.add_argument("--parallelism", default=None,
                     choices=("data", "voting", "feature"),

@@ -144,11 +144,9 @@ class LightGBMParams(HasFeaturesCol, HasLabelCol, HasPredictionCol,
                       "by (1-topRate)/otherRate)", default=0.1,
                       typeConverter=TypeConverters.toFloat)
     histogramMethod = Param("histogramMethod",
-                            "TPU histogram backend: auto, dot16, onehot, "
-                            "segment, pallas, pallas_bf16, pallas_fused (segment "
-                            "gather fused in-kernel), pallas_ring (gather + "
-                            "histogram + cross-shard ring reduce in one "
-                            "kernel)", default="auto",
+                            "Histogram build: auto (native on the CPU, "
+                            "dot16 on the TPU, segment elsewhere), native, "
+                            "segment, dot16, onehot", default="auto",
                             typeConverter=TypeConverters.toString)
     collective = Param("collective",
                        "Cross-shard histogram reduction on mesh fits: "

@@ -39,8 +39,9 @@ def _resolve_collective_cfg(params: "TrainParams", mesh, *,
                             ranking: bool = False):
     """Resolve ``params.collective`` → ``("psum"|"ring", mesh, reason)``.
 
-    "auto" stays on psum: no ring-vs-psum measurement exists on real
-    interconnect yet (ROADMAP S6).  "ring" requires a multi-shard
+    "auto" stays on psum: the one timing on the four-chip host has the
+    ring slower at every payload (PERF.md Findings, PR 29).  "ring"
+    requires a multi-shard
     layout whose data axis is the only populated one, on a path whose
     scans support the data-only mesh (gbdt/goss/rf/multiclass, data- or
     voting-parallel — not ranking, dart or a feature-sharded mesh).
@@ -245,9 +246,6 @@ class TrainParams:
     #: docs/collectives.md).  Ring fits run on a data-only 1-axis mesh
     #: and degrade to psum wherever the kernel gates refuse.
     collective: str = "auto"
-    #: pack four uint8 bins per u32 word for the per-split segment gather
-    #: (grower.GrowerConfig.packed_gather); measured knob, default off
-    packed_gather: bool = False
     #: quantized-gradient training (ISSUE 17; Shi et al. 2022, LightGBM
     #: use_quantized_grad): "off" keeps f32 gradients; "16"/"8"
     #: discretize (g, h) each boost round onto a seeded
@@ -338,6 +336,9 @@ class TrainParams:
             raise ValueError(
                 f"quantizedGrad={self.quantized_grad!r} is not supported; "
                 "valid: off, 16, 8")
+        # a removed or mistyped name fails here, not inside a trace
+        from ..ops.histogram import check_method
+        check_method(self.histogram_method)
 
 
 @functools.partial(jax.jit, static_argnames=("obj", "cfg", "lr"),
@@ -1846,7 +1847,6 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         min_sum_hessian_in_leaf=params.min_sum_hessian_in_leaf,
         min_gain_to_split=params.min_gain_to_split,
         hist_method=params.histogram_method,
-        packed_gather=params.packed_gather,
         collective=collective,
         voting_k=params.top_k if use_voting else 0,
         use_categorical=mapper.has_categorical,
@@ -2523,7 +2523,6 @@ def _train_distributed_sharded(bins_shards, label_shards, weight_shards,
         min_sum_hessian_in_leaf=params.min_sum_hessian_in_leaf,
         min_gain_to_split=params.min_gain_to_split,
         hist_method=params.histogram_method,
-        packed_gather=params.packed_gather,
         collective=collective,
         voting_k=params.top_k if params.parallelism == "voting" else 0,
         use_categorical=mapper.has_categorical,

@@ -34,7 +34,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core.backend import pallas_interpret
 from ..ops.histogram import compute_histogram
 
 EPS_GAIN = 1e-10
@@ -104,13 +103,6 @@ class GrowerConfig:
     compact_rows: bool = True
     #: smallest compaction bucket (rows); buckets double up to 2^ceil(lg n)
     min_bucket: int = 2048
-    #: gather leaf segments from a (n, ceil(f/4)) uint32 matrix with four
-    #: uint8 bins packed per word (unpacked by shift/mask after the
-    #: gather, which fuses into the histogram's elementwise prologue).
-    #: The per-split row gather touches 4x fewer elements — aimed at the
-    #: TPU gather cost PERF.md measured at ~2x the histogram itself.
-    #: Requires uint8 bins; ignored otherwise.
-    packed_gather: bool = False
     #: PV-Tree voting parallelism (Meng et al. 2016; LightGBM
     #: tree_learner=voting, top_k): > 0 with ``axis_name`` set keeps leaf
     #: histograms SHARD-LOCAL; each shard votes its top-k features by
@@ -738,33 +730,13 @@ def _partition_switch(row_order, col, off, cnt, thr, use_cat, cat_bits,
     return jax.lax.switch(branch, [make(s) for s in sizes], 0)
 
 
-def pack_bins_u32(bins: jnp.ndarray) -> jnp.ndarray:
-    """(n, f) uint8 bins → (n, ceil(f/4)) uint32, four bins per word
-    (little-endian within the word).  O(n·f) elementwise — cheap next to
-    one histogram pass; computed once per tree, outside the split loop."""
-    n, f = bins.shape
-    f4 = (f + 3) // 4
-    bu = bins.astype(jnp.uint32)
-    if f4 * 4 != f:
-        bu = jnp.pad(bu, ((0, 0), (0, f4 * 4 - f)))
-    bu = bu.reshape(n, f4, 4)
-    return (bu[..., 0] | (bu[..., 1] << 8) | (bu[..., 2] << 16)
-            | (bu[..., 3] << 24))
-
-
 def _segment_hist(bins, gh, row_order, off, cnt, n, sizes,
-                  cfg: GrowerConfig, bins_pk=None, binsT=None):
+                  cfg: GrowerConfig):
     """Histogram the contiguous ``row_order[off:off+cnt]`` segment via the
     smallest power-of-two bucket gather.  Local (no psum) — the caller
     reduces over the data axis, keeping collectives out of switch
     branches.  On the CPU backend the gather fuses into the native FFI
-    kernel (no (size, f) materialization).  With ``bins_pk`` (see
-    :func:`pack_bins_u32`) the row gather reads the packed words and the
-    shift/mask unpack fuses into the histogram prologue.  With
-    ``hist_method='pallas_fused'`` (and ``binsT`` provided) the row
-    gather happens INSIDE the Pallas kernel against a VMEM-resident
-    binsT block — no (size, f) sub-matrix ever touches HBM (PERF.md
-    headroom item: the bucket-gather rivals the histogram itself)."""
+    kernel (no (size, f) materialization)."""
     from ..ops.histogram import native_segment_hist
     if cfg.hist_method in ("auto", "native"):
         fused = native_segment_hist(bins, gh, row_order, off, cnt,
@@ -772,36 +744,6 @@ def _segment_hist(bins, gh, row_order, off, cnt, n, sizes,
                                     max_code=cfg.quantized_max_code)
         if fused is not None:
             return fused
-    if (cfg.hist_method in ("pallas_fused", "pallas_ring")
-            and binsT is not None and cfg.num_bins <= 256):
-        from ..ops.pallas_histogram import (FUSED_MAX_ROWS,
-                                            histogram_pallas_fused)
-        interp = pallas_interpret()
-        # beyond the VMEM-residency gate the segment falls through to
-        # the gather-then-pallas path below
-        if n <= FUSED_MAX_ROWS:
-            f_out = bins.shape[1]
-            accum = ("int32" if jnp.issubdtype(gh.dtype, jnp.integer)
-                     else "float32")
-
-            def make_f(size):
-                def fn(_):
-                    seg = jax.lax.dynamic_slice(row_order, (off,), (size,))
-                    valid = jnp.arange(size, dtype=jnp.int32) < cnt
-                    rows = jnp.minimum(seg, n - 1)
-                    gh_sub = jnp.take(gh, rows, axis=0) * \
-                        valid.astype(gh.dtype)[:, None]
-                    # binsT arrives pre-padded to the 8-feature fold
-                    # (see _grow_tree_impl); slice back to real columns
-                    return histogram_pallas_fused(
-                        binsT, gh_sub, rows, cfg.num_bins, size,
-                        accum=accum, interpret=interp)[:f_out]
-                return fn
-
-            branch = jnp.searchsorted(jnp.asarray(sizes, jnp.int32), cnt,
-                                      side="left")
-            return jax.lax.switch(branch, [make_f(s) for s in sizes], 0)
-    f_cols = bins.shape[1]
 
     def make(size):
         def fn(_):
@@ -809,20 +751,12 @@ def _segment_hist(bins, gh, row_order, off, cnt, n, sizes,
             valid = jnp.arange(size, dtype=jnp.int32) < cnt
             rows = jnp.minimum(seg, n - 1)
             with jax.named_scope("row_gather"):
-                if bins_pk is not None:
-                    u = jnp.take(bins_pk, rows, axis=0)   # (size, f4) u32
-                    parts = jnp.stack(
-                        [(u >> (8 * k)) & jnp.uint32(0xFF)
-                         for k in range(4)], axis=-1)
-                    b_sub = parts.reshape(size, -1)[:, :f_cols] \
-                        .astype(jnp.int32)
-                else:
-                    # rows are clamped above: "clip" says so, and spares
-                    # the bucket the fill mode's select, a whole pass
-                    # over it that also kept XLA from handing the
-                    # histogram kernel its rows-minor layout straight
-                    # from the gather (PERF.md Findings, PR 28)
-                    b_sub = jnp.take(bins, rows, axis=0, mode="clip")
+                # rows are clamped above: "clip" says so, and spares
+                # the bucket the fill mode's select, a whole pass
+                # over it that also kept XLA from handing the
+                # histogram kernel its rows-minor layout straight
+                # from the gather (PERF.md Findings, PR 28)
+                b_sub = jnp.take(bins, rows, axis=0, mode="clip")
                 gh_sub = jnp.take(gh, rows, axis=0, mode="clip") * \
                     valid.astype(gh.dtype)[:, None]
             with jax.named_scope("segment_hist"):
@@ -834,59 +768,6 @@ def _segment_hist(bins, gh, row_order, off, cnt, n, sizes,
     branch = jnp.searchsorted(jnp.asarray(sizes, jnp.int32), cnt,
                               side="left")
     return jax.lax.switch(branch, [make(s) for s in sizes], 0)
-
-
-def _segment_hist_dist(bins, gh, row_order, off, cnt, n, sizes,
-                       cfg: GrowerConfig, bins_pk=None, binsT=None):
-    """Distributed segment histogram: returns ``(hist, reduced)`` where
-    ``reduced`` is a STATIC bool — True when the cross-shard reduction
-    already happened inside the kernel.
-
-    With ``hist_method='pallas_ring'`` under a ring collective, the
-    whole gather→histogram→ring-allreduce runs as ONE Pallas kernel
-    (ops/pallas_collectives.fused_segment_hist_ring): the bucket is
-    chosen from the GLOBAL max segment count (``pmax``) so every shard
-    enters the same ``lax.switch`` branch — a collective may never live
-    in a branch shards could disagree on — and the kernel overlaps the
-    ICI transfer of finished histogram chunks with the MXU accumulation
-    of the next.  Anything the static gates refuse falls back to the
-    local :func:`_segment_hist` with the reduction applied by the
-    caller."""
-    use_fused_ring = (
-        cfg.collective == "ring" and cfg.hist_method == "pallas_ring"
-        and cfg.axis_name is not None and not _is_voting(cfg)
-        and cfg.data_axis_size > 1 and binsT is not None
-        and cfg.num_bins <= 256)
-    if use_fused_ring:
-        from ..ops.pallas_collectives import (fused_ring_applicable,
-                                              fused_segment_hist_ring)
-        interp = pallas_interpret()
-        if fused_ring_applicable(binsT.shape[0], n, cfg.num_bins,
-                                 cfg.data_axis_size):
-            f_out = bins.shape[1]
-            cnt_g = jax.lax.pmax(cnt, cfg.axis_name)
-            accum = ("int32" if jnp.issubdtype(gh.dtype, jnp.integer)
-                     else "float32")
-
-            def make_f(size):
-                def fn(_):
-                    seg = jax.lax.dynamic_slice(row_order, (off,), (size,))
-                    valid = jnp.arange(size, dtype=jnp.int32) < cnt
-                    rows = jnp.minimum(seg, n - 1)
-                    gh_sub = jnp.take(gh, rows, axis=0) * \
-                        valid.astype(gh.dtype)[:, None]
-                    return fused_segment_hist_ring(
-                        binsT, gh_sub, rows, cfg.num_bins, size,
-                        cfg.axis_name, cfg.data_axis_size,
-                        accum=accum, interpret=interp)[:f_out]
-                return fn
-
-            branch = jnp.searchsorted(jnp.asarray(sizes, jnp.int32),
-                                      cnt_g, side="left")
-            return jax.lax.switch(branch, [make_f(s) for s in sizes],
-                                  0), True
-    return _segment_hist(bins, gh, row_order, off, cnt, n, sizes, cfg,
-                         bins_pk=bins_pk, binsT=binsT), False
 
 
 def _leaf_of_position(leaf_start, leaf_cnt, n):
@@ -960,20 +841,19 @@ def _find_split(hist, pg, ph, pc, fi, depth_ok, cfg: GrowerConfig,
 
 
 def hist_build_schedule(cfg: GrowerConfig, n_rows: int) -> dict:
-    """Which build of the histogram a tree's call sites compile, from the
-    shapes alone: the root (``n_rows`` rows a shard) and, where rows are
-    compacted, each rung of the bucket ladder.  ``build`` names the
-    implementations (``ops.histogram.histogram_build``; several joined by
-    ``+`` if the sites differ), ``fused`` counts the sites whose one-hot
+    """Which build of the histogram a tree's call sites compile: the root
+    (``n_rows`` rows a shard) and, where rows are compacted, each rung of
+    the bucket ladder.  ``build`` names the implementation
+    (``ops.histogram.histogram_build``: one for all sites, since no build
+    is chosen by the row count), ``fused`` counts the sites whose one-hot
     product stays on the chip, ``sites`` all of them."""
     from ..ops.histogram import histogram_build
-    sites = [n_rows] + (_bucket_sizes(n_rows, cfg) if cfg.compact_rows
-                        else [])
-    names = [histogram_build(cfg.hist_method, s, cfg.num_bins,
-                             _is_quantized(cfg)) for s in sites]
-    return {"build": "+".join(sorted(set(names))),
-            "fused": sum(n == "dot16/mosaic" for n in names),
-            "sites": len(sites)}
+    sites = 1 + (len(_bucket_sizes(n_rows, cfg)) if cfg.compact_rows else 0)
+    build = histogram_build(cfg.hist_method, cfg.num_bins,
+                            _is_quantized(cfg))
+    return {"build": build,
+            "fused": sites if build == "dot16/mosaic" else 0,
+            "sites": sites}
 
 
 def collective_schedule(cfg: GrowerConfig, f: int, *,
@@ -1114,25 +994,6 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
     # precompute it once and pass it in; the default covers direct calls.
     if binsT is None:
         binsT = bins.T
-    binsT_hist = binsT
-    if cfg.hist_method in ("pallas_fused", "pallas_ring"):
-        # pad the feature axis to the kernel's fold ONCE per grow — a
-        # per-call jnp.pad inside the split loop would copy the whole
-        # (f, n) matrix at every segment histogram.  The ring-fused
-        # kernel additionally needs one chunk of feature blocks per
-        # device, so it pads to 8 * data_axis_size.
-        mult = 8
-        if (cfg.hist_method == "pallas_ring"
-                and cfg.collective == "ring" and cfg.data_axis_size > 1):
-            mult = 8 * cfg.data_axis_size
-        fp8 = (-binsT.shape[0]) % mult
-        if fp8:
-            binsT_hist = jnp.pad(binsT, ((0, fp8), (0, 0)))
-    bins_pk = None
-    if (cfg.packed_gather and cfg.compact_rows
-            and bins.dtype == jnp.uint8):
-        bins_pk = pack_bins_u32(bins)
-
     with jax.named_scope("root_hist"):
         hist0 = _hist(bins, gh, cfg, efb)
     g0, h0, c0 = _global_totals(*tot_deq(*_totals_from_hist(hist0)), cfg)
@@ -1253,16 +1114,14 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                     use_right = cnt_r_p <= cnt_l_p
                 child_off = jnp.where(use_right, off + cnt_l_p, off)
                 child_cnt = jnp.where(use_right, cnt_r_p, cnt_l_p)
-                hist_small, reduced = _segment_hist_dist(
+                hist_small = _segment_hist(
                     bins, gh, row_order, child_off, child_cnt, n, sizes,
-                    cfg, bins_pk=bins_pk, binsT=binsT_hist)
+                    cfg)
                 if efb is not None:
                     # expansion is linear, so it commutes with the
-                    # reduction — safe whether the fused ring already
-                    # reduced or the psum below still will
+                    # reduction below
                     hist_small = _efb_expand(hist_small, efb)
-                if (not reduced and cfg.axis_name is not None
-                        and not _is_voting(cfg)):
+                if cfg.axis_name is not None and not _is_voting(cfg):
                     # voting keeps per-leaf histograms local; only voted
                     # candidate slices are reduced inside _find_split
                     hist_small = _reduce_hist(hist_small, cfg)

@@ -4,11 +4,20 @@ This is the TPU-native replacement for the per-feature histogram build inside
 the reference's native engine (``LGBM_BoosterUpdateOneIter`` → ConstructHistograms;
 SURVEY.md §3.1 hot loop).  The reference scatters grad/hess into per-feature
 bin buffers with CPU/CUDA code; scatter-add with data-dependent indices is the
-one primitive TPUs dislike, so three formulations are provided:
+one primitive TPUs dislike.  Four formulations, one per condition the code
+can observe, and ``auto`` picks among them from the backend alone
+(:func:`_auto_method`): ``native`` on the CPU, ``dot16`` on the TPU,
+``segment`` anywhere else.
+
+``native``
+    The C++ accumulator behind an XLA FFI custom call (native/fasthist.cc):
+    the CPU backend's build, and the fused gather+histogram of a leaf's
+    segment (:func:`native_segment_hist`).  Not available on accelerators.
 
 ``segment``
     ``jax.ops.segment_sum`` per feature (vmapped).  Lowers to XLA scatter;
-    correct everywhere, fastest on CPU, mediocre on TPU.
+    correct everywhere: the XLA reference the other builds are held to,
+    and ``auto`` where neither of the others applies.
 
 ``dot16``
     Nibble-decomposed one-hot matmul.  A bin index in [0, 256) is split into
@@ -24,8 +33,8 @@ one primitive TPUs dislike, so three formulations are provided:
     build of every other case.
 
 ``onehot``
-    Naive one-hot einsum, row/feature chunked.  Reference implementation for
-    testing the clever ones.
+    Naive one-hot einsum, row/feature chunked.  A reference for tests;
+    ``auto`` never picks it.
 
 All accept already *masked* gradient triples ``gh = (grad, hess, count)``
 (rows outside the active leaf carry zeros), which is how leaf-conditional
@@ -48,71 +57,6 @@ from ..core.backend import pallas_interpret
 
 #: channels in the gradient triple
 GH_CHANNELS = 3  # grad, hess, count
-
-
-_SWEEP_CACHE: dict = {}
-
-
-def _sanitize_sweep(doc: dict) -> Optional[dict]:
-    """Winner table with 0.0-clamped readings refused.
-
-    A slope that clamps to 0.0 means the measurement sat below the
-    dispatch-noise floor (tools/sweep_histogram.py) — the method may be
-    the fastest or pure noise, so it must never be RANKED.  A winner
-    entry is kept only when its own reading at that bucket is present
-    and strictly positive AND no other exact method at the bucket is
-    0.0-clamped (an unmeasurable rival means the ranking itself is
-    unresolved).  Refused buckets fall out of the table, so
-    :func:`_auto_method` falls back to the nearest larger resolved
-    bucket / the backend default — exactly the committed
-    ``_sweep_tpu.json`` artifacts (``pallas: 0.0`` at 2048, ``dot16:
-    0.0`` at 4096/8192) demand.
-
-    Quantized-dtype sweep rows (ISSUE 17) land in the same table under
-    ``method@int16`` / ``method@int32`` keys: they are informational
-    columns and must never be RANKED — a winner entry naming one is
-    refused, and as rivals they are ignored (the membership check below
-    only admits the four f32-exact methods)."""
-    winners = doc.get("winner_by_rows") or {}
-    times = doc.get("times_us_by_rows") or {}
-    out = {}
-    for rows, method in winners.items():
-        if "@" in method:
-            continue
-        t = times.get(rows)
-        if t is None:
-            # no raw readings recorded (hand-built table): trust it
-            out[rows] = method
-            continue
-        win_t = t.get(method)
-        if win_t is None or win_t <= 0.0:
-            continue
-        rivals = [v for k, v in t.items()
-                  if k != method and k in ("segment", "dot16", "onehot",
-                                           "pallas") and v is not None]
-        if any(v <= 0.0 for v in rivals):
-            continue
-        out[rows] = method
-    return out or None
-
-
-def _load_sweep(backend: str) -> Optional[dict]:
-    """Measured winner-by-rows table for this backend (see
-    tools/sweep_histogram.py), sanitized against 0.0-clamped noise
-    artifacts, or None if never swept."""
-    if backend not in _SWEEP_CACHE:
-        import json
-        import os
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            f"_sweep_{backend}.json")
-        table = None
-        try:
-            with open(path) as fh:
-                table = _sanitize_sweep(json.load(fh))
-        except (OSError, ValueError):
-            pass
-        _SWEEP_CACHE[backend] = table
-    return _SWEEP_CACHE[backend]
 
 
 _NATIVE_OK: Optional[bool] = None
@@ -272,23 +216,30 @@ def native_find_split(hist, parent_g, parent_h, parent_c, feature_mask,
     return gain, feat, b
 
 
-def _auto_method(n_rows: Optional[int] = None) -> str:
-    """Pick the histogram formulation for a call site of ``n_rows`` rows.
+#: what ``method`` may name; anything else is a ``ValueError``
+METHODS = ("auto", "native", "segment", "dot16", "onehot")
+
+
+def check_method(method: str) -> None:
+    """Refuse a name that is not one of :data:`METHODS` (input from
+    outside: ``histogramMethod``, ``passThroughArgs``)."""
+    if method not in METHODS:
+        raise ValueError(f"Unknown histogram method {method!r}; valid: "
+                         + ", ".join(METHODS))
+
+
+def _auto_method() -> str:
+    """The formulation ``auto`` stands for, from the backend alone.
 
     CPU backend: the native C++ accumulator (fasthist.cc) when the
     extension builds — it beats every XLA scatter/matmul formulation at
-    all sizes on one core (~1 ns vs ~6 ns per row-feature; PERF.md).
-    Otherwise this backend's measured sweep table; fall back to segment
-    (CPU) / dot16 (accelerators) where no table exists."""
+    all sizes on one core (~1 ns vs ~6 ns per row-feature; PERF.md) —
+    and ``segment`` without it.  TPU: ``dot16``, at every row count and
+    width (the sweep on the chip found no crossover: PERF.md Findings,
+    PR 28).  Any other backend: ``segment``."""
     backend = jax.default_backend()
     if backend == "cpu" and _native_available():
         return "native"
-    table = _load_sweep(backend)
-    if table and n_rows:
-        for s in sorted(int(k) for k in table):
-            if n_rows <= s:
-                return table[str(s)]
-        return table[str(max(int(k) for k in table))]
     return "dot16" if backend == "tpu" else "segment"
 
 
@@ -304,13 +255,15 @@ def _dot16_on_chip(num_bins: int, quantized: bool) -> bool:
             and num_bins <= 256)
 
 
-def histogram_build(method: str, n_rows: int, num_bins: int,
-                    quantized: bool) -> str:
+def histogram_build(method: str, num_bins: int, quantized: bool) -> str:
     """The implementation :func:`compute_histogram` compiles for a call
-    site of ``n_rows`` rows: the resolved method, and for ``dot16`` which
-    of its two builds (``dot16/mosaic``, ``dot16/xla``)."""
+    site: the resolved method, and for ``dot16`` which of its two builds
+    (``dot16/mosaic``, ``dot16/xla``)."""
+    check_method(method)
     if method == "auto":
-        method = _auto_method(n_rows)
+        method = _auto_method()
+    if method == "native" and (num_bins > 256 or not _native_available()):
+        return "segment"
     if method == "dot16":
         return ("dot16/mosaic" if _dot16_on_chip(num_bins, quantized)
                 else "dot16/xla")
@@ -331,10 +284,8 @@ def compute_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
         accumulates exactly in int32 — the result is ``(f, B, 3)`` int32
         (dequantize at split evaluation, grower-side).
       num_bins: static bin count B.
-      method: "segment" | "dot16" | "onehot" | "pallas" | "pallas_bf16"
-        | "auto" (plus the fused variants "pallas_fused" and
-        "pallas_ring", which behave like "pallas" here — their fusion
-        lives in the grower's segment path / ring collective).
+      method: one of :data:`METHODS`; anything else raises
+        ``ValueError`` before any device work.
       max_code: quantized mode only — the grid's |code| bound, which
         gates the native packed-int64 single-add fast path
         (:func:`packed_accum_ok`).
@@ -344,45 +295,21 @@ def compute_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
     """
     quantized = jnp.issubdtype(gh.dtype, jnp.integer)
     acc_dtype = jnp.int32 if quantized else jnp.float32
-    if method == "auto":
-        method = _auto_method(bins.shape[0])
-    if method == "native":
-        if num_bins > 256 or not _native_available():
-            return _hist_segment(bins, gh, num_bins, acc_dtype)
+    build = histogram_build(method, num_bins, quantized)
+    if build == "native":
         if quantized:
             return _hist_native_q(bins, gh, num_bins, max_code)
         return _hist_native(bins, gh, num_bins)
-    if method == "segment":
+    if build == "segment":
         return _hist_segment(bins, gh, num_bins, acc_dtype)
-    if method == "dot16":
-        if _dot16_on_chip(num_bins, quantized):
-            from .pallas_histogram import histogram_dot16
-            # the TPU's XLA keeps a uint8 table rows-minor: no copy here
-            return histogram_dot16(bins.T, gh, num_bins,
-                                   interpret=pallas_interpret())
+    if build == "dot16/mosaic":
+        from .pallas_histogram import histogram_dot16
+        # the TPU's XLA keeps a uint8 table rows-minor: no copy here
+        return histogram_dot16(bins.T, gh, num_bins,
+                               interpret=pallas_interpret())
+    if build == "dot16/xla":
         return _hist_dot16(bins, gh, num_bins, row_chunk, acc_dtype)
-    if method == "onehot":
-        return _hist_onehot(bins, gh, num_bins, row_chunk, acc_dtype)
-    if method in ("pallas", "pallas_bf16", "pallas_fused", "pallas_ring"):
-        # 'pallas_fused' fuses the SEGMENT gather (grower._segment_hist)
-        # and 'pallas_ring' additionally fuses the cross-shard ring
-        # reduction (ops/pallas_collectives.py); direct full-matrix
-        # calls like the root histogram have nothing to gather/reduce
-        # and run the plain kernel
-        from .pallas_histogram import BMAX, histogram_pallas
-        if num_bins > BMAX:   # kernel folds 16x16 nibbles; fall back
-            return _hist_dot16(bins, gh, num_bins, row_chunk, acc_dtype)
-        if quantized:
-            return histogram_pallas(
-                bins.astype(jnp.int32), gh.astype(jnp.int32), num_bins,
-                row_chunk=min(row_chunk, 4096), accum="int32",
-                interpret=pallas_interpret())
-        return histogram_pallas(
-            bins.astype(jnp.int32), gh.astype(jnp.float32), num_bins,
-            row_chunk=min(row_chunk, 4096),   # VMEM ceiling for the kernel
-            accum="bfloat16" if method == "pallas_bf16" else "float32",
-            interpret=pallas_interpret())
-    raise ValueError(f"Unknown histogram method {method!r}")
+    return _hist_onehot(bins, gh, num_bins, row_chunk, acc_dtype)
 
 
 def _hist_native(bins, gh, num_bins):

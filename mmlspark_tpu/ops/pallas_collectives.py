@@ -4,8 +4,9 @@ The distributed training hot loop reduces each split's ``(f, B, 3)``
 leaf-histogram partials across the ``data`` mesh axis.  The stock path is
 a bare ``jax.lax.psum`` of the whole state, which XLA stages through
 HBM.  This module keeps the per-tree collective in VMEM and on the
-interconnect (SNIPPETS [1]–[3] are the exemplar ring kernels); whether
-that beats psum on ICI is unmeasured (ROADMAP S6):
+interconnect (SNIPPETS [1]–[3] are the exemplar ring kernels).  On the
+four-chip v5e host it does not beat psum at any payload timed (PERF.md
+Findings, PR 29; ROADMAP D2a):
 
 ``ring_allreduce``
     Chunked ring reduce-scatter + all-gather of any float32 array, as one
@@ -29,17 +30,6 @@ that beats psum on ICI is unmeasured (ROADMAP S6):
     differs, keeping the two launches' barriers from aliasing when one
     program runs both.
 
-``fused_segment_hist_ring``
-    The full gather→histogram→ring-allreduce fusion: extends
-    ``histogram_pallas_fused``'s VMEM-resident row gather + 16×16
-    nibble-fold MXU accumulation with the ring schedule.  Feature blocks
-    are grouped into one chunk per device; the kernel computes chunk
-    ``my_id`` first, then at ring step ``s`` starts the remote DMA of the
-    just-finished partial while the MXU accumulates the NEXT chunk's
-    histogram — ICI transfer and compute overlap by construction, and the
-    reduced histogram never round-trips HBM between the gather and the
-    collective.
-
 Semantics are pinned on CPU via Pallas interpret mode (remote DMAs
 discharge to ``all_gather`` exchanges on a forced multi-device host
 platform), which is how tier-1 tests hold without a chip; the interpret
@@ -62,7 +52,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.backend import pallas_interpret
-from .pallas_histogram import BMAX, FB, LO, _accum_dtypes
 
 #: VMEM gate for the dense ring all-reduce: the flattened array plus the
 #: double-buffered work/comm chunks must stay resident (the output
@@ -71,17 +60,10 @@ from .pallas_histogram import BMAX, FB, LO, _accum_dtypes
 #: histogram state while refusing pathological f that would thrash VMEM.
 RING_MAX_BYTES = 4 << 20
 
-#: VMEM gate for the fused gather→hist→ring kernel: the whole (fp, n)
-#: binsT block stays resident for the in-kernel gather (the DISTRIBUTED
-#: shard's rows — n here is n_local = n_global / D, which is what makes
-#: whole-matrix residency affordable exactly when the ring applies).
-FUSED_RING_MAX_BINST_BYTES = 6 << 20
-
 #: Mosaic collective ids for the kernel families (any constant works
 #: as long as every device in the gang runs the same program; distinct
 #: ids keep the kernels' barriers from aliasing).
 _RING_COLLECTIVE_ID = 7
-_FUSED_RING_COLLECTIVE_ID = 8
 _SELECT_RING_COLLECTIVE_ID = 9
 
 
@@ -325,159 +307,3 @@ def ring_allreduce_select_or_psum(hist: jnp.ndarray, cand: jnp.ndarray,
         return _ring_flat(slab, axis_name, num_devices, pallas_interpret(),
                           _SELECT_RING_COLLECTIVE_ID)
     return jax.lax.psum(slab, axis_name)
-
-
-# -- fused gather → segment histogram → ring all-reduce ----------------------
-
-
-def _fused_hist_ring_kernel(binsT_ref, idx_ref, gh_ref, out_ref,
-                            work, comm, send_sem, recv_sem, cap_sem,
-                            lo_scr, hi_scr, *,
-                            axis_name: str, num_dev: int, cb: int,
-                            row_chunk: int, n_row_chunks: int,
-                            accum_dtype, interpret: bool):
-    """Gather + nibble-fold histogram + ring reduce in ONE kernel.
-
-    Feature blocks are grouped into ``num_dev`` chunks of ``cb`` blocks.
-    :func:`_ring_schedule` computes chunk ``(my_id - s) % D``'s local
-    histogram with the MXU (in-VMEM row gather, exactly the
-    ``histogram_pallas_fused`` inner loop) WHILE the previous chunk's
-    partial rides the ICI to the right neighbor.  The accumulation order
-    inside each (block, channel) product is identical to
-    ``histogram_pallas_fused`` (ascending row chunks), so at D = 2 the
-    result is bit-identical to gather→hist→psum.
-    """
-    acc_t = out_ref.dtype          # f32, or int32 when quantized
-    c = row_chunk
-    iota16 = jax.lax.broadcasted_iota(jnp.int32, (c, LO), 1)
-
-    def compute_chunk(chunk_idx, slot):
-        """Local histogram of feature-block chunk ``chunk_idx`` into
-        ``work[slot]`` — the _fused_kernel gather+MXU loop, with the
-        block row offset dynamic (it depends on ``my_id``)."""
-        for b in range(cb):
-            row0 = (chunk_idx * cb + b) * FB
-            for ch in range(3):
-                work[slot, b, ch] = jnp.zeros_like(work[slot, b, ch])
-
-            def row_body(j, _):
-                idxc = idx_ref[pl.ds(j * c, c)]
-                g = gh_ref[pl.ds(j * c, c), :].astype(acc_t)
-                for f in range(FB):
-                    col = jnp.take(
-                        binsT_ref[pl.ds(row0 + f, 1), :][0], idxc,
-                        axis=0).astype(jnp.int32)[:, None]
-                    lo_scr[:, f * LO:(f + 1) * LO] = \
-                        (col % LO == iota16).astype(accum_dtype)
-                    hi_scr[:, f * LO:(f + 1) * LO] = \
-                        (col // LO == iota16).astype(acc_t)
-                lo_oh = lo_scr[...]
-                hi_oh = hi_scr[...]
-                for ch in range(3):
-                    rhs = (hi_oh * g[:, ch][:, None]).astype(accum_dtype)
-                    work[slot, b, ch] += jax.lax.dot_general(
-                        lo_oh, rhs,
-                        dimension_numbers=(((0,), (0,)), ((), ())),
-                        preferred_element_type=acc_t)
-                return 0
-
-            jax.lax.fori_loop(0, n_row_chunks, row_body, 0)
-
-    def accumulate(slot):
-        for b in range(cb):
-            for ch in range(3):
-                work[slot, b, ch] += comm[slot, b, ch]
-
-    _ring_schedule(out_ref, work, comm, send_sem, recv_sem, cap_sem,
-                   axis_name=axis_name, num_dev=num_dev, cb=cb,
-                   load=compute_chunk, accumulate=accumulate,
-                   interpret=interpret)
-
-
-def fused_ring_applicable(f: int, n: int, num_bins: int,
-                          num_devices: int) -> bool:
-    """Static gate for the fused gather→hist→ring kernel: bins must fit
-    the nibble fold, the shard's binsT block must fit VMEM, and the comm
-    buffers (2×2 chunks of cb (3,128,128) products) must stay modest."""
-    if num_devices <= 1 or num_bins > BMAX:
-        return False
-    fp = f + ((-f) % (FB * num_devices))
-    if fp * n > FUSED_RING_MAX_BINST_BYTES:
-        return False
-    cb = fp // FB // num_devices
-    # out + work + comm VMEM budget: (D*cb + 4*cb) products of 196 KB
-    return (num_devices * cb + 4 * cb) * 3 * 128 * 128 * 4 <= (8 << 20)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "size", "axis_name",
-                                    "num_devices", "row_chunk", "accum",
-                                    "interpret"))
-def fused_segment_hist_ring(binsT, gh_sub, idx, num_bins: int, size: int,
-                            axis_name: str, num_devices: int,
-                            row_chunk: int = 1024, accum: str = "float32",
-                            interpret: bool = False) -> jnp.ndarray:
-    """Segment histogram with the row gather AND the cross-shard
-    reduction fused into one kernel (call inside ``shard_map``).
-
-    Args mirror :func:`mmlspark_tpu.ops.pallas_histogram.
-    histogram_pallas_fused` — ``binsT`` is THIS SHARD's (f, n_local)
-    transposed binned matrix, ``idx``/``gh_sub`` the shard's segment rows
-    (pre-clamped/pre-masked, padded entries zero-weighted) — plus the
-    mesh axis to reduce over.  Every shard must call with the same
-    static ``size`` (the grower picks the bucket from the global max
-    count when the ring is active).  Returns the REDUCED (f, num_bins,
-    3) histogram, bit-comparable at D=2 to gathering, calling
-    ``histogram_pallas_fused`` and ``psum``-ing the partials.
-    """
-    if num_bins > BMAX:
-        raise ValueError(f"fused ring histogram supports ≤{BMAX} bins, "
-                         f"got {num_bins}")
-    f, n = binsT.shape
-    if not fused_ring_applicable(f, n, num_bins, num_devices):
-        raise ValueError(
-            f"fused ring histogram gate refused (f={f}, n={n}, "
-            f"D={num_devices}); callers fall back to "
-            f"histogram_pallas_fused + ring_allreduce_or_psum")
-    accum_dtype, out_dtype = _accum_dtypes(accum)
-
-    c = min(row_chunk, size)
-    # pad feature blocks to one chunk of cb blocks per device
-    f_pad = (-f) % (FB * num_devices)
-    if f_pad:
-        binsT = jnp.pad(binsT, ((0, f_pad), (0, 0)))
-    fp = f + f_pad
-    nfb = fp // FB
-    cb = nfb // num_devices
-    s_pad = (-size) % c
-    if s_pad:
-        idx = jnp.pad(idx, (0, s_pad))
-        gh_sub = jnp.pad(gh_sub, ((0, s_pad), (0, 0)))
-
-    out = pl.pallas_call(
-        functools.partial(
-            _fused_hist_ring_kernel, axis_name=axis_name,
-            num_dev=num_devices, cb=cb, row_chunk=c,
-            n_row_chunks=(size + s_pad) // c, accum_dtype=accum_dtype,
-            interpret=interpret),
-        out_shape=jax.ShapeDtypeStruct((nfb, 3, FB * LO, FB * LO),
-                                       out_dtype),
-        scratch_shapes=_ring_scratch((cb, 3, FB * LO, FB * LO),
-                                     out_dtype) + [
-            pltpu.VMEM((c, FB * LO), accum_dtype),
-            pltpu.VMEM((c, FB * LO), out_dtype),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            collective_id=_FUSED_RING_COLLECTIVE_ID),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * 3 * (size + s_pad) * nfb * 128 * 128,
-            bytes_accessed=fp * n + (size + s_pad) * 16,
-            transcendentals=0),
-        interpret=interpret,
-    )(binsT.astype(jnp.int32) if interpret else binsT,
-      idx.astype(jnp.int32), gh_sub.astype(out_dtype))
-    # extract the diagonal 16x16 blocks, exactly like histogram_pallas
-    out = out.reshape(nfb, 3, FB, LO, FB, LO)
-    diag = out[:, :, jnp.arange(FB), :, jnp.arange(FB), :]
-    hist = diag.transpose(1, 0, 4, 3, 2).reshape(fp, BMAX, 3)
-    return hist[:f, :num_bins, :]

@@ -4,27 +4,24 @@ The GBDT hot loop builds per-feature (B, 3) gradient histograms — a scatter
 by bin index, the one primitive TPUs lack.  Matmul reformulations pay a
 structural tax: a per-feature one-hot contraction has only ``B·3`` output
 elements, so the MXU runs at ``B·3 / 128²`` ≈ 4.7 % utilization no matter
-how the nibbles are split (that is what XLA's dot16 path achieves).
+how the nibbles are split, and XLA's own program of it writes the one-hot
+operands to HBM (``ops/histogram._hist_dot16``: 840 bytes a cell whose bin
+is one byte).
 
-This kernel buys utilization back by **folding 8 features into one
-128-wide matmul pair**.  With ``B = 256 = 16·16`` split into lo/hi nibbles
-and combined keys
+:func:`histogram_dot16` buys both back by **folding 8 features into one
+128-wide matmul** and making the operands where the MXU reads them.  With
+``B = 256 = 16·16`` split into hi/lo nibbles and combined keys
 
-  klo = f·16 + (bin % 16)   ∈ [0, 128)
   khi = f·16 + (bin // 16)  ∈ [0, 128)
+  klo = f·16 + (bin % 16)   ∈ [0, 128)
 
-the contraction ``outᶜ = onehot(klo)ᵀ @ (onehot(khi) · ghᶜ)`` is a clean
+the contraction ``accᶜ = onehot(khi) @ (onehot(klo) · ghᶜ)ᵀ`` is a clean
 (128, C) × (C, 128) MXU matmul per gradient channel whose **diagonal**
 16×16 blocks are exactly the 8 features' histograms (off-diagonal blocks
 are cross-feature garbage that costs 8× FLOPs but runs at ~100 % MXU
-utilization — a net win over the 4.7 % structural bound, biggest in bf16).
-Everything stays in VMEM; the kernel emits the full (3, 128, 128) product
-per feature-block and XLA extracts the diagonal afterwards (in-kernel
-lane slicing and reshapes are Mosaic-hostile).
-
-``accum="bfloat16"`` runs the matmul operands in bf16 with f32
-accumulation (preferred_element_type): the one-hot side is exact, only
-grad/hess operand values round.
+utilization).  Operands are bf16 (the one-hot side exact, grad/hess
+rounded once), accumulation is f32; everything stays in VMEM and the
+kernel writes the diagonal blocks only.
 
 This replaces the per-feature scatter-add inside the reference's native
 engine (``LGBM_BoosterUpdateOneIter`` → ConstructHistograms; SURVEY.md §3.1
@@ -42,100 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 LO = 16          # low-nibble width
 FB = 8           # features folded per matmul: FB * LO = 128 lanes
-BMAX = LO * LO   # 256 bins supported; larger falls back to dot16
+BMAX = LO * LO   # 256 bins supported; larger keeps XLA's formulation
 GH = 3           # gradient channels: grad, hess, count
-
-#: Scoped-VMEM ceiling handed to Mosaic.  One grid step holds about ten
-#: lane-padded ``(c, 128)`` 32-bit tiles (the double-buffered ``(c, 3)``
-#: gh block, two one-hot scratches, the transposed bins and the matmul
-#: operands): 19.4 MB at the ``c = 4096`` chunk ``compute_histogram``
-#: uses, which Mosaic's 16 MB default refuses for n >= 262144 rows
-#: (measured by compiling for v5e, PERF.md "Bring-up").  v5e has 128 MiB.
-_VMEM_LIMIT_BYTES = 32 << 20
-
-
-def _accum_dtypes(accum: str):
-    """(matmul operand dtype, accumulator/output dtype) per accum mode.
-
-    ``"int32"`` is the quantized-gradient mode (ISSUE 17): ``gh`` holds
-    integer grid codes, both one-hot operands and the dot accumulate in
-    int32, and the kernel output is EXACT int32 — order-invariant across
-    chunk schedules and reduction topologies."""
-    if accum == "int32":
-        return jnp.int32, jnp.int32
-    if accum == "bfloat16":
-        return jnp.bfloat16, jnp.float32
-    return jnp.float32, jnp.float32
-
-
-def _hist_kernel(binsT_ref, gh_ref, out_ref, lo_scr, hi_scr, *, accum_dtype):
-    """One (feature_block, row_chunk) grid step; accumulates into out_ref."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    acc_t = out_ref.dtype                 # f32, or int32 when quantized
-    bT = binsT_ref[...].T                 # (C, FB) int32
-    g = gh_ref[...].astype(acc_t)         # (C, 3)
-    c = bT.shape[0]
-
-    # Combined one-hots built 16 lanes at a time (per folded feature) into
-    # VMEM scratch — n·(16+16) compares per row-feature instead of n·128.
-    iota16 = jax.lax.broadcasted_iota(jnp.int32, (c, LO), 1)
-    for f in range(FB):
-        col = bT[:, f][:, None]
-        lo_scr[:, f * LO:(f + 1) * LO] = (col % LO == iota16).astype(
-            accum_dtype)
-        hi_scr[:, f * LO:(f + 1) * LO] = (col // LO == iota16).astype(
-            acc_t)
-
-    lo_oh = lo_scr[...]
-    hi_oh = hi_scr[...]
-    for ch in range(3):
-        rhs = (hi_oh * g[:, ch][:, None]).astype(accum_dtype)
-        out_ref[0, ch] += jax.lax.dot_general(
-            lo_oh, rhs, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=acc_t)                 # (128, 128)
-
-
-def _fused_kernel(binsT_ref, idx_ref, gh_ref, out_ref, lo_scr, hi_scr, *,
-                  accum_dtype):
-    """One (feature_block, idx_chunk) grid step of the FUSED
-    gather+histogram: the full (FB, n) binsT block is VMEM-resident
-    across the idx-chunk axis, so the per-segment row gather happens
-    in-register instead of materializing a (size, f) sub-matrix in HBM
-    (PERF.md headroom: the bucket-gather costs as much as the dot16
-    histogram itself, ~26 ns/row)."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    acc_t = out_ref.dtype                       # f32, or int32 (quantized)
-    idx = idx_ref[...]                          # (C,) i32, pre-clamped
-    g = gh_ref[...].astype(acc_t)               # (C, 3), pre-masked
-    c = idx.shape[0]
-
-    iota16 = jax.lax.broadcasted_iota(jnp.int32, (c, LO), 1)
-    for f in range(FB):
-        col = jnp.take(binsT_ref[f, :], idx, axis=0).astype(
-            jnp.int32)[:, None]                 # VMEM gather
-        lo_scr[:, f * LO:(f + 1) * LO] = (col % LO == iota16).astype(
-            accum_dtype)
-        hi_scr[:, f * LO:(f + 1) * LO] = (col // LO == iota16).astype(
-            acc_t)
-
-    lo_oh = lo_scr[...]
-    hi_oh = hi_scr[...]
-    for ch in range(3):
-        rhs = (hi_oh * g[:, ch][:, None]).astype(accum_dtype)
-        out_ref[0, ch] += jax.lax.dot_general(
-            lo_oh, rhs, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=acc_t)
-
 
 #: Rows (lanes) of one grid step of the dot16 kernel, and the scoped VMEM
 #: it may use.  From the sweep on the chip (tools/sweep_histogram.py
@@ -253,151 +158,3 @@ def histogram_dot16(binsT: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
     # out[fold, ch, f*16+hi, lo] -> hist[fold*8+f, hi*16+lo, ch]
     hist = out.reshape(folds, GH, FB, BMAX).transpose(0, 2, 3, 1)
     return hist.reshape(folds * FB, BMAX, GH)[:f, :num_bins]
-
-
-#: VMEM budget gate for the fused kernel: the (FB, n) uint8 binsT block
-#: must stay resident (plus ~1 MB of one-hot scratch and the (3,128,128)
-#: accumulator), so n is capped under VMEM/FB bytes with headroom —
-#: 1.5M rows = 12 MB block on a ~16 MB-VMEM core.
-FUSED_MAX_ROWS = 1_500_000
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "size", "row_chunk",
-                                    "accum", "interpret"))
-def histogram_pallas_fused(binsT, gh_sub, idx, num_bins: int, size: int,
-                           row_chunk: int = 1024, accum: str = "float32",
-                           interpret: bool = False) -> jnp.ndarray:
-    """Segment histogram with the row gather fused into the kernel.
-
-    Args:
-      binsT: ``(f, n)`` uint8/int32 TRANSPOSED binned matrix (the boost
-        scan already keeps ``binsT`` hoisted per fit).
-      gh_sub: ``(size, 3)`` float32 — the segment's gradient rows,
-        gathered by the caller (12 B/row, cheap) and ZERO for padding.
-      idx: ``(size,)`` int32 — the segment row ids (``row_order`` slice),
-        clamped into ``[0, n)``; padded entries may repeat a valid row
-        (their gh is zero).
-      size: static bucket size (the grower's power-of-two ladder).
-
-    Returns ``(f, num_bins, 3)`` float32, bit-comparable to gathering
-    then calling :func:`histogram_pallas`.
-    """
-    if num_bins > BMAX:
-        raise ValueError(f"pallas fused histogram supports ≤{BMAX} bins, "
-                         f"got {num_bins}")
-    f, n = binsT.shape
-    if n > FUSED_MAX_ROWS:
-        raise ValueError(
-            f"fused kernel needs the (8, n) binsT block VMEM-resident; "
-            f"n={n} exceeds {FUSED_MAX_ROWS}")
-    accum_dtype, out_dtype = _accum_dtypes(accum)
-
-    c = min(row_chunk, size)
-    f_pad = (-f) % FB
-    if f_pad:
-        # direct callers only — the grower pre-pads binsT once per tree
-        # so this whole-matrix copy never runs in the split loop
-        binsT = jnp.pad(binsT, ((0, f_pad), (0, 0)))
-    fp = f + f_pad
-    nfb = fp // FB
-    s_pad = (-size) % c
-    if s_pad:
-        idx = jnp.pad(idx, (0, s_pad))
-        gh_sub = jnp.pad(gh_sub, ((0, s_pad), (0, 0)))
-
-    grid = (nfb, (size + s_pad) // c)
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, accum_dtype=accum_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((FB, n), lambda i, j: (i, 0)),   # VMEM-resident
-            pl.BlockSpec((c,), lambda i, j: (j,)),
-            pl.BlockSpec((c, 3), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 3, FB * LO, FB * LO),
-                               lambda i, j: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nfb, 3, FB * LO, FB * LO),
-                                       out_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((c, FB * LO), accum_dtype),
-            pltpu.VMEM((c, FB * LO), out_dtype),
-        ],
-        interpret=interpret,
-        cost_estimate=pl.CostEstimate(
-            flops=2 * 3 * (size + s_pad) * nfb * 128 * 128,
-            bytes_accessed=fp * n + (size + s_pad) * 16,
-            transcendentals=0),
-    )(binsT.astype(jnp.int32) if interpret else binsT,
-      idx.astype(jnp.int32), gh_sub.astype(out_dtype))
-    out = out.reshape(nfb, 3, FB, LO, FB, LO)
-    diag = out[:, :, jnp.arange(FB), :, jnp.arange(FB), :]
-    hist = diag.transpose(1, 0, 4, 3, 2).reshape(fp, BMAX, 3)
-    return hist[:f, :num_bins, :]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "row_chunk", "accum",
-                                    "interpret"))
-def histogram_pallas(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
-                     row_chunk: int = 1024, accum: str = "float32",
-                     interpret: bool = False) -> jnp.ndarray:
-    """Per-feature gradient histograms via a VMEM-resident Pallas kernel.
-
-    Args:
-      bins: ``(n, f)`` int32 bin indices in ``[0, num_bins)``;
-        num_bins ≤ 256.
-      gh: ``(n, 3)`` float32 (grad, hess, count), pre-masked.
-      accum: "float32" | "bfloat16" — MXU operand precision (accumulation
-        is f32 via preferred_element_type) — or "int32" for the
-        quantized-gradient mode: ``gh`` holds integer grid codes and the
-        whole contraction runs (and returns) exact int32.
-
-    Returns:
-      ``(f, num_bins, 3)`` float32 (int32 when ``accum="int32"``).
-    """
-    if num_bins > BMAX:
-        raise ValueError(f"pallas histogram supports ≤{BMAX} bins, "
-                         f"got {num_bins}")
-    n, f = bins.shape
-    accum_dtype, out_dtype = _accum_dtypes(accum)
-
-    c = min(row_chunk, max(128 * ((n + 127) // 128), 128))
-    n_pad = (-n) % c
-    f_pad = (-f) % FB
-    # padded rows point at bin 0 with zero gh weight → no contribution
-    binsT = jnp.pad(bins.T, ((0, f_pad), (0, n_pad)))
-    gh = jnp.pad(gh.astype(out_dtype), ((0, n_pad), (0, 0)))
-    fp, np_ = binsT.shape
-    nfb = fp // FB
-
-    grid = (nfb, np_ // c)
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel, accum_dtype=accum_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((FB, c), lambda i, j: (i, j)),
-            pl.BlockSpec((c, 3), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 3, FB * LO, FB * LO),
-                               lambda i, j: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nfb, 3, FB * LO, FB * LO),
-                                       out_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((c, FB * LO), accum_dtype),
-            pltpu.VMEM((c, FB * LO), out_dtype),
-        ],
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * 3 * np_ * nfb * 128 * 128,
-            bytes_accessed=np_ * fp * 4 + np_ * 12 + nfb * 3 * 128 * 128 * 4,
-            transcendentals=0),
-    )(binsT.astype(jnp.int32), gh)
-    # extract diagonal blocks: out[i, ch, f·16+lo, f·16+hi] → hist
-    out = out.reshape(nfb, 3, FB, LO, FB, LO)
-    diag = out[:, :, jnp.arange(FB), :, jnp.arange(FB), :]  # (FB, nfb, 3, LO, LO)
-    # (FB, nfb, 3, lo, hi) → (nfb, FB, hi, lo, 3) → (f, B, 3)
-    hist = diag.transpose(1, 0, 4, 3, 2).reshape(fp, BMAX, 3)
-    return hist[:f, :num_bins, :]

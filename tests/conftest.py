@@ -109,6 +109,23 @@ def mesh2_2axis():
     return build_mesh(data=2, feature=1, devices=jax.devices()[:2])
 
 
+@pytest.fixture
+def mosaic_interpreted(monkeypatch):
+    """``method="dot16"`` takes the Mosaic kernel as on the TPU, run by
+    the Pallas interpreter; returns the calls the kernel received."""
+    import mmlspark_tpu.ops.histogram as H
+    import mmlspark_tpu.ops.pallas_histogram as PH
+    calls = []
+    real = PH.histogram_dot16
+    monkeypatch.setattr(PH, "histogram_dot16", lambda *a, **k: (
+        calls.append(a[0].shape), real(*a, **k))[1])
+    monkeypatch.setattr(H, "_dot16_on_chip",
+                        lambda num_bins, quantized: (not quantized
+                                                     and num_bins <= 256))
+    monkeypatch.setattr(H, "pallas_interpret", lambda: True)
+    return calls
+
+
 @pytest.fixture(scope="session")
 def binary_table(rng):
     """Small adult-income-shaped binary classification table."""

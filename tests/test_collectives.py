@@ -7,7 +7,8 @@ chunk rotation, slot reuse, reduction order — are exercised without a
 chip.  The bit-parity contract is pinned at D=2 (pairwise float adds
 commute, so ring == psum bitwise); larger rings are ulp-rotated and
 tested with allclose.  chip_smoke.py runs the same kernels through Mosaic
-on the four-chip host; no ring-vs-psum timing exists yet (ROADMAP S6).
+on the four-chip host; PERF.md (Findings, PR 29) has the one timing of
+ring against psum there.
 """
 
 import numpy as np
@@ -194,117 +195,77 @@ class TestRingAllreduceSelect:
                                       np.asarray(hist)[[4, 1, 7]])
 
 
-class TestFusedSegmentHistRing:
-    """The gather→hist→ring kernel vs the gather→hist→psum reference, at
-    the partition grower's real pow2 bucket ladder."""
-
-    @pytest.mark.parametrize("size", [2048, 4096, 8192, 16384])
-    def test_bucket_ladder_bit_parity(self, size, rng, mesh2):
-        from mmlspark_tpu.ops.pallas_collectives import (
-            fused_ring_applicable, fused_segment_hist_ring)
-        from mmlspark_tpu.ops.pallas_histogram import histogram_pallas_fused
-        d, f, n_local, B = 2, 11, 1500, 64
-        assert fused_ring_applicable(f, n_local, B, d)
-        binsT = jax.device_put(
-            jnp.asarray(rng.integers(0, B, size=(d * f, n_local)),
-                        jnp.int32),
-            NamedSharding(mesh2, P(DATA_AXIS, None)))
-        gh = jax.device_put(
-            jnp.asarray(rng.normal(size=(d * size, 3)), jnp.float32),
-            NamedSharding(mesh2, P(DATA_AXIS, None)))
-        idx = jax.device_put(
-            jnp.asarray(rng.integers(0, n_local, size=(d * size,)),
-                        jnp.int32),
-            NamedSharding(mesh2, P(DATA_AXIS)))
-        in_specs = (P(DATA_AXIS, None), P(DATA_AXIS, None), P(DATA_AXIS))
-        out_spec = P(DATA_AXIS, None, None)
-        got = np.asarray(_smap(
-            lambda b, g, i: fused_segment_hist_ring(
-                b, g, i, B, size, DATA_AXIS, d, interpret=True),
-            mesh2, in_specs, out_spec)(binsT, gh, idx))
-        want = np.asarray(_smap(
-            lambda b, g, i: jax.lax.psum(
-                histogram_pallas_fused(b, g, i, B, size, interpret=True),
-                DATA_AXIS),
-            mesh2, in_specs, out_spec)(binsT, gh, idx))
-        np.testing.assert_array_equal(got, want)
-
-    @pytest.mark.slow
-    def test_bucket_65536_bit_parity(self, rng, mesh2):
-        """Top of the committed ladder — minutes-scale in interpret
-        mode, so it rides the slow marker like the other long tails."""
-        self.test_bucket_ladder_bit_parity(65536, rng, mesh2)
-
-    def test_full_256_bins_and_odd_features(self, rng, mesh2):
-        """B=256 (full nibble fold) with a feature count that needs both
-        the 8-fold and the per-device chunk padding."""
-        from mmlspark_tpu.ops.pallas_collectives import (
-            fused_segment_hist_ring)
-        from mmlspark_tpu.ops.pallas_histogram import histogram_pallas_fused
-        d, f, n_local, B, size = 2, 13, 700, 256, 512
-        binsT = jax.device_put(
-            jnp.asarray(rng.integers(0, B, size=(d * f, n_local)),
-                        jnp.int32),
-            NamedSharding(mesh2, P(DATA_AXIS, None)))
-        gh = jax.device_put(
-            jnp.asarray(rng.normal(size=(d * size, 3)), jnp.float32),
-            NamedSharding(mesh2, P(DATA_AXIS, None)))
-        idx = jax.device_put(
-            jnp.asarray(rng.integers(0, n_local, size=(d * size,)),
-                        jnp.int32),
-            NamedSharding(mesh2, P(DATA_AXIS)))
-        in_specs = (P(DATA_AXIS, None), P(DATA_AXIS, None), P(DATA_AXIS))
-        out_spec = P(DATA_AXIS, None, None)
-        got = np.asarray(_smap(
-            lambda b, g, i: fused_segment_hist_ring(
-                b, g, i, B, size, DATA_AXIS, d, interpret=True),
-            mesh2, in_specs, out_spec)(binsT, gh, idx))
-        want = np.asarray(_smap(
-            lambda b, g, i: jax.lax.psum(
-                histogram_pallas_fused(b, g, i, B, size, interpret=True),
-                DATA_AXIS),
-            mesh2, in_specs, out_spec)(binsT, gh, idx))
-        np.testing.assert_array_equal(got, want)
-
-    def test_vmem_gate_refuses_oversized_binst(self):
-        from mmlspark_tpu.ops.pallas_collectives import (
-            FUSED_RING_MAX_BINST_BYTES, fused_ring_applicable)
-        # boundary: exactly at the gate passes, one row past fails
-        d, f = 2, 16          # fp = 16 (already 8*D aligned)
-        n_ok = FUSED_RING_MAX_BINST_BYTES // f
-        assert fused_ring_applicable(f, n_ok, 64, d)
-        assert not fused_ring_applicable(f, n_ok + 1, 64, d)
-        # > BMAX bins can never fuse
-        assert not fused_ring_applicable(f, 1000, 512, d)
-        # serial (single shard) has nothing to ring over
-        assert not fused_ring_applicable(f, 1000, 64, 1)
+_LADDER = [2048, 4096, 8192, 16384]
 
 
-class TestFusedMaxRowsBoundary:
-    def test_histogram_pallas_fused_gate(self):
-        """The n <= FUSED_MAX_ROWS VMEM gate: at the boundary the kernel
-        runs; one row past raises (grower falls back to the bucket
-        gather + plain kernel path)."""
-        from mmlspark_tpu.ops.pallas_histogram import (
-            FB, FUSED_MAX_ROWS, histogram_pallas_fused)
-        binsT = jnp.zeros((FB, FUSED_MAX_ROWS), jnp.uint8)
-        out = histogram_pallas_fused(
-            binsT, jnp.zeros((8, 3), jnp.float32),
-            jnp.zeros((8,), jnp.int32), num_bins=16, size=8,
-            interpret=True)
-        assert out.shape == (FB, 16, 3)
-        with pytest.raises(ValueError, match="VMEM-resident"):
-            histogram_pallas_fused(
-                jnp.zeros((FB, FUSED_MAX_ROWS + 1), jnp.uint8),
-                jnp.zeros((8, 3), jnp.float32),
-                jnp.zeros((8,), jnp.int32), num_bins=16, size=8,
-                interpret=True)
+class TestSegmentHistUnderShardMap:
+    """What a mesh fit does at every split, with either build of dot16:
+    each shard histograms its own segment through the bucket ladder
+    (``grower._segment_hist``) and the partials are reduced
+    (``_reduce_hist``).  Held to the ``segment`` histogram of the two
+    shards' segments together: counts bit for bit, grad and hess to the
+    bf16-operand bound (the kernel rounds them once; the CPU's XLA build
+    does not round at all)."""
+
+    def _check(self, build, size, f, B, rng, mesh2, request):
+        from mmlspark_tpu.gbdt.grower import (GrowerConfig, _reduce_hist,
+                                              _segment_hist)
+        from mmlspark_tpu.ops.histogram import (compute_histogram,
+                                                histogram_build)
+        if build == "dot16/mosaic":
+            request.getfixturevalue("mosaic_interpreted")
+        d, n = 2, _LADDER[-1] + 600
+        cfg = GrowerConfig(num_bins=B, hist_method="dot16",
+                           axis_name=DATA_AXIS, data_axis_size=d)
+        assert histogram_build("dot16", B, False) == build
+        bins = rng.integers(0, B, size=(d, n, f)).astype(np.uint8)
+        gh = np.concatenate([rng.normal(size=(d, n, 2)),
+                             np.ones((d, n, 1))], axis=2).astype(np.float32)
+        # a leaf's segment somewhere inside each shard's permutation, of
+        # a length only this rung of the ladder holds; the tail that the
+        # bucket reads past it belongs to other leaves or is sentinels
+        order = np.stack([np.concatenate([rng.permutation(n),
+                                          np.full(_LADDER[-1], n)])
+                          for _ in range(d)]).astype(np.int32)
+        off = np.asarray([37, 0], np.int32)
+        cnt = np.asarray([size, size // 2 + 1], np.int32)
+
+        def shard(b, g, ro, o, c):
+            h = _segment_hist(b[0], g[0], ro[0], o[0], c[0], n, _LADDER,
+                              cfg)
+            return _reduce_hist(h, cfg)[None]
+
+        rows = P(DATA_AXIS)
+        got = np.asarray(_smap(shard, mesh2, (rows,) * 5, rows)(
+            bins, gh, order, off, cnt))
+        np.testing.assert_array_equal(got[0], got[1])     # reduced
+        seg = [order[k, off[k]:off[k] + cnt[k]] for k in range(d)]
+        seg_bins = np.concatenate([bins[k][seg[k]] for k in range(d)])
+        seg_gh = np.concatenate([gh[k][seg[k]] for k in range(d)])
+        want = np.asarray(compute_histogram(seg_bins, seg_gh, B,
+                                            method="segment"), np.float64)
+        mass = np.asarray(compute_histogram(seg_bins, np.abs(seg_gh), B,
+                                            method="segment"), np.float64)
+        assert got[0].shape == (f, B, 3)
+        np.testing.assert_array_equal(got[0][..., 2], want[..., 2])
+        assert np.all(np.abs(got[0] - want) <= 2.0 ** -8 * mass + 1e-4)
+
+    @pytest.mark.parametrize("build", ["dot16/xla", "dot16/mosaic"])
+    @pytest.mark.parametrize("size", _LADDER)
+    def test_bucket_ladder_parity(self, build, size, rng, mesh2, request):
+        self._check(build, size, 11, 64, rng, mesh2, request)
+
+    @pytest.mark.parametrize("build", ["dot16/xla", "dot16/mosaic"])
+    def test_full_256_bins_and_odd_features(self, build, rng, mesh2,
+                                            request):
+        """B = 256 (every nibble pair in use) with a feature count that
+        leaves the kernel's last fold of 8 short."""
+        self._check(build, 2048, 13, 256, rng, mesh2, request)
 
 
 class TestForestIdentity:
     """End-to-end: collective='ring' forests are BIT-IDENTICAL to their
-    psum references on the 2-device mesh — the dense ring behind dot16
-    and the fully fused pallas_ring kernel both."""
+    psum references on the 2-device mesh."""
 
     def _fit(self, method, collective, mesh, **kw):
         from mmlspark_tpu.gbdt import fit_bin_mapper
@@ -336,11 +297,6 @@ class TestForestIdentity:
     def test_dense_ring_forest_identity(self, mesh2_2axis):
         a = self._fit("dot16", "psum", mesh2_2axis)
         b = self._fit("dot16", "ring", mesh2_2axis)
-        self._assert_forests_equal(a, b)
-
-    def test_fused_ring_forest_identity(self, mesh2_2axis):
-        a = self._fit("pallas_fused", "psum", mesh2_2axis)
-        b = self._fit("pallas_ring", "ring", mesh2_2axis)
         self._assert_forests_equal(a, b)
 
     def test_voting_ring_forest_identity(self, mesh2_2axis):
@@ -390,21 +346,19 @@ class TestForestIdentity:
 
     def test_resolution_recorded(self, mesh2_2axis):
         from mmlspark_tpu.gbdt.engine import last_fit_info
-        self._fit("pallas_ring", "ring", mesh2_2axis)
+        self._fit("dot16", "ring", mesh2_2axis)
         assert last_fit_info["collective"] == "ring"
-        assert last_fit_info["histogram_method"] == "pallas_ring"
+        assert last_fit_info["histogram_method"] == "dot16"
         # ... and the /metrics exposition names the resolved kernel
         from mmlspark_tpu.core import telemetry as tm
         text = tm.get_registry().render_prometheus()
         assert "mmlspark_tpu_train_histogram_method_info" in text
-        assert 'histogram_method="pallas_ring"' in text
+        assert 'histogram_method="dot16"' in text
         assert 'collective="ring"' in text
 
 
 class TestResolutionAndFallback:
-    @pytest.mark.parametrize("method,collective", [
-        ("dot16", "ring"), ("pallas_ring", "ring"),
-        ("pallas_fused", "psum"), ("pallas", "psum")])
+    @pytest.mark.parametrize("method,collective", [("dot16", "ring")])
     def test_refused_kernel_raises_not_downgrades(
             self, monkeypatch, mesh2_2axis, method, collective):
         """On TPU an explicitly requested kernel the compiler refuses

@@ -307,7 +307,6 @@ def test_hist_build_schedule_counts_the_fused_call_sites(monkeypatch, case,
     import mmlspark_tpu.ops.histogram as H
     from mmlspark_tpu.gbdt.grower import GrowerConfig, hist_build_schedule
     monkeypatch.setattr(H.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(H, "_SWEEP_CACHE", {})
     cfg = GrowerConfig(num_leaves=255, num_bins=255)
     n = 400_000
     if case == "epsilon a chip of four":

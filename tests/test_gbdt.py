@@ -511,22 +511,6 @@ class TestPassThroughArgs:
             assert tr.num_leaves <= 5
         assert "[custom_tag: abc]" in s
 
-    def test_pass_through_packed_gather_identical_model(self):
-        from mmlspark_tpu.gbdt import LightGBMClassifier
-        rng = np.random.default_rng(1)
-        X = rng.normal(size=(2000, 8)).astype(np.float32)
-        y = ((X[:, 0] + X[:, 1] * X[:, 2]) > 0).astype(float)
-        t = {"features": X, "label": y}
-        kw = dict(numIterations=4, numLeaves=7, verbosity=0,
-                  histogramMethod="dot16")
-        a = LightGBMClassifier(**kw).fit(t)
-        b = LightGBMClassifier(**kw,
-                               passThroughArgs="packed_gather=true").fit(t)
-        for x, z in zip(a.getModel().trees, b.getModel().trees):
-            np.testing.assert_array_equal(x.split_feature, z.split_feature)
-            np.testing.assert_allclose(x.leaf_value, z.leaf_value,
-                                       rtol=1e-6, atol=1e-7)
-
 
 # ------------------------------------------- the histogram cache's slots
 

@@ -110,7 +110,7 @@ class TestNativeHistogram:
                                                 _native_available)
         if not _native_available():
             pytest.skip("native toolchain unavailable")
-        assert _auto_method(100_000) == "native"
+        assert _auto_method() == "native"
 
 
 class TestNativePartitionParity:
@@ -155,49 +155,6 @@ class TestNativePartitionParity:
             np.testing.assert_array_equal(x.decision_type, z.decision_type)
             np.testing.assert_allclose(x.leaf_value, z.leaf_value,
                                        rtol=1e-4, atol=1e-6)
-
-
-class TestPackedGather:
-    """packed_gather (four uint8 bins per u32 word in the segment gather)
-    must be a pure layout change: identical trees, any histogram method."""
-
-    def _grow(self, packed, method="dot16"):
-        import jax.numpy as jnp
-        from mmlspark_tpu.gbdt.grower import (GrowerConfig, grow_tree,
-                                              make_feat_info)
-        rng = np.random.default_rng(4)
-        n, f, B = 3000, 10, 64
-        bins = rng.integers(0, B, size=(n, f)).astype(np.uint8)
-        y = (bins[:, 0] > 30).astype(np.float32) + rng.normal(
-            scale=0.1, size=n).astype(np.float32)
-        g = (y - y.mean()).astype(np.float32)
-        gh = np.stack([g, np.ones(n, np.float32),
-                       np.ones(n, np.float32)], axis=1)
-        cfg = GrowerConfig(num_leaves=15, num_bins=B, min_data_in_leaf=5,
-                           hist_method=method, packed_gather=packed)
-        return grow_tree(jnp.asarray(bins), jnp.asarray(gh),
-                         make_feat_info(f), cfg)
-
-    def test_packed_matches_plain(self):
-        t0, rl0 = self._grow(False)
-        t1, rl1 = self._grow(True)
-        np.testing.assert_array_equal(np.asarray(t0.node_feat),
-                                      np.asarray(t1.node_feat))
-        np.testing.assert_array_equal(np.asarray(t0.node_bin),
-                                      np.asarray(t1.node_bin))
-        np.testing.assert_allclose(np.asarray(t0.leaf_value),
-                                   np.asarray(t1.leaf_value),
-                                   rtol=1e-6, atol=1e-7)
-        np.testing.assert_array_equal(np.asarray(rl0), np.asarray(rl1))
-
-    def test_packed_matches_plain_segment_method(self):
-        t0, _ = self._grow(False, method="segment")
-        t1, _ = self._grow(True, method="segment")
-        np.testing.assert_array_equal(np.asarray(t0.node_feat),
-                                      np.asarray(t1.node_feat))
-        np.testing.assert_allclose(np.asarray(t0.leaf_value),
-                                   np.asarray(t1.leaf_value),
-                                   rtol=1e-6, atol=1e-7)
 
 
 class TestNativeFindSplit:
@@ -250,81 +207,132 @@ class TestNativeFindSplit:
         assert mismatched_winner == 0
 
 
-class TestPallasFused:
-    """Fused gather+histogram kernel (VERDICT r4 next #1): in-kernel VMEM
-    row gather must reproduce gather-then-histogram exactly (interpret
-    mode on CPU only: Mosaic refuses the in-kernel gather, PERF.md
-    "Bring-up on v5e")."""
+class TestDot16Fit:
+    """A whole fit on either build of ``dot16``.  ``seed`` draws nothing
+    in these fits (no bagging, no feature fraction) but is part of the
+    jitted programs' static configuration, so a fit under the patched
+    build does not replay the other build's cached trace."""
 
-    def test_fused_matches_gather_then_pallas(self):
-        from mmlspark_tpu.ops.pallas_histogram import (
-            histogram_pallas, histogram_pallas_fused)
-        rng = np.random.default_rng(0)
-        n, f, B, size = 3000, 11, 64, 1024
-        binsM = rng.integers(0, B, size=(n, f)).astype(np.int32)
-        gh = rng.normal(size=(n, 3)).astype(np.float32)
-        idx = rng.choice(n, size, replace=False).astype(np.int32)
-        cnt = 700
-        ghs = gh[idx] * (np.arange(size) < cnt).astype(np.float32)[:, None]
-        fused = np.asarray(histogram_pallas_fused(
-            jnp.asarray(binsM.T), jnp.asarray(ghs), jnp.asarray(idx),
-            B, size, interpret=True))
-        ref = np.asarray(histogram_pallas(
-            jnp.asarray(binsM[idx]), jnp.asarray(ghs), B,
-            interpret=True))
-        np.testing.assert_allclose(fused, ref, rtol=1e-6, atol=1e-6)
-
-    def test_fused_fit_forest_matches_dot16(self):
-        """End-to-end: a tiny fit with hist_method='pallas_fused' grows
-        the same forest as dot16 (both nibble-fold formulations)."""
+    @staticmethod
+    def _fit(seed, mesh=None):
         from mmlspark_tpu.gbdt import fit_bin_mapper
         from mmlspark_tpu.gbdt.engine import TrainParams, train
         from mmlspark_tpu.gbdt.objectives import get_objective
         rng = np.random.default_rng(1)
-        X = rng.normal(size=(600, 8))
-        y = (X[:, 0] - X[:, 2] > 0).astype(np.float64)
-        mapper = fit_bin_mapper(X, max_bin=63)
-        bins = mapper.transform_packed(X)
-
-        def fit(method):
-            return train(bins, y, None, mapper, get_objective("binary"),
-                         TrainParams(num_iterations=3, num_leaves=7,
-                                     min_data_in_leaf=5, max_bin=63,
-                                     histogram_method=method,
-                                     verbosity=0))
-        a = fit("pallas_fused")
-        b = fit("dot16")
-        assert len(a.trees) == len(b.trees)
-        for s, t in zip(a.trees, b.trees):
-            np.testing.assert_array_equal(s.split_feature, t.split_feature)
-            np.testing.assert_allclose(s.leaf_value, t.leaf_value,
-                                       rtol=1e-5, atol=1e-7)
-
-    def test_fused_fit_matches_dot16_under_data_mesh(self):
-        """pallas_fused inside the shard_mapped grower: the in-kernel
-        gather runs on each shard's local binsT block; psum composes the
-        partial histograms as usual — forest equality vs dot16."""
-        from mmlspark_tpu.core.mesh import build_mesh
-        from mmlspark_tpu.gbdt import fit_bin_mapper
-        from mmlspark_tpu.gbdt.engine import TrainParams, train
-        from mmlspark_tpu.gbdt.objectives import get_objective
-        rng = np.random.default_rng(2)
         X = rng.normal(size=(640, 8))
         y = (X[:, 0] - X[:, 2] > 0).astype(np.float64)
         mapper = fit_bin_mapper(X, max_bin=63)
         bins = mapper.transform_packed(X)
+        return train(bins, y, None, mapper, get_objective("binary"),
+                     TrainParams(num_iterations=3, num_leaves=7,
+                                 min_data_in_leaf=5, max_bin=63,
+                                 histogram_method="dot16", seed=seed,
+                                 verbosity=0), mesh=mesh)
 
-        def fit(method):
-            return train(bins, y, None, mapper, get_objective("binary"),
-                         TrainParams(num_iterations=2, num_leaves=7,
-                                     min_data_in_leaf=5, max_bin=63,
-                                     histogram_method=method, verbosity=0),
-                         mesh=build_mesh(data=8, feature=1))
-        a, b = fit("pallas_fused"), fit("dot16")
+    @staticmethod
+    def _mesh(kind):
+        from mmlspark_tpu.core.mesh import build_mesh
+        return build_mesh(data=8, feature=1) if kind == "mesh" else None
+
+    @pytest.mark.parametrize("kind", ["serial", "mesh"])
+    def test_mosaic_fit_grows_the_xla_fits_forest(self, kind,
+                                                  mosaic_interpreted):
+        """The two builds of one method: the same splits, leaf values to
+        the kernel's bf16 operands."""
+        from mmlspark_tpu.gbdt.engine import last_fit_info
+        a = self._fit(seed=101, mesh=self._mesh(kind))
+        assert mosaic_interpreted, "the fit never reached the kernel"
+        assert last_fit_info["hist_build"] == "dot16/mosaic"
+        with pytest.MonkeyPatch.context() as mp:
+            import mmlspark_tpu.ops.histogram as H
+            mp.setattr(H, "_dot16_on_chip", lambda *a: False)
+            b = self._fit(seed=102, mesh=self._mesh(kind))
+            assert last_fit_info["hist_build"] == "dot16/xla"
+        assert len(a.trees) == len(b.trees) == 3
         for s, t in zip(a.trees, b.trees):
             np.testing.assert_array_equal(s.split_feature, t.split_feature)
+            np.testing.assert_array_equal(s.threshold, t.threshold)
             np.testing.assert_allclose(s.leaf_value, t.leaf_value,
-                                       rtol=1e-5, atol=1e-7)
+                                       rtol=2e-2, atol=1e-4)
+
+    @pytest.mark.parametrize("kind", ["serial", "mesh"])
+    def test_a_kernel_that_raises_fails_the_fit(self, kind, monkeypatch,
+                                                mosaic_interpreted):
+        """What was asked for or an error: a fit whose kernel raises (as
+        a compiler's refusal would) raises that error, and what the fit
+        recorded names no other build and no downgrade."""
+        import mmlspark_tpu.ops.pallas_histogram as PH
+        from mmlspark_tpu.gbdt.engine import last_fit_info
+
+        def refuse(*a, **k):
+            raise NotImplementedError("the compiler's own words")
+        monkeypatch.setattr(PH, "histogram_dot16", refuse)
+        with pytest.raises(NotImplementedError, match="compiler's own"):
+            self._fit(seed=103, mesh=self._mesh(kind))
+        assert last_fit_info["histogram_method"] == "dot16"
+        assert last_fit_info["hist_build"] == "dot16/mosaic"
+        assert last_fit_info["collective_downgrade"] == "none"
+
+
+_REMOVED = ["pallas", "pallas_bf16", "pallas_fused", "pallas_ring"]
+
+
+class TestMethodNames:
+    @pytest.mark.parametrize("site", ["compute_histogram", "TrainParams"])
+    @pytest.mark.parametrize("name", _REMOVED)
+    def test_a_removed_name_is_refused_with_the_valid_ones(self, name,
+                                                           site):
+        """Before any device work: at the histogram's entry, and where a
+        fit's parameters are made (typed or through passThroughArgs)."""
+        from mmlspark_tpu.gbdt.engine import TrainParams
+        valid = "valid: auto, native, segment, dot16, onehot"
+        if site == "compute_histogram":
+            with pytest.raises(ValueError, match=valid):
+                compute_histogram(np.zeros((4, 2), np.uint8),
+                                  np.zeros((4, 3), np.float32), 16,
+                                  method=name)
+            return
+        with pytest.raises(ValueError, match=valid):
+            TrainParams(histogram_method=name)
+        with pytest.raises(ValueError, match=valid):
+            TrainParams(pass_through={"histogram_method": name})
+
+    @pytest.mark.parametrize("rows", [2048, 30_000_000])
+    @pytest.mark.parametrize("backend,native,want", [
+        ("cpu", True, "native"), ("cpu", False, "segment"),
+        ("tpu", False, "dot16")])
+    def test_auto_follows_the_backend_alone(self, monkeypatch, backend,
+                                            native, want, rows):
+        """The rule, with no table: native on the CPU where the
+        extension is built, segment without it, dot16 on the TPU; and
+        the same build at the root and at every rung, whatever the
+        rows."""
+        import mmlspark_tpu.ops.histogram as H
+        from mmlspark_tpu.gbdt.grower import (GrowerConfig,
+                                              hist_build_schedule)
+        monkeypatch.setattr(H.jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(H, "_native_available", lambda: native)
+        assert H._auto_method() == want
+        sched = hist_build_schedule(GrowerConfig(num_bins=255), rows)
+        assert sched["build"] == H.histogram_build("auto", 255, False) == (
+            "dot16/mosaic" if want == "dot16" else want)
+        assert sched["fused"] in (0, sched["sites"])
+        assert sched["sites"] >= 2
+
+    def test_the_param_text_names_the_methods_the_code_accepts(self):
+        """``histogramMethod``'s description and ``METHODS`` cannot
+        drift apart."""
+        import re
+
+        from mmlspark_tpu.gbdt.base import LightGBMBase
+        from mmlspark_tpu.ops.histogram import METHODS
+        text = LightGBMBase.histogramMethod.doc
+        names = [w for w in re.findall(r"[a-z_0-9]+", text.split(":", 1)[1])
+                 if w in METHODS or w.startswith("pallas")]
+        assert sorted(set(names)) == sorted(METHODS)
+        for name in METHODS:
+            compute_histogram(np.zeros((4, 2), np.uint8),
+                              np.zeros((4, 3), np.float32), 16, method=name)
 
 
 def _bf16(x):
@@ -445,7 +453,7 @@ class TestDot16OnChip:
             got = np.asarray(compute_histogram(bins, gh, 255, method=method))
             np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
         assert calls == [(7, 300), (7, 300)]
-        assert on_tpu.histogram_build("auto", 300, 255, False) == \
+        assert on_tpu.histogram_build("auto", 255, False) == \
             "dot16/mosaic"
 
     @pytest.mark.parametrize("why,B,dtype", [
@@ -469,12 +477,12 @@ class TestDot16OnChip:
         np.testing.assert_allclose(
             got, _ref_hist(bins, gh.astype(np.float64), B), atol=1e-3)
         assert on_tpu.histogram_build(
-            "dot16", 400, B, dtype == np.int16) == "dot16/xla"
+            "dot16", B, dtype == np.int16) == "dot16/xla"
 
     def test_cpu_keeps_xla_for_an_explicit_dot16(self):
         import mmlspark_tpu.ops.histogram as H
-        assert H.histogram_build("dot16", 4096, 255, False) == "dot16/xla"
-        assert H.histogram_build("auto", 4096, 255, False) in (
+        assert H.histogram_build("dot16", 255, False) == "dot16/xla"
+        assert H.histogram_build("auto", 255, False) in (
             "native", "segment")
 
     def test_kernel_refuses_more_than_256_bins(self):
@@ -482,66 +490,3 @@ class TestDot16OnChip:
         with pytest.raises(ValueError, match="256"):
             histogram_dot16(jnp.zeros((8, 128), jnp.uint8),
                             jnp.zeros((128, 3)), 300, interpret=True)
-
-
-class TestSweepSanitize:
-    """_auto_method must never rank a 0.0-clamped sweep reading (ISSUE 10
-    satellite): a slope that clamped to zero sat below the dispatch-noise
-    floor and says nothing about which method wins."""
-
-    def test_committed_tpu_table_drops_clamped_buckets(self):
-        """The REAL committed _sweep_tpu.json carries pallas=0.0 at 2048
-        and dot16=0.0 at 4096/8192/65536; sanitization must refuse to
-        rank those buckets while keeping the resolved 16384/32768 ones."""
-        import json
-        import os
-
-        import mmlspark_tpu.ops.histogram as H
-        path = os.path.join(os.path.dirname(H.__file__), "_sweep_tpu.json")
-        with open(path) as fh:
-            doc = json.load(fh)
-        table = H._sanitize_sweep(doc)
-        assert table is not None
-        for rows in ("2048", "4096", "8192", "65536"):
-            assert rows not in table, \
-                f"bucket {rows} has a 0.0-clamped reading and must " \
-                "not be ranked"
-        assert table.get("16384") == "dot16"
-        assert table.get("32768") == "dot16"
-
-    def test_winner_with_zero_reading_refused(self):
-        from mmlspark_tpu.ops.histogram import _sanitize_sweep
-        doc = {"winner_by_rows": {"2048": "pallas", "4096": "dot16"},
-               "times_us_by_rows": {
-                   "2048": {"pallas": 0.0, "dot16": 10.0},
-                   "4096": {"pallas": 12.0, "dot16": 5.0}}}
-        table = _sanitize_sweep(doc)
-        assert table == {"4096": "dot16"}
-
-    def test_unmeasurable_rival_refuses_bucket(self):
-        """A winner whose RIVAL clamped to 0.0 is also unranked: the
-        rival may be the true winner."""
-        from mmlspark_tpu.ops.histogram import _sanitize_sweep
-        doc = {"winner_by_rows": {"2048": "dot16"},
-               "times_us_by_rows": {
-                   "2048": {"dot16": 22.0, "pallas": 0.0,
-                            "segment": 561.0}}}
-        assert _sanitize_sweep(doc) is None
-
-    def test_hand_built_table_without_times_trusted(self):
-        from mmlspark_tpu.ops.histogram import _sanitize_sweep
-        doc = {"winner_by_rows": {"2048": "dot16"}}
-        assert _sanitize_sweep(doc) == {"2048": "dot16"}
-
-    def test_auto_method_falls_back_to_nearest_resolved(self, monkeypatch):
-        """With the committed table's 2048/4096/8192 buckets refused, a
-        2048-row call site ranks by the nearest RESOLVED bucket (16384 →
-        dot16) instead of trusting noise."""
-        import mmlspark_tpu.ops.histogram as H
-        monkeypatch.setattr(H, "_SWEEP_CACHE", {})
-        monkeypatch.setattr(H.jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(H, "_native_available", lambda: False)
-        assert H._auto_method(2048) == "dot16"
-        assert H._auto_method(16384) == "dot16"
-        # beyond the largest resolved bucket: largest entry's winner
-        assert H._auto_method(10_000_000) == "dot16"

@@ -35,16 +35,16 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_hist_kernel_compiles_at_the_flagship_root(v5e):
-    """The root histogram of the 400 000-row flagship: one grid step
-    needs ~19 MB of scoped VMEM, over Mosaic's 16 MB default — the kernel
-    must ask for its ceiling (it compiled only below 262 144 rows)."""
-    from mmlspark_tpu.ops.pallas_histogram import histogram_pallas
+def test_dot16_kernel_compiles_at_the_flagship_root(v5e):
+    """The root histogram of chip_smoke's flagship, 400 000 x 50 at 256
+    bins: a grid step of 8192 rows holds some 20 MB of operands, over
+    Mosaic's 16 MB default, so the kernel has to ask for its ceiling;
+    the last fold holds 2 features of 8, the last chunk 6784 rows."""
+    from mmlspark_tpu.ops.pallas_histogram import histogram_dot16
     one = SingleDeviceSharding(v5e[0])
     n = 400_000
-    jax.jit(lambda b, g: histogram_pallas(
-        b, g, 256, row_chunk=4096, interpret=False)).lower(
-        _sds((n, 50), jnp.int32, one), _sds((n, 3), jnp.float32, one)
+    jax.jit(lambda b, g: histogram_dot16(b, g, 256, interpret=False)).lower(
+        _sds((50, n), jnp.uint8, one), _sds((n, 3), jnp.float32, one)
     ).compile()
 
 
@@ -60,20 +60,6 @@ def test_ring_kernel_compiles_on_the_2x2(v5e):
         mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
     jax.jit(fn).lower(_sds((d * 50, 256, 3), jnp.float32,
                            NamedSharding(mesh, spec))).compile()
-
-
-def test_fused_gather_is_refused_with_the_recorded_message(v5e):
-    """State (b) in PERF.md: Mosaic has no 1-D dynamic gather, so
-    pallas_fused (and pallas_ring, the same gather) raise on TPU."""
-    from mmlspark_tpu.ops.pallas_histogram import histogram_pallas_fused
-    one = SingleDeviceSharding(v5e[0])
-    with pytest.raises(NotImplementedError,
-                       match="Only 2D gather is supported"):
-        jax.jit(lambda b, g, i: histogram_pallas_fused(
-            b, g, i, 256, 2048, interpret=False)).lower(
-            _sds((56, 4096), jnp.uint8, one),
-            _sds((2048, 3), jnp.float32, one),
-            _sds((2048,), jnp.int32, one))
 
 
 # ------------------------------------- the split loop's carry, in place

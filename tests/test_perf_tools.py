@@ -209,10 +209,29 @@ class TestPerfSentinel:
         return [e for e in telemetry.get_journal().events()
                 if e.get("ev") == "perf_regression"]
 
-    def test_calibrate_then_clean_green(self, tmp_path):
+    def test_calibrate_then_clean_green(self, tmp_path, monkeypatch):
         """Unmodified tree: calibrate a baseline, re-run against it —
-        exit 0, no perf_regression journaled."""
+        exit 0, no perf_regression journaled.  On an injected clock
+        (every reading a tick later, a sleep as long as it was asked):
+        two runs of the same code then take the same time whatever the
+        box is doing, where the wall clock read a codec stage 2.3x its
+        own baseline under six test workers.  The same clock still sees
+        a seeded 2x slowdown, so it has not blinded the gate."""
         sentinel = _load_tool("perf_sentinel")
+
+        class Clock:
+            now = 0.0
+
+            def perf_counter(self):
+                self.now += 1e-2
+                return self.now
+
+            def sleep(self, seconds):
+                self.now += seconds
+
+            def __getattr__(self, name):
+                return getattr(time, name)
+        monkeypatch.setattr(sentinel, "time", Clock())
         base = str(tmp_path / "base.json")
         assert sentinel.main(["--calibrate", "--out", base,
                               *SENTINEL_FAST]) == 0
@@ -223,6 +242,13 @@ class TestPerfSentinel:
         rc = sentinel.main(["--baseline", base, *SENTINEL_FAST])
         assert rc == 0
         assert len(self._regressions_in_journal()) == before
+        monkeypatch.setenv(sentinel.SLOWDOWN_ENV, "codec_json=2.0")
+        # a stage reads the clock three times, so the seeded region is
+        # 5 ticks against 3: --rel as test_seeded_2x_slowdown_fires
+        assert sentinel.main(["--baseline", base, "--rel", "1.4",
+                              *SENTINEL_FAST]) != 0
+        fired = self._regressions_in_journal()[before:]
+        assert [e["stage"] for e in fired] == ["codec_json"]
 
     def test_seeded_2x_slowdown_fires(self, tmp_path, monkeypatch):
         """ISSUE 12 acceptance: a seeded 2x stage slowdown against the

@@ -378,29 +378,3 @@ class TestVendoredParity:
                 y[te], m.getModel().predict(X[te], raw_score=True))
         delta = abs(auc["16"] - auc["off"]) / auc["off"]
         assert delta <= 1e-3, auc
-
-
-# ------------------------------------------------- sweep sanitization
-
-
-class TestSweepQuantizedRows:
-    """Satellite: ``method@dtype`` rows are informational — the auto
-    table must never rank them, and their presence must not poison the
-    f32 rivals' buckets."""
-
-    def test_suffixed_winner_refused(self):
-        doc = {"winner_by_rows": {"4096": "segment@int16"},
-               "times_us_by_rows": {
-                   "4096": {"segment@int16": 5.0, "segment": 9.0,
-                            "dot16": 7.0}}}
-        assert H._sanitize_sweep(doc) is None
-
-    def test_suffixed_rivals_ignored(self):
-        """A clean f32 winner stays ranked even when quantized rows
-        share the bucket (they are not rivals)."""
-        doc = {"winner_by_rows": {"4096": "dot16"},
-               "times_us_by_rows": {
-                   "4096": {"dot16": 5.0, "segment": 9.0,
-                            "segment@int16": 0.0,
-                            "dot16@int32": 2.0}}}
-        assert H._sanitize_sweep(doc) == {"4096": "dot16"}

@@ -686,60 +686,6 @@ class TestAcceptLoopRegistration:
             proc.wait(timeout=15)
 
 
-class TestFusedNoFallback:
-    """histogram_method=pallas_fused is what was asked for or an error:
-    only the row-count gate (a fact about the request) reroutes it."""
-
-    @staticmethod
-    def _fit(method, **kw):
-        rng = np.random.default_rng(11)
-        X = rng.normal(size=(400, 6)).astype(np.float32)
-        y = (X[:, 0] - X[:, 1]).astype(np.float64)
-        return LightGBMRegressor(
-            numIterations=3, numLeaves=7, minDataInLeaf=5, maxBin=15,
-            parallelism="serial", histogramMethod=method, verbosity=0,
-            **kw).fit({"features": X, "label": y}).getModel()
-
-    def test_compile_failure_raises(self, monkeypatch):
-        """A serial pallas_fused fit whose kernel the compiler refuses
-        raises the compiler's message; it does not become 'pallas'.
-        The CPU lowering, told it is a TPU, is the refusing compiler."""
-        import jax
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        with pytest.raises(Exception, match="[Ii]nterpret"):
-            self._fit("pallas_fused", lambdaL2=0.125)
-
-    def test_row_gate_reroutes_to_gather_then_pallas(self, monkeypatch):
-        """Beyond FUSED_MAX_ROWS the (8, n) block cannot stay resident:
-        the segment takes the gather-then-pallas path, bit-comparable
-        by contract, so the forest equals the plain pallas one."""
-        import mmlspark_tpu.ops.pallas_histogram as ph
-        monkeypatch.setattr(ph, "FUSED_MAX_ROWS", 10)
-
-        def boom(*a, **k):
-            raise AssertionError("fused kernel ran past its row gate")
-
-        monkeypatch.setattr(ph, "histogram_pallas_fused", boom)
-        a = self._fit("pallas_fused", lambdaL2=0.25)
-        b = self._fit("pallas", lambdaL2=0.25)
-        for s, t in zip(a.trees, b.trees):
-            np.testing.assert_array_equal(s.split_feature,
-                                          t.split_feature)
-            np.testing.assert_array_equal(np.asarray(s.leaf_value),
-                                          np.asarray(t.leaf_value))
-
-    def test_fused_fit_matches_plain_pallas_fit(self):
-        """In interpret mode the fused gather is bit-comparable with
-        gather-then-pallas, so the two methods grow the same forest."""
-        a = self._fit("pallas_fused", lambdaL2=0.5)
-        b = self._fit("pallas", lambdaL2=0.5)
-        for s, t in zip(a.trees, b.trees):
-            np.testing.assert_array_equal(s.split_feature,
-                                          t.split_feature)
-            np.testing.assert_array_equal(np.asarray(s.leaf_value),
-                                          np.asarray(t.leaf_value))
-
-
 class TestStatsCounters:
     def test_latency_percentiles(self):
         s = LatencyStats(capacity=100)
