@@ -473,7 +473,8 @@ def build_reference_profile(bins: np.ndarray, mapper,
                             = None,
                             max_edges: int = MAX_PROFILE_EDGES,
                             margin_buckets: int = 32,
-                            meta: Optional[Dict[str, Any]] = None
+                            meta: Optional[Dict[str, Any]] = None,
+                            fine_counts: Optional[np.ndarray] = None
                             ) -> ReferenceProfile:
     """Build the fit-time profile from the BINNED training matrix — no
     raw-feature pass needed.
@@ -490,9 +491,21 @@ def build_reference_profile(bins: np.ndarray, mapper,
     ``margins``: the training-set prediction margins (any shape;
     raveled) — the prediction-distribution baseline.  Edges are the
     interior ``margin_buckets``-quantiles of the margins.
+
+    ``fine_counts``: ``(f, mapper.num_total_bins)`` exact integer counts
+    of ``bins``' rows per fine bin, from a caller that has them already
+    (the fit's device-resident table counts them in one pass:
+    ``gbdt/engine.py``); absent, each column is counted here, one
+    strided pass over the table a feature.
     """
     bins = np.asarray(bins)
     n, f = bins.shape
+    if fine_counts is not None:
+        fine_counts = np.asarray(fine_counts, np.int64)
+        if fine_counts.shape != (f, mapper.num_total_bins):
+            raise ValueError(
+                f"fine_counts has shape {fine_counts.shape}; the table "
+                f"has {f} features of {mapper.num_total_bins} bins")
     edges_list: List[np.ndarray] = []
     sketches: List[Dict[str, Any]] = []
     for j in range(f):
@@ -504,9 +517,12 @@ def build_reference_profile(bins: np.ndarray, mapper,
         lo, hi = ((float(edges[0]), float(edges[-1]))
                   if len(edges) else (None, None))
         sk = StreamSketch(edges, lo, hi)
-        col = np.ascontiguousarray(bins[:, j])
-        fine = np.bincount(col, minlength=mapper.num_total_bins
-                           ).astype(np.int64)
+        if fine_counts is not None:
+            fine = fine_counts[j]
+        else:
+            col = np.ascontiguousarray(bins[:, j])
+            fine = np.bincount(col, minlength=mapper.num_total_bins
+                               ).astype(np.int64)
         sk.nan = int(fine[mapper.missing_bin])
         if mapper.is_categorical(j):
             # category identity occupies the fine bins; the coarse

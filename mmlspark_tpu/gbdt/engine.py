@@ -26,6 +26,7 @@ from ..core import debug as _debug
 from ..core import telemetry as _tm
 from ..core.profiler import get_profiler, install_jax_hooks
 from ..core.profiling import StageStats
+from ..ops.histogram import bin_counts
 from .binning import BinMapper, fit_bin_mapper
 from .booster import Booster, HostTree, host_tree_from_arrays
 from .grower import (EFBArrays, GrowerConfig, TreeArrays, apply_shrinkage,
@@ -398,7 +399,8 @@ _CKPT_MESH_STATE = _CKPT_MESH_PREFIX + "{:06d}.npz"
 train_stats = StageStats()
 for _k in ("chunks_replayed", "ckpt_saved", "ckpt_resumed",
            "ckpt_discarded", "boost_chunks", "ref_profiles",
-           "collective_count", "collective_payload_bytes"):
+           "ref_profiles_device", "collective_count",
+           "collective_payload_bytes"):
     train_stats.incr(_k, 0)
 del _k
 # federate under the process registry: a serving process that also
@@ -1574,19 +1576,68 @@ def _bin_space_forest(booster: Booster, mapper: BinMapper) -> Booster:
     return out
 
 
+@functools.partial(jax.jit, static_argnames=("num_bins", "mesh"))
+def _table_bin_counts(bins_d, num_bins: int, mesh=None):
+    """Rows per (feature, fine bin) of the binned table a fit left on
+    the device, ``(f, num_bins)`` int32 and exact
+    (:func:`mmlspark_tpu.ops.histogram.bin_counts`): the reference
+    profile's counts without a pass over the host's table.  On a mesh
+    (``prepare_arrays``' layout) every chip counts its own rows and one
+    ``psum`` of the table adds them; pad rows and pad features are in the
+    counts, for the caller to take out."""
+    if mesh is None:
+        return bin_counts(bins_d, num_bins)
+    from jax.sharding import PartitionSpec as P
+    from ..core.mesh import DATA_AXIS
+    from .distributed import _f_ax
+    return jax.shard_map(
+        lambda b: jax.lax.psum(bin_counts(b, num_bins), DATA_AXIS),
+        mesh=mesh, in_specs=P(DATA_AXIS, _f_ax(mesh)),
+        out_specs=P(_f_ax(mesh), None), check_vma=False)(bins_d)
+
+
+def _representative_table(mapper: BinMapper) -> np.ndarray:
+    """``(f, num_total_bins)`` float32 of :func:`_bin_representatives`,
+    a categorical column's row holding the bin's own index for
+    :func:`_bin_space_forest` (its trailing bin, every other value, stays
+    NaN and goes right)."""
+    table = np.stack(_bin_representatives(mapper))
+    if mapper.has_categorical:
+        cat = np.flatnonzero(mapper.categorical)
+        table[cat] = np.where(np.isnan(table[cat]), np.nan,
+                              np.arange(table.shape[1], dtype=np.float64))
+    return table.astype(np.float32)
+
+
+@jax.jit
+def _representative_rows(sample, table):
+    """``out[r, j] = table[j, sample[r, j]]``: binned rows to their
+    float32 representatives where the forest walk reads them.  A compare
+    and select against each bin, summed (one term is not nought; a NaN
+    is picked, never multiplied): XLA fuses it, and worked feature-major,
+    as the TPU lays both tables out, it needs no temporary."""
+    bin_ids = jnp.arange(table.shape[1], dtype=sample.dtype)
+    hit = sample.T[:, :, None] == bin_ids
+    return jnp.sum(jnp.where(hit, table[:, None, :], 0.0), axis=-1).T
+
+
 def _capture_reference_profile(booster: Booster, bins, mapper,
-                               feature_names) -> None:
+                               feature_names,
+                               device_table: Optional[dict] = None) -> None:
     """Attach the fit-time data-quality baseline (ISSUE 15): per-feature
     sketches over the full binned training matrix plus a
     prediction-margin sketch from a bin-representative predict pass.
     Advisory — a capture failure logs and leaves
     ``booster.reference_profile`` None (drift monitoring off), it never
     fails the fit.  Charged to every fit: the ``train.reference_profile``
-    span, ``rows`` being the rows sketched."""
+    span, ``rows`` being the rows sketched and ``counts`` where they were
+    counted: ``device`` when the fit left ``device_table`` (see
+    :func:`_train_impl`), whose table is counted there and released,
+    ``host`` when ``bins`` is counted column by column."""
     if os.environ.get(REF_PROFILE_ENV, "1") == "0" or mapper is None:
         return
     with get_profiler().region("train.reference_profile",
-                               rows=0) as sp:
+                               rows=0, counts="host") as sp:
         try:
             from ..core.sketch import build_reference_profile
             if isinstance(bins, (list, tuple)):
@@ -1594,31 +1645,45 @@ def _capture_reference_profile(booster: Booster, bins, mapper,
             bins = np.asarray(bins)
             if bins.ndim != 2 or bins.shape[1] != mapper.num_features:
                 return
-            sp["rows"] = int(bins.shape[0])
+            n, f = bins.shape
+            sp["rows"] = int(n)
+            counts_d = None
+            if device_table:
+                counts_d = _table_bin_counts(
+                    device_table.pop("bins"), mapper.num_total_bins,
+                    device_table.get("mesh"))
             sample = bins
-            if sample.shape[0] > _REF_PROFILE_MARGIN_ROWS:
+            if n > _REF_PROFILE_MARGIN_ROWS:
                 idx = np.random.default_rng(0).choice(
-                    sample.shape[0], size=_REF_PROFILE_MARGIN_ROWS,
-                    replace=False)
+                    n, size=_REF_PROFILE_MARGIN_ROWS, replace=False)
                 idx.sort()
-                sample = sample[idx]
-            reps = _bin_representatives(mapper)
-            Xr = np.empty(sample.shape, np.float32)
-            for j, rep in enumerate(reps):
-                if mapper.is_categorical(j):
-                    # its bin, for the bin-space forest; the trailing bin
-                    # (every other value) stays NaN and goes right
-                    rep = np.where(np.isnan(rep), np.nan,
-                                   np.arange(len(rep), dtype=np.float64))
-                Xr[:, j] = rep[sample[:, j].astype(np.int64)]
+                sample = bins[idx]
+            fine_counts = None
+            if counts_d is not None:
+                # the wait is the capture's; with it the fit's table
+                # leaves the device, before the sampled rows arrive
+                fine_counts = np.asarray(counts_d)[:f].astype(np.int64)
+                fine_counts[:, 0] -= device_table["pad_rows"]
+                sp["counts"] = "device"
+            table = _representative_table(mapper)
+            if jax.default_backend() == "cpu":
+                # predict_margin walks numpy rows natively there
+                Xr = table[np.arange(f), sample]
+            else:
+                Xr = _representative_rows(
+                    jnp.asarray(sample, mapper.bin_dtype),
+                    jnp.asarray(table))
             margins = np.asarray(
                 _bin_space_forest(booster, mapper).predict_margin(Xr))
             booster.reference_profile = build_reference_profile(
                 bins, mapper, margins, feature_names=feature_names,
                 meta={"trees": len(booster.trees),
                       "num_class": booster.num_class,
-                      "fit_span": _tm.current_fit_span()})
+                      "fit_span": _tm.current_fit_span()},
+                fine_counts=fine_counts)
             train_stats.incr("ref_profiles")
+            if fine_counts is not None:
+                train_stats.incr("ref_profiles_device")
         except Exception:  # noqa: BLE001 - the profile is advisory
             log.exception("reference-profile capture failed; drift "
                           "monitoring will be unavailable for this model")
@@ -1654,8 +1719,10 @@ def train(*args, **kwargs) -> Booster:
         # phase below is its child, and what no child covers is its
         # self time
         with get_profiler().region("train.fit") as sp:
+            device_table: dict = {}
             try:
-                booster = _train_impl(*args, **kwargs)
+                booster = _train_impl(*args, device_table=device_table,
+                                      **kwargs)
             except BaseException as e:
                 _tm.get_journal().emit("fit_failed", fit=span,
                                        error=type(e).__name__)
@@ -1669,7 +1736,8 @@ def train(*args, **kwargs) -> Booster:
                 raise
             bins, mesh = _arg(0, "bins"), _arg(13, "mesh")
             _capture_reference_profile(booster, bins, _arg(3, "mapper"),
-                                       _arg(6, "feature_names"))
+                                       _arg(6, "feature_names"),
+                                       device_table)
             sp.update(_fit_attrs(booster, bins, mesh, _arg(3, "mapper")))
             _tm.get_journal().emit(
                 "fit_end", fit=span,
@@ -1787,7 +1855,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
           init_scores: Optional[np.ndarray] = None,
           val_init_scores: Optional[np.ndarray] = None,
           ranking_info: Optional[Dict] = None,
-          shard_rows: Optional[List[int]] = None) -> Booster:
+          shard_rows: Optional[List[int]] = None,
+          device_table: Optional[dict] = None) -> Booster:
     """Train a forest.  ``bins``: (n, f) int32 pre-binned features.
 
     ``val_init_scores``: per-row margin offsets for the validation set —
@@ -1814,6 +1883,12 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
     supports validation/early stopping, per-machine bagging, callbacks,
     init scores, goss, rf, dart and lambdarank — for ranking each
     query's rows must live on one shard).
+
+    ``device_table``: out, for :func:`train`'s reference profile.  A fit
+    that uploaded ``bins`` as it is (one host table, not bundled: here,
+    and ``_train_distributed`` through ``prepare_arrays``) leaves the
+    device array under ``bins``, its ``pad_rows`` (bin 0 of every
+    feature) and, on a mesh, the ``mesh``; any other fit leaves it empty.
     """
     if isinstance(bins, (list, tuple)):
         return _train_distributed_sharded(
@@ -1974,7 +2049,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             feature_names, init, rng, bag_rng, init_scores,
             val_bins=val_bins, val_labels=val_labels,
             val_weights=val_weights, val_metric=val_metric,
-            callbacks=callbacks, val_init_scores=val_init_scores)
+            callbacks=callbacks, val_init_scores=val_init_scores,
+            device_table=device_table)
 
     # Exclusive Feature Bundling (serial paths; uint8 bins only — a
     # bundle's encoded width is capped at num_total_bins).  goss/dart
@@ -2426,6 +2502,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         if ckpt:
             _ckpt_clear(ckpt)
 
+    if device_table is not None and efb_dev is None:
+        device_table.update(bins=bins_d, pad_rows=0)
     return _export_booster(trees_chunks, K, stop_iter, init, params,
                            objective, mapper, feature_names, f,
                            dart_scales=scales if use_dart else None,
@@ -3086,7 +3164,8 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
                        init_scores=None, val_bins=None, val_labels=None,
                        val_weights=None, val_metric=None,
                        callbacks=None, shard_data=None,
-                       val_init_scores=None) -> Booster:
+                       val_init_scores=None,
+                       device_table: Optional[dict] = None) -> Booster:
     """Distributed boosting: the whole iteration loop is ONE shard_mapped
     ``lax.scan`` launch (no per-iteration host round-trips); with a
     validation set the loop chunks and the host replays per-iteration
@@ -3515,5 +3594,8 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
         if jax.process_index() == 0:
             _ckpt_clear(ckpt)
 
+    if device_table is not None and efb_dev_m is None \
+            and shard_data is None and bins_d.is_fully_addressable:
+        device_table.update(bins=bins_d, pad_rows=rp, mesh=mesh)
     return _export_booster(chunks, K, stop_iter, init, params, objective,
                            mapper, feature_names, f, rf=use_rf_m)

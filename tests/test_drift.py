@@ -282,6 +282,109 @@ class TestReferenceProfile:
         assert b.reference_profile is None
 
 
+# --------------------------------- device counts (ISSUE 30; gbdt/engine.py)
+
+
+def _profile_doc(profile):
+    doc = json.loads(profile.to_json())
+    for key in ("created", "fit_span"):
+        doc["meta"].pop(key)
+    return doc
+
+
+def _count_case(case):
+    """``(X, categorical columns, max_bin, mesh)`` of one case."""
+    rng = np.random.default_rng(30)
+    n = 40003 if case == "mesh4_pad_rows" else 36000
+    X = rng.normal(size=(n, 7)).astype(np.float32)
+    cats, max_bin, mesh = [], 63, None
+    if case == "missing":
+        X[rng.random(X.shape) < 0.07] = np.nan
+    elif case == "categorical":
+        cats = [1, 4]
+        X[:, 1] = rng.integers(0, 40, n)
+        X[:, 4] = rng.integers(0, 3, n) * 7
+    elif case == "wide_bins":
+        max_bin = 300
+    elif case == "mesh4_pad_rows":
+        import jax
+        from jax.sharding import Mesh
+        from mmlspark_tpu.core.mesh import DATA_AXIS, FEATURE_AXIS
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(4, 1),
+                    (DATA_AXIS, FEATURE_AXIS))
+    return X, cats, max_bin, mesh
+
+
+@pytest.mark.parametrize("case", ["numeric", "missing", "categorical",
+                                  "wide_bins", "mesh4_pad_rows"])
+def test_profile_from_device_counts_equals_the_host_tables(case):
+    """The profile a fit leaves, its rows counted on the device from the
+    table the fit uploaded, is the profile the host's column passes give:
+    the same JSON but for the clock and the fit's id.  Over 32 768 rows,
+    so the margins come from the sampled rows; on the mesh the rows are
+    no multiple of 4 and the pad rows must not be counted."""
+    from mmlspark_tpu.core.profiler import get_profiler
+    from mmlspark_tpu.gbdt import engine
+    from mmlspark_tpu.gbdt.objectives import get_objective
+    X, cats, max_bin, mesh = _count_case(case)
+    # column 1's effect has no order: a categorical split's to find
+    y = (X[:, 0] + np.nan_to_num(X[:, 2]) + 2 * (X[:, 1] % 3 == 0)
+         > 1).astype(np.float32)
+    mapper = fit_bin_mapper(X, max_bin=max_bin, seed=0,
+                            categorical_features=cats)
+    bins = mapper.transform_packed(X)
+    assert bins.dtype == (np.int32 if case == "wide_bins" else np.uint8)
+    names = [f"c{j}" for j in range(X.shape[1])]
+    booster = engine.train(
+        bins, y, None, mapper, get_objective("binary"),
+        engine.TrainParams(num_iterations=3, num_leaves=7), names,
+        mesh=mesh)
+    span = [s for s in get_profiler().spans()
+            if s["name"] == "train.reference_profile"][-1]
+    assert span["attrs"] == {"rows": len(X), "counts": "device"}
+    from_device = booster.reference_profile
+    engine._capture_reference_profile(booster, bins, mapper, names)
+    assert booster.reference_profile is not from_device
+    assert _profile_doc(from_device) == _profile_doc(
+        booster.reference_profile)
+    assert from_device.meta["n_rows"] == len(X)
+    if case == "categorical":
+        assert any(t.num_cat for t in booster.trees)
+
+
+def test_device_counts_are_exact_past_2p24_rows_a_bin():
+    """A float32 sum stops counting at 2^24; the pass adds row chunks as
+    int32 (ops/histogram.bin_counts)."""
+    import jax.numpy as jnp
+    from mmlspark_tpu.gbdt import engine
+    n = (1 << 24) + 4099
+    bins = np.full((n, 1), 3, np.uint8)
+    bins[::4096] = 5
+    counts = np.asarray(engine._table_bin_counts(jnp.asarray(bins), 8))
+    assert counts.dtype == np.int32 and counts.shape == (1, 8)
+    assert counts[0].tolist() == np.bincount(bins[:, 0],
+                                             minlength=8).tolist()
+    assert counts[0, 3] > 1 << 24
+
+
+@pytest.mark.parametrize("dtype,num_bins", [(np.uint8, 256),
+                                            (np.int32, 300)])
+def test_representative_rows_is_the_tables_lookup(dtype, num_bins):
+    """What an accelerator backend runs in place of the host's fancy
+    index (the CPU backend keeps numpy rows for ``predict_margin``'s
+    native walk): the same float32, NaN where the table has one."""
+    from mmlspark_tpu.gbdt import engine
+    rng = np.random.default_rng(2)
+    table = rng.normal(size=(5, num_bins)).astype(np.float32)
+    table[:, -1] = np.nan
+    table[2, 7] = np.nan
+    sample = rng.integers(0, num_bins, (700, 5)).astype(dtype)
+    sample[:9, 2] = 7
+    out = np.asarray(engine._representative_rows(sample, table))
+    assert out.dtype == np.float32
+    assert np.array_equal(out, table[np.arange(5), sample], equal_nan=True)
+
+
 # ------------------------------------------------- registry persistence
 
 

@@ -272,6 +272,72 @@ def test_fit_emits_every_span_once_inside_its_root(fit_inputs, devices):
         assert a["collective_bytes"] >= 6 * 30 * f * 256 * 3 * 4
 
 
+def _one_hot_table(n=4000, groups=3, width=6, seed=1):
+    """Mutually exclusive columns, which EFB bundles, and one dense."""
+    rng = np.random.default_rng(seed)
+    X = np.zeros((n, groups * width + 1), np.float32)
+    for g in range(groups):
+        X[np.arange(n), g * width + rng.integers(0, width, n)] = 1.0
+    X[:, -1] = rng.normal(size=n)
+    return X, (X[:, 0] + X[:, -1] > 0.5).astype(np.float32)
+
+
+@pytest.mark.parametrize("case,counts", [
+    ("serial", "device"), ("mesh4", "device"), ("bundled", "host"),
+    ("incremental", "host")])
+def test_reference_profile_says_where_its_rows_were_counted(
+        fit_inputs, case, counts):
+    """``train.reference_profile``'s ``counts``: ``device`` where the fit
+    left the caller's table on the device as it was, ``host`` where the
+    device holds bundles and for a capture that is no fit's own (the
+    merged forest's, after ``train_incremental``'s fit)."""
+    inp = fit_inputs
+    stats0 = engine.train_stats.snapshot()["counters"]
+    if case == "bundled":
+        X, y = _one_hot_table()
+        est = LightGBMClassifier(numIterations=3, numLeaves=7,
+                                 verbosity=0, enableBundle=True)
+        mapper = fit_bin_mapper(X, max_bin=est.getMaxBin(),
+                                seed=est.getSeed())
+        inp = dict(fit_inputs, bins=mapper.transform_packed(X),
+                   labels=est._prepare_labels(y), mapper=mapper,
+                   params=est._train_params())
+    prof = get_profiler()
+    before = {s["id"] for s in prof.spans()}
+    if case == "incremental":
+        base = engine.train(inp["bins"], inp["labels"], None, inp["mapper"],
+                            inp["objective"], inp["params"])
+        before = {s["id"] for s in prof.spans()}
+        booster = engine.train_incremental(
+            inp["bins"], inp["labels"], inp["mapper"], init_booster=base,
+            objective=inp["objective"], params=inp["params"])
+    else:
+        booster = engine.train(
+            inp["bins"], inp["labels"], None, inp["mapper"],
+            inp["objective"], inp["params"],
+            mesh=_mesh4() if case == "mesh4" else None)
+    new = [s for s in prof.spans() if s["id"] not in before]
+    if case == "bundled":
+        # fewer bytes than the table has cells: the bundles went up
+        upload, = [s for s in new if s["name"] == "train.upload"]
+        assert upload["attrs"]["bytes"] < inp["bins"].size
+    spans = [s for s in new if s["name"] == "train.reference_profile"]
+    # the incremental fit's own capture, then the merged forest's
+    assert [s["attrs"]["counts"] for s in spans] == \
+        (["device", "host"] if case == "incremental" else [counts])
+    assert all(s["attrs"]["rows"] == inp["bins"].shape[0] for s in spans)
+    assert booster.reference_profile is not None
+    stats = engine.train_stats.snapshot()["counters"]
+    done = {k: stats[k] - stats0[k]
+            for k in ("ref_profiles", "ref_profiles_device")}
+    assert done == {
+        "serial": {"ref_profiles": 1, "ref_profiles_device": 1},
+        "mesh4": {"ref_profiles": 1, "ref_profiles_device": 1},
+        "bundled": {"ref_profiles": 1, "ref_profiles_device": 0},
+        "incremental": {"ref_profiles": 3, "ref_profiles_device": 2},
+    }[case]
+
+
 @pytest.mark.parametrize("method,build", [("auto", "native"),
                                           ("dot16", "dot16/xla")])
 def test_fit_names_the_histogram_build_it_compiled(fit_inputs, method,

@@ -183,3 +183,48 @@ def test_boost_scan_at_epsilons_shape_holds_no_one_hot(
     assert compiled.as_text().count("tpu_custom_call") >= 10
     assert _wider_than_the_bins(compiled, 2000) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 7.5e9
+
+
+# ------------------- the reference profile's passes over the fit's table
+
+
+@pytest.mark.parametrize("n,f,chips", [(400_000, 2000, 1),
+                                       (30_000_000, 39, 1),
+                                       (400_000, 2000, 4)])
+def test_count_pass_holds_nothing_wider_than_the_table(
+        v5e, decides_as_on_the_tpu, n, f, chips):
+    """``engine._table_bin_counts`` at the wide cell's shape, at the click
+    log's (four row chunks: a bin may pass 2^24 rows) and row-sharded on
+    the 2x2: the histogram kernel on the uint8 bins, no ``(rows, F,
+    bins)`` array, temporaries under 0.5 GB (the scan's are 6.1), and on
+    the mesh one all-reduce, of the counts."""
+    from mmlspark_tpu.core.mesh import FEATURE_AXIS
+    from mmlspark_tpu.gbdt.engine import _table_bin_counts
+    mesh, sharding = None, SingleDeviceSharding(v5e[0])
+    if chips > 1:
+        mesh = Mesh(np.asarray(v5e).reshape(chips, 1),
+                    (DATA_AXIS, FEATURE_AXIS))
+        sharding = NamedSharding(mesh, P(DATA_AXIS, FEATURE_AXIS))
+    compiled = _table_bin_counts.lower(
+        _sds((n, f), jnp.uint8, sharding), 256, mesh).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _wider_than_the_bins(compiled, f) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    from mmlspark_tpu.core.profiling import compiled_instructions
+    reduced = compiled_instructions(compiled, opcodes=("all-reduce",))
+    assert [shape for _, shape, _ in reduced] == \
+        ([(f, 256)] if chips > 1 else [])
+
+
+def test_representative_rows_needs_no_temporary(v5e):
+    """The sampled rows' lookup at the wide cell's shape: compare, select
+    and sum fused, worked in the layout the TPU keeps both tables in, so
+    nothing but the ``(32768, 2000)`` float32 result is made (row-major
+    it took a transposed copy of the result, 268 MB)."""
+    from mmlspark_tpu.gbdt.engine import _representative_rows
+    one = SingleDeviceSharding(v5e[0])
+    compiled = _representative_rows.lower(
+        _sds((32768, 2000), jnp.uint8, one),
+        _sds((2000, 256), jnp.float32, one)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
