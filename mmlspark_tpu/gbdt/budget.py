@@ -31,6 +31,12 @@ import numpy as np
 
 #: rows the dot16 histogram build takes at a time (ops/histogram.py)
 HIST_CHUNK_ROWS = 8192
+#: a chunk of a ranker's pair pass: ``ranking``'s default
+#: ``query_chunk_pairs`` cells a tensor, and the (queries, G, G) tensors
+#: XLA keeps at once (pair mask, the two deltas, score difference,
+#: sigmoid, lambda, hessian, and the reductions' operands)
+RANK_CHUNK_PAIRS = 4_000_000
+RANK_PAIR_TENSORS = 12
 
 
 def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
@@ -38,14 +44,16 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
                        chunk: int = 64, bin_itemsize: int = 1,
                        bagging: bool = False, n_val_local: int = 0,
                        min_bucket: int = 2048,
-                       hist_on_chip: bool = False) -> Dict[str, int]:
+                       hist_on_chip: bool = False,
+                       rank_layout_bytes: int = 0) -> Dict[str, int]:
     """Per-device resident-bytes breakdown for one training fit.
 
     ``n_local``: this device's row count (global rows / data-mesh size).
     ``hist_on_chip``: the fit's histogram call sites all compile the
     build that keeps its one-hots in VMEM (``grower.
-    hist_build_schedule``).  Returns a dict of named costs plus
-    ``"total"``.
+    hist_build_schedule``).  ``rank_layout_bytes``: a ranker's query
+    layout as uploaded (``ranking.QueryLayout``; 0 for any other fit).
+    Returns a dict of named costs plus ``"total"``.
     """
     n, f, B, L, K, C = (n_local, num_features, num_bins, num_leaves,
                         num_class, chunk)
@@ -81,6 +89,12 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
     if n_val_local:
         costs["validation"] = n_val_local * (f * bin_itemsize
                                              + 4 * K * (C + 1))
+    if rank_layout_bytes:
+        # the layout, as much again for what the pair pass keeps a slot
+        # (gathered scores, each slot's gradient and hessian, their
+        # concatenations), and a chunk's (queries, G, G) pair tensors
+        costs["rank_layout"] = (2 * rank_layout_bytes
+                                + RANK_PAIR_TENSORS * 4 * RANK_CHUNK_PAIRS)
     costs["total"] = sum(costs.values())
     return costs
 
@@ -110,7 +124,8 @@ def check_fit_budget(n_local: int, num_features: int, num_bins: int,
                      bin_itemsize: int = 1, bagging: bool = False,
                      n_val_local: int = 0, data_shards: int = 1,
                      verbosity: int = 1,
-                     hist_on_chip: bool = False) -> Dict[str, int]:
+                     hist_on_chip: bool = False,
+                     rank_layout_bytes: int = 0) -> Dict[str, int]:
     """Estimate, log, and fail FAST when the fit cannot fit.
 
     Raises ``MemoryError`` with the breakdown and concrete remediations
@@ -119,7 +134,8 @@ def check_fit_budget(n_local: int, num_features: int, num_bins: int,
     """
     costs = estimate_fit_bytes(
         n_local, num_features, num_bins, num_leaves, num_class, chunk,
-        bin_itemsize, bagging, n_val_local, hist_on_chip=hist_on_chip)
+        bin_itemsize, bagging, n_val_local, hist_on_chip=hist_on_chip,
+        rank_layout_bytes=rank_layout_bytes)
     cap = device_capacity_bytes()
     if verbosity > 0:
         import logging

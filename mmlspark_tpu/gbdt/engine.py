@@ -30,7 +30,7 @@ from ..ops.histogram import bin_counts
 from .binning import BinMapper, fit_bin_mapper
 from .booster import Booster, HostTree, host_tree_from_arrays
 from .grower import (EFBArrays, GrowerConfig, TreeArrays, apply_shrinkage,
-                     collective_schedule, grow_tree, hist_build_schedule,
+                     collective_schedule, hist_build_schedule,
                      predict_tree_binned, predict_tree_binned_any,
                      predict_tree_binned_efb, _grow_tree_impl)
 from .objectives import Objective, MulticlassObjective
@@ -1757,7 +1757,10 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
     table with categorical columns also says how many they are, how many
     of its trees' internal nodes are categorical splits, and the u32
     words of their raw-value bitsets; a numeric fit carries none of the
-    three."""
+    three.  A fit whose gradient was a ranker's query layout
+    (``ranking.LambdarankGrad``) says how many queries and size classes
+    it held and, times the trees, the pairs of the queries' exact sizes
+    and the pair slots its programs computed."""
     shards = bins if isinstance(bins, (list, tuple)) else [bins]
     shapes = [np.shape(b) for b in shards if b is not None]
     trees = len(booster.trees)
@@ -1775,6 +1778,12 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
         "hist_build": last_fit_info.get("hist_build", ""),
         "hist_build_rungs": last_fit_info.get("hist_build_rungs", ""),
     }
+    if "rank_queries" in last_fit_info:
+        attrs.update(
+            rank_queries=int(last_fit_info["rank_queries"]),
+            rank_size_classes=int(last_fit_info["rank_size_classes"]),
+            rank_pairs_useful=per_tree("rank_pairs_useful_per_tree"),
+            rank_pairs_computed=per_tree("rank_pairs_computed_per_tree"))
     if mapper is not None and mapper.has_categorical:
         attrs.update(
             cat_features=int(mapper.categorical.sum()),
@@ -1863,9 +1872,11 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
     the continued-training (init_model) companion of ``init_scores``, so
     early stopping evaluates the merged model's trajectory.
 
-    ``grad_fn_override``: optional ``(scores) -> (g, h)`` replacing the
-    objective's grad/hess (used by the ranking objective which closes over
-    query structure).
+    ``grad_fn_override``: a ranker's gradient, ``ranking.LambdarankGrad``
+    (``ranking.make_lambdarank_grad_fn``): its query layout is uploaded
+    (``train.rank_pack``) and handed to the ordinary programs (scan, goss,
+    dart) as their ``labels``, with lambdarank as their objective.  A
+    bare closure is refused: the compiled programs cannot take one.
 
     ``callbacks``: each called as ``cb(it, trees_dev)`` with the list of
     on-device ``TreeArrays`` grown so far (fixed-size, shrinkage applied);
@@ -1898,6 +1909,14 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             callbacks=callbacks,
             grad_fn_override=grad_fn_override, init_scores=init_scores,
             ranking_info=ranking_info, shard_rows=shard_rows)
+    from .ranking import LambdarankGrad
+    if grad_fn_override is not None \
+            and not isinstance(grad_fn_override, LambdarankGrad):
+        raise TypeError(
+            "grad_fn_override takes a ranker's gradient "
+            "(ranking.make_lambdarank_grad_fn), whose query layout the "
+            "compiled programs take as an argument; got "
+            f"{type(grad_fn_override).__name__}")
     n, f = bins.shape
     K = objective.num_model_per_iteration
     rng = np.random.default_rng(params.seed)
@@ -2011,7 +2030,9 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         n_val_local=(-(-val_bins.shape[0] // _dn)
                      if val_bins is not None else 0),
         data_shards=_dn, verbosity=params.verbosity,
-        hist_on_chip=hist_sched["fused"] == hist_sched["sites"])
+        hist_on_chip=hist_sched["fused"] == hist_sched["sites"],
+        rank_layout_bytes=(grad_fn_override.nbytes
+                           if grad_fn_override is not None else 0))
     if use_mesh:
         if ranking_info is not None:
             if init_scores is not None:
@@ -2056,7 +2077,7 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
     # bundle's encoded width is capped at num_total_bins).  goss/dart
     # score the bundled TRAINING matrix through the EFB-aware walk
     # (predict_tree_binned_efb decodes each level's bundle column back
-    # to the node's original feature); the ranking host loop
+    # to the node's original feature); a ranker's fit
     # (grad_fn_override) stays unbundled.
     efb_dev = None
     bins_host_final = bins
@@ -2078,6 +2099,23 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         scores = jnp.asarray(scores0)
         sp["bytes"] = int(bins_d.nbytes + labels_d.nbytes
                           + weights_d.nbytes + scores.nbytes)
+
+    # A ranker's gradient rides the ordinary programs: its query layout
+    # goes up as their ``labels``, its row multipliers as their
+    # ``weights``, and lambdarank is their objective.
+    rank = grad_fn_override
+    step_obj, step_labels, step_weights = objective, labels_d, weights_d
+    if rank is not None:
+        with get_profiler().region("train.rank_pack") as sp:
+            step_obj = rank.objective
+            step_labels, step_weights = rank.upload()
+            sp["bytes"] = rank.nbytes
+        lay = rank.layout
+        last_fit_info.update(
+            rank_queries=str(lay.queries),
+            rank_size_classes=str(len(lay.classes)),
+            rank_pairs_useful_per_tree=str(lay.pairs_useful),
+            rank_pairs_computed_per_tree=str(lay.pairs_computed))
 
     has_val = val_bins is not None and val_metric is not None
     if has_val:
@@ -2138,9 +2176,10 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         }
     ckpt = params.checkpoint_dir
     if ckpt and (use_dart or grad_fn_override is not None):
-        log.warning("checkpoint_dir is inert for dart/custom-gradient "
-                    "host loops (per-iteration host bookkeeping; no "
-                    "chunk boundaries to snapshot)")
+        log.warning("checkpoint_dir is inert for the dart host loop "
+                    "(per-iteration host bookkeeping; no chunk "
+                    "boundaries to snapshot) and for a ranker (its query "
+                    "layout is not in the resume fingerprint)")
         ckpt = ""
     if ckpt:
         # bounded chunks = bounded lost work after a process death
@@ -2151,82 +2190,7 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
     trees_chunks: List[TreeArrays] = []
     stop_iter = T
 
-    if grad_fn_override is not None and not use_dart:
-        # Per-iteration host loop: the ranking gradient closes over query
-        # structure on the host (not a hashable static), so it can't ride
-        # the scan.  Trees still cross to the host as one packed chunk.
-        # goss samples inside the loop (Σ|g·h| ranking per iteration); rf
-        # fits every tree at the constant init scores, unshrunk.
-        run_grow = _debug.checked(functools.partial(grow_tree, cfg=cfg))
-        binsT_d = jnp.transpose(bins_d)   # fit-invariant, once per fit
-        trees_list: List[TreeArrays] = []
-        for it in range(T):
-            t_iter = time.perf_counter()
-            if use_bag and it % params.bagging_freq == 0:
-                cur_bag = (bag_rng.random(n) < params.bagging_fraction
-                           ).astype(np.float32)
-            bag_mask = jnp.asarray(cur_bag)
-            fi = jnp.asarray(iter_fi(it))
-            g, h = grad_fn_override(scores)
-            if use_goss:
-                infl = jnp.abs(g * h)
-                rank = jnp.argsort(-infl)
-                top_idx = rank[:k1]
-                rk = jax.random.uniform(goss_keys[it], (n - k1,))
-                other_idx = jnp.take(rank[k1:], jnp.argsort(rk)[:k2])
-                idx = jnp.concatenate([top_idx, other_idx])
-                amp_vec = jnp.concatenate([
-                    jnp.ones(k1, jnp.float32),
-                    jnp.full(k2, goss_amp, jnp.float32)])
-                gh = jnp.stack([jnp.take(g, idx) * amp_vec,
-                                jnp.take(h, idx) * amp_vec,
-                                jnp.ones(k1 + k2, jnp.float32)], axis=1)
-                tree, _ = run_grow(jnp.take(bins_d, idx, axis=0), gh, fi)
-                scores = scores + params.learning_rate * \
-                    predict_tree_binned(tree, bins_d, params.num_leaves)
-                tree = apply_shrinkage(tree, params.learning_rate)
-                trees_list.append(tree)
-            else:
-                gh = jnp.stack([g * bag_mask, h * bag_mask, bag_mask],
-                               axis=1)
-                tree, row_leaf = run_grow(bins_d, gh, fi, binsT=binsT_d)
-                if not use_rf:
-                    scores = scores + params.learning_rate * \
-                        tree.leaf_value[row_leaf]
-                    tree = apply_shrinkage(tree, params.learning_rate)
-                trees_list.append(tree)
-            # per-iteration telemetry (custom-gradient host loop):
-            # objective=None — the override replaces the objective's
-            # gradient, so its train_loss would not describe this fit
-            get_profiler().record_phase(
-                "train.host_iter", time.perf_counter() - t_iter)
-            _monitor_chunk(it, it + 1, time.perf_counter() - t_iter,
-                           n, K, cfg.hist_method, coll_sched=coll_sched)
-            if has_val:
-                # trees are already shrunk, so val scores add at lr=1.0
-                val_scores = val_scores + predict_tree_binned(
-                    tree, val_bins_d, params.num_leaves)
-                margins = (_rf_margins(init, np.asarray(val_scores), it)
-                           if use_rf else np.asarray(val_scores))
-                metric = float(val_metric(margins, val_labels_np,
-                                          val_weights))
-                if metric < best_metric - 1e-12:
-                    best_metric, best_iter = metric, it
-                elif esr > 0 and it - best_iter >= esr:
-                    if params.verbosity > 0:
-                        log.info("Early stopping at iteration %d "
-                                 "(best %d, metric %.6f)", it, best_iter,
-                                 best_metric)
-                    stop_iter = best_iter + 1
-                    trees_list = trees_list[:stop_iter]
-                    break
-            if callbacks:
-                for cb in callbacks:
-                    cb(it, trees_list)
-        if trees_list:
-            trees_chunks = [jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *trees_list)]
-    elif use_dart:
+    if use_dart:
         # Dart (Rashmi & Gilad-Bachrach 2015; LightGBM boosting=dart):
         # each iteration drops a random subset of the ensemble, fits the
         # new tree against the dropped-out scores, then renormalizes —
@@ -2235,10 +2199,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         # weights are tracked on host and baked into the exported trees.
         dart_rng = np.random.default_rng(params.drop_seed)
         run_dart = _debug.checked(functools.partial(
-            _dart_step, obj=objective, cfg=cfg, lr=params.learning_rate,
+            _dart_step, obj=step_obj, cfg=cfg, lr=params.learning_rate,
             K=K, efb=efb_dev))
-        run_grow_dart = _debug.checked(functools.partial(grow_tree,
-                                                         cfg=cfg))
         binsT_d = jnp.transpose(bins_d)   # fit-invariant, once per fit
         L_steps = params.num_leaves
 
@@ -2267,18 +2229,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             return jnp.asarray(iter_fi(it))
 
         def grow_unit(s_minus, bag_mask, fi):
-            if grad_fn_override is not None:
-                # ranking dart (single-model): gradients at the dropped-
-                # out scores through the query-structured closure
-                g, h = grad_fn_override(s_minus)
-                gh = jnp.stack([g * bag_mask, h * bag_mask, bag_mask],
-                               axis=1)
-                unit, row_leaf = run_grow_dart(bins_d, gh, fi,
-                                               binsT=binsT_d)
-                unit = apply_shrinkage(unit, params.learning_rate)
-                return unit, unit.leaf_value[row_leaf]
-            return run_dart(bins_d, binsT_d, s_minus, labels_d,
-                            weights_d, bag_mask, fi)
+            return run_dart(bins_d, binsT_d, s_minus, step_labels,
+                            step_weights, bag_mask, fi)
 
         val_state = {"scores": val_scores if has_val else None,
                      "best": (np.inf, -1)}
@@ -2318,11 +2270,11 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         # Static args bind via partial so checkify only sees array args.
         with get_profiler().region("train.build_step"):
             run_scan = _debug.checked(functools.partial(
-                _boost_scan, obj=objective, cfg=cfg, lr=params.learning_rate,
+                _boost_scan, obj=step_obj, cfg=cfg, lr=params.learning_rate,
                 has_val=has_val, rf=use_rf, efb=efb_dev))
             if use_goss:
                 run_goss = _debug.checked(functools.partial(
-                    _boost_scan_goss, obj=objective, cfg=cfg,
+                    _boost_scan_goss, obj=step_obj, cfg=cfg,
                     lr=params.learning_rate, k1=k1, k2=k2, amp=goss_amp,
                     has_val=has_val, K=K, efb=efb_dev))
             if K > 1:
@@ -2380,15 +2332,15 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             def run_chunk(scores, val_scores):
                 if use_goss:
                     return run_goss(
-                        bins_d, scores, labels_d, weights_d,
+                        bins_d, scores, step_labels, step_weights,
                         goss_keys[it:it + C], fi_stack, val_bins_d,
                         val_scores)
                 if K > 1:
                     return run_multi(
-                        bins_d, scores, labels_d, weights_d, bag_masks,
-                        fi_stack, val_bins_d, val_scores)
+                        bins_d, scores, step_labels, step_weights,
+                        bag_masks, fi_stack, val_bins_d, val_scores)
                 return run_scan(
-                    bins_d, scores, labels_d, weights_d, bag_masks,
+                    bins_d, scores, step_labels, step_weights, bag_masks,
                     fi_stack, val_bins_d, val_scores)
 
             t_chunk = time.perf_counter()
@@ -2424,7 +2376,7 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
                             # the chunk runners that captured them
                             efb_dev = _efb_dev_from_host(efb_host)
                             run_scan = _debug.checked(functools.partial(
-                                _boost_scan, obj=objective, cfg=cfg,
+                                _boost_scan, obj=step_obj, cfg=cfg,
                                 lr=params.learning_rate, has_val=has_val,
                                 rf=use_rf, efb=efb_dev))
                             if K > 1:
@@ -2443,10 +2395,14 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
                             it, attempt + 1, ftr)
                         bins_d = jnp.asarray(ft_host["bins"],
                                              mapper.bin_dtype)
-                        labels_d = jnp.asarray(
-                            ft_host["labels"],
-                            jnp.int32 if K > 1 else jnp.float32)
-                        weights_d = jnp.asarray(ft_host["w"], jnp.float32)
+                        if rank is not None:
+                            step_labels, step_weights = rank.upload()
+                        else:
+                            step_labels = jnp.asarray(
+                                ft_host["labels"],
+                                jnp.int32 if K > 1 else jnp.float32)
+                            step_weights = jnp.asarray(ft_host["w"],
+                                                       jnp.float32)
                         val_bins_d = jnp.asarray(ft_host["val_bins"],
                                                  mapper.bin_dtype)
                         bag_masks = jnp.asarray(bagm_host)

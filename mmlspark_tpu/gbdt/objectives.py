@@ -416,7 +416,7 @@ class MulticlassObjective(Objective):
 
 class _LambdarankStub(Objective):
     """Metadata-only objective: the ranker supplies grad/hess via its
-    query-structured override (gbdt/ranking.py); init score is 0."""
+    query layout (gbdt/ranking.LambdarankObjective); init score is 0."""
 
     name = "lambdarank"
     model_str = "lambdarank"

@@ -4,11 +4,22 @@ TPU-native re-implementation of the reference's ranker
 (lightgbm/LightGBMRanker.scala, expected path, UNVERIFIED; SURVEY.md §2.1)
 whose native engine computes pairwise ΔNDCG-weighted gradients per query.
 
-Static-shape design (SURVEY.md §7 hard part 6): rows are sorted by query on
-the host and packed into a padded ``(num_queries, max_group)`` index matrix;
-the jitted gradient function scans over query *chunks*, computing the full
-``(chunk, G, G)`` pairwise lambda tensor per chunk — bucketed padding instead
-of LightGBM's per-query loops.  Semantics follow lambdarank:
+Static-shape design (SURVEY.md §7 hard part 6): queries are grouped on the
+host into SIZE CLASSES, powers of two from 8 up to the longest query
+(:func:`pack_queries_by_size`); a class of length ``G`` is one
+``(queries, G)`` block of row indices, cut into chunks of queries, and the
+gradient program scans over a class's chunks computing the full
+``(chunk, G, G)`` pairwise lambda tensor per chunk.  A query of more than
+four documents is padded to under twice its length, so its pairs computed
+are under four times its pairs; a shorter one costs the 64 slots of the
+smallest class.  The trainer on one device takes the layout as the
+``labels`` of :class:`LambdarankObjective` on the ordinary boost scan
+(``engine._boost_scan``): the query tensors are arguments of a program
+built once, not constants of a closure.  The mesh trainer
+(``distributed.make_ranking_scan``) keeps the older layout, every query
+padded to the longest one on its shard (:func:`shard_queries`); both call
+the same pair mathematics (:func:`_pair_lambdas`).  Semantics follow
+lambdarank:
 
 * gains ``2^label - 1``, discounts ``1/log2(2 + rank)`` with ranks from the
   *current* scores, ΔNDCG normalized by the query's ideal DCG;
@@ -19,7 +30,8 @@ of LightGBM's per-query loops.  Semantics follow lambdarank:
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,27 +41,106 @@ from ..core.params import Param, TypeConverters
 from ..core.schema import DataTable, features_matrix
 from .base import LightGBMBase, LightGBMModelBase
 from .booster import Booster
+from .objectives import Objective
+
+#: the shortest size class: a query of up to 8 documents is padded to 8
+MIN_SIZE_CLASS = 8
+#: a chunk's pair tensors are laid out with the query length minor; the
+#: TPU pads that dimension to its 128 lanes, so a chunk is sized by it
+_LANES = 128
+
+
+def _query_runs(query_ids: np.ndarray):
+    """``(order, starts, counts)``: the stable sort of the rows by query
+    and each query's run in it."""
+    order = np.argsort(query_ids, kind="stable")
+    _, starts, counts = np.unique(query_ids[order], return_index=True,
+                                  return_counts=True)
+    return order, starts, counts
+
+
+def _padded_runs(starts: np.ndarray, counts: np.ndarray, G: int):
+    """``(idx, real)``, both (queries, G): positions ``starts + 0..G-1``
+    of each run (0 where padded) and which of them are the run's own."""
+    pos = np.arange(G)
+    real = pos[None, :] < counts[:, None]
+    return np.where(real, starts[:, None] + pos[None, :], 0), real
 
 
 def pack_queries(query_ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray,
                                                  np.ndarray]:
-    """Group rows by query.
+    """Group rows by query, every query padded to the longest.
 
     Returns (order, qidx, qmask): ``order`` sorts rows by query (stable);
     ``qidx`` is (Q, G) of positions into the *sorted* row order (0 padded);
     ``qmask`` marks real entries.
     """
-    order = np.argsort(query_ids, kind="stable")
-    sorted_q = query_ids[order]
-    _, starts, counts = np.unique(sorted_q, return_index=True,
-                                  return_counts=True)
-    Q, G = len(starts), int(counts.max())
-    qidx = np.zeros((Q, G), np.int32)
-    qmask = np.zeros((Q, G), np.float32)
-    for i, (s, c) in enumerate(zip(starts, counts)):
-        qidx[i, :c] = np.arange(s, s + c)
-        qmask[i, :c] = 1.0
-    return order.astype(np.int32), qidx, qmask
+    order, starts, counts = _query_runs(np.asarray(query_ids))
+    qidx, real = _padded_runs(starts, counts, int(counts.max()))
+    return (order.astype(np.int32), qidx.astype(np.int32),
+            real.astype(np.float32))
+
+
+class QueryLayout(NamedTuple):
+    """The rows of a table grouped by query into size classes, on the
+    host.  ``classes`` holds, per class, ``(rows, gains, labq, invmax)``:
+    ``rows`` (chunks, c, G) int32 indexes the table's rows in their
+    ORIGINAL order (``n`` where padded), ``gains`` and ``labq`` the
+    slots' ``2^label - 1`` and labels (0 and -1 where padded), ``invmax``
+    (chunks, c) each query's inverse ideal DCG.  ``slot`` (n,) is where
+    each original row lies in the concatenation of the classes' flattened
+    blocks.  ``pairs_useful`` is the sum over queries of their squared
+    sizes, ``pairs_computed`` the slots of the classes' pair tensors."""
+    n: int
+    queries: int
+    slot: np.ndarray
+    classes: tuple
+    pairs_useful: int
+    pairs_computed: int
+
+
+def pack_queries_by_size(labels: np.ndarray, query_ids: np.ndarray,
+                         truncation_level: int, max_label: int = 31,
+                         query_chunk_pairs: int = 4_000_000
+                         ) -> QueryLayout:
+    """Group rows by query and queries by size class (powers of two from
+    :data:`MIN_SIZE_CLASS`): array operations per class, no loop over
+    queries."""
+    order, starts, counts = _query_runs(np.asarray(query_ids))
+    n = len(order)
+    labels_sorted = np.asarray(labels, np.float32)[order]
+    # the next power of two: frexp's exponent of size - 1 is its bit length
+    size_class = np.maximum(
+        MIN_SIZE_CLASS, 1 << np.frexp(counts - 1.0)[1].astype(np.int64))
+    slot = np.empty(n, np.int32)
+    classes, base, computed = [], 0, 0
+    for G in np.unique(size_class).tolist():
+        sel = np.flatnonzero(size_class == G)
+        sidx, real = _padded_runs(starts[sel], counts[sel], G)
+        gains_q, lab_q, invmax = query_tensors(
+            labels_sorted, sidx, real.astype(np.float32), truncation_level,
+            max_label)
+        rows = np.where(real, order[sidx], n).astype(np.int32)
+        # chunks of equal length, so that padding adds under one query a
+        # chunk; a chunk's pair tensors hold c * G * max(G, lanes) cells
+        c_max = max(1, query_chunk_pairs // (G * max(G, _LANES)))
+        chunks = -(-len(sel) // c_max)
+        c = -(-len(sel) // chunks)
+        flat = base + np.arange(len(sel) * G).reshape(len(sel), G)
+        slot[rows[real]] = flat[real]
+        pad = chunks * c - len(sel)
+
+        def blocks(a, fill):
+            a = np.concatenate([a, np.full((pad,) + a.shape[1:], fill,
+                                           a.dtype)])
+            return a.reshape((chunks, c) + a.shape[1:])
+
+        classes.append((blocks(rows, n), blocks(gains_q, 0.0),
+                        blocks(lab_q, -1.0), blocks(invmax, 0.0)))
+        base += chunks * c * G
+        computed += chunks * c * G * G
+    return QueryLayout(n, len(starts), slot, tuple(classes),
+                       int(np.sum(counts.astype(np.int64) ** 2)), computed)
 
 
 def _dcg_discount(rank):
@@ -76,9 +167,37 @@ def query_tensors(labels_sorted: np.ndarray, qidx: np.ndarray,
             inv_max_dcg.astype(np.float32))
 
 
+def _pair_lambdas(s, qm, gains, labs, invmax, sig: float, tr: int):
+    """``(g_q, h_q)``, both (c, G): each slot's lambdarank gradient and
+    hessian from the full (c, G, G) pair tensors of a chunk of queries.
+    ``s`` holds the slots' current scores (-1e9 where padded), ``qm`` 1.0
+    at real slots."""
+    # ranks within query from current scores (descending; ties keep the
+    # order of the slots: argsort is stable)
+    rank_order = jnp.argsort(-s, axis=1)
+    ranks = jnp.argsort(rank_order, axis=1).astype(jnp.float32)
+    disc = _dcg_discount(ranks)                # (c, G)
+    # pairwise tensors (c, G, G): i vs j
+    better = (labs[:, :, None] > labs[:, None, :])
+    in_trunc = (ranks[:, :, None] < tr) | (ranks[:, None, :] < tr)
+    pair_mask = (better & in_trunc).astype(jnp.float32) * \
+        qm[:, :, None] * qm[:, None, :]
+    dgain = jnp.abs(gains[:, :, None] - gains[:, None, :])
+    ddisc = jnp.abs(disc[:, :, None] - disc[:, None, :])
+    delta = dgain * ddisc * invmax[:, None, None]
+    sdiff = s[:, :, None] - s[:, None, :]
+    p = jax.nn.sigmoid(-sig * sdiff)           # P(j beats i)
+    lam = -sig * p * delta * pair_mask         # grad for i (winner)
+    hes = sig * sig * p * (1.0 - p) * delta * pair_mask
+    g_q = jnp.sum(lam, axis=2) - jnp.sum(lam, axis=1)
+    h_q = jnp.sum(hes, axis=2) + jnp.sum(hes, axis=1)
+    return g_q, h_q
+
+
 def lambda_grad_sorted(s_sorted, qidx_c, qmask_c, gains_c, labq_c, invmax_c,
                        sigma: float, trunc: int, n: int):
-    """(n,) lambdarank grad/hess for scores already sorted by query.
+    """(n,) lambdarank grad/hess for scores already sorted by query, every
+    query padded to the longest (the mesh trainer's layout).
 
     Query tensors arrive pre-chunked ``(n_chunks, c, G)``; a ``lax.scan``
     over chunks bounds the transient (c, G, G) pairwise tensors.  Pure
@@ -90,24 +209,7 @@ def lambda_grad_sorted(s_sorted, qidx_c, qmask_c, gains_c, labq_c, invmax_c,
         g_acc, h_acc = carry
         qi, qm, gains, labs, invmax = args         # (c, G, ...)
         s = s_sorted[qi] * qm - 1e9 * (1.0 - qm)   # pad to -inf-ish
-        # ranks within query from current scores (descending)
-        rank_order = jnp.argsort(-s, axis=1)
-        ranks = jnp.argsort(rank_order, axis=1).astype(jnp.float32)
-        disc = _dcg_discount(ranks)                # (c, G)
-        # pairwise tensors (c, G, G): i vs j
-        better = (labs[:, :, None] > labs[:, None, :])
-        in_trunc = (ranks[:, :, None] < tr) | (ranks[:, None, :] < tr)
-        pair_mask = (better & in_trunc).astype(jnp.float32) * \
-            qm[:, :, None] * qm[:, None, :]
-        dgain = jnp.abs(gains[:, :, None] - gains[:, None, :])
-        ddisc = jnp.abs(disc[:, :, None] - disc[:, None, :])
-        delta = dgain * ddisc * invmax[:, None, None]
-        sdiff = s[:, :, None] - s[:, None, :]
-        p = jax.nn.sigmoid(-sig * sdiff)           # P(j beats i)
-        lam = -sig * p * delta * pair_mask         # grad for i (winner)
-        hes = sig * sig * p * (1.0 - p) * delta * pair_mask
-        g_q = jnp.sum(lam, axis=2) - jnp.sum(lam, axis=1)
-        h_q = jnp.sum(hes, axis=2) + jnp.sum(hes, axis=1)
+        g_q, h_q = _pair_lambdas(s, qm, gains, labs, invmax, sig, tr)
         # scatter back into sorted row order (pad slots -> dropped)
         flat_qi = jnp.where(qm > 0, qi.astype(jnp.int32), n).reshape(-1)
         g_acc = g_acc.at[flat_qi].add((g_q * qm).reshape(-1), mode="drop")
@@ -120,61 +222,113 @@ def lambda_grad_sorted(s_sorted, qidx_c, qmask_c, gains_c, labq_c, invmax_c,
     return g_s, h_s
 
 
+def lambda_grad_classes(scores, slot, classes, sigma: float, trunc: int):
+    """(n,) lambdarank grad/hess in the rows' ORIGINAL order from a
+    :class:`QueryLayout`'s arrays: one scan over chunks per size class,
+    each slot's sums kept where the slot is, and one gather by ``slot``
+    back to the rows (no scatter: a row lies in exactly one slot)."""
+    sig, tr = float(sigma), int(trunc)
+    n = scores.shape[0]
+
+    def chunk_step(_, args):
+        rows, gains, labs, invmax = args           # (c, G, ...)
+        qm = (rows < n).astype(jnp.float32)
+        s = scores.at[rows].get(mode="fill", fill_value=-1e9)
+        g_q, h_q = _pair_lambdas(s, qm, gains, labs, invmax, sig, tr)
+        return None, (g_q * qm, h_q * qm)
+
+    g_parts, h_parts = [], []
+    for blocks in classes:
+        _, (g_c, h_c) = jax.lax.scan(chunk_step, None, tuple(blocks))
+        g_parts.append(g_c.reshape(-1))
+        h_parts.append(h_c.reshape(-1))
+    return (jnp.concatenate(g_parts)[slot], jnp.concatenate(h_parts)[slot])
+
+
+class LambdarankObjective(Objective):
+    """lambdarank as an objective of the ordinary boost programs: its
+    ``labels`` are a :class:`QueryLayout`'s device arrays ``(slot,
+    classes)`` and its ``weights`` per-row multipliers of gradient and
+    hessian (LightGBM's lambdarank weight semantics).  Static in those
+    programs by ``sigma`` and the truncation level alone."""
+
+    name = "lambdarank"
+    model_str = "lambdarank"
+
+    def __init__(self, sigma: float = 1.0, truncation_level: int = 30):
+        self.sigma = float(sigma)
+        self.truncation_level = int(truncation_level)
+
+    def grad_hess(self, scores, labels, weights):
+        slot, classes = labels
+        with jax.named_scope("rank_grad"):
+            g, h = lambda_grad_classes(scores, slot, classes, self.sigma,
+                                       self.truncation_level)
+        return g * weights, jnp.maximum(h * weights, 1e-9)
+
+
+@functools.partial(jax.jit, static_argnames=("obj",))
+def _lambdarank_program(scores, labels, weights, obj: LambdarankObjective):
+    return obj.grad_hess(scores, labels, weights)
+
+
+class LambdarankGrad:
+    """A table's lambdarank gradient: the host's :class:`QueryLayout`,
+    the objective and the row weights.  ``engine.train`` takes one as its
+    ``grad_fn_override``, uploads the layout (:meth:`upload`, span
+    ``train.rank_pack``) and hands it with :attr:`objective` to its
+    ordinary programs.  Called as ``fn(scores) -> (grad, hess)`` (rows in
+    their original order) it runs the same mathematics as one program of
+    its own, built once a process for a set of shapes."""
+
+    def __init__(self, layout: QueryLayout, objective: LambdarankObjective,
+                 weights: Optional[np.ndarray] = None):
+        self.layout = layout
+        self.objective = objective
+        self.weights = weights
+        self._device = None
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the layout :meth:`upload` sends."""
+        return int(self.layout.slot.nbytes + sum(
+            a.nbytes for blocks in self.layout.classes for a in blocks))
+
+    def upload(self):
+        """``(labels, weights)`` for :meth:`LambdarankObjective.grad_hess`
+        on the device."""
+        lay = self.layout
+        labels = (jnp.asarray(lay.slot),
+                  tuple(tuple(jnp.asarray(a) for a in blocks)
+                        for blocks in lay.classes))
+        weights = (jnp.ones(lay.n, jnp.float32) if self.weights is None
+                   else jnp.asarray(self.weights, jnp.float32))
+        return labels, weights
+
+    def __call__(self, scores):
+        if self._device is None:
+            self._device = self.upload()
+        return _lambdarank_program(jnp.asarray(scores, jnp.float32),
+                                   *self._device, obj=self.objective)
+
+
 def make_lambdarank_grad_fn(labels: np.ndarray, query_ids: np.ndarray,
                             sigma: float = 1.0,
                             truncation_level: int = 30,
                             max_label: int = 31,
                             query_chunk_pairs: int = 4_000_000,
-                            weights: Optional[np.ndarray] = None):
-    """Build ``fn(scores) -> (grad, hess)`` closed over the query structure.
+                            weights: Optional[np.ndarray] = None
+                            ) -> LambdarankGrad:
+    """The table's :class:`LambdarankGrad`: ``fn(scores) -> (grad, hess)``.
 
     ``scores`` is in original row order (n,); so are the returned grad/hess.
     ``weights`` are per-row multipliers applied to grad/hess (LightGBM
     lambdarank weight semantics).
     """
-    n = len(labels)
-    order, qidx, qmask = pack_queries(np.asarray(query_ids))
-    Q, G = qidx.shape
-    chunk = max(1, min(Q, query_chunk_pairs // max(G * G, 1)))
-    pad_q = (-Q) % chunk
-    if pad_q:
-        qidx = np.concatenate([qidx, np.zeros((pad_q, G), np.int32)])
-        qmask = np.concatenate([qmask, np.zeros((pad_q, G), np.float32)])
-
-    labels_sorted = np.asarray(labels, np.float32)[order]
-    gains_q, lab_q, inv_max_dcg = query_tensors(
-        labels_sorted, qidx[:Q], qmask[:Q], truncation_level, max_label)
-    if pad_q:
-        gains_q = np.concatenate([gains_q, np.zeros((pad_q, G), np.float32)])
-        lab_q = np.concatenate([lab_q, -np.ones((pad_q, G), np.float32)])
-        inv_max_dcg = np.concatenate([inv_max_dcg,
-                                      np.zeros(pad_q, np.float32)])
-
-    qidx_d = jnp.asarray(qidx.reshape(-1, chunk, G))
-    qmask_d = jnp.asarray(qmask.reshape(-1, chunk, G))
-    gains_d = jnp.asarray(gains_q.reshape(-1, chunk, G))
-    labq_d = jnp.asarray(lab_q.reshape(-1, chunk, G))
-    invmax_d = jnp.asarray(inv_max_dcg.reshape(-1, chunk))
-    order_d = jnp.asarray(order)
-    w_d = None if weights is None else jnp.asarray(weights, jnp.float32)
-    sig = float(sigma)
-    trunc = int(truncation_level)
-
-    @jax.jit
-    def grad_fn(scores):
-        s_sorted = scores[order_d]                     # (n,) sorted by query
-        g_s, h_s = lambda_grad_sorted(
-            s_sorted, qidx_d, qmask_d, gains_d, labq_d, invmax_d,
-            sig, trunc, n)
-        # back to original row order
-        g = jnp.zeros(n, jnp.float32).at[order_d].set(g_s)
-        h = jnp.zeros(n, jnp.float32).at[order_d].set(h_s)
-        if w_d is not None:
-            g = g * w_d
-            h = h * w_d
-        return g, jnp.maximum(h, 1e-9)
-
-    return grad_fn
+    layout = pack_queries_by_size(labels, query_ids, truncation_level,
+                                  max_label, query_chunk_pairs)
+    return LambdarankGrad(
+        layout, LambdarankObjective(sigma, truncation_level), weights)
 
 
 def shard_queries(labels: np.ndarray, query_ids: np.ndarray, n_shards: int,
@@ -199,11 +353,7 @@ def shard_queries(labels: np.ndarray, query_ids: np.ndarray, n_shards: int,
     the sharded-ingestion path pins each query to the shard whose host
     already holds its rows (see :func:`shard_queries_from_shards`).
     """
-    q = np.asarray(query_ids)
-    order = np.argsort(q, kind="stable")
-    sorted_q = q[order]
-    _, starts, counts = np.unique(sorted_q, return_index=True,
-                                  return_counts=True)
+    order, starts, counts = _query_runs(np.asarray(query_ids))
     D = n_shards
     loads = np.zeros(D, np.int64)
     if assign is None:
@@ -310,7 +460,14 @@ def shard_queries_from_shards(label_shards, qid_shards, truncation_level: int,
 
 
 class LightGBMRanker(LightGBMBase):
-    """lambdarank estimator; mirrors the reference's LightGBMRanker API."""
+    """lambdarank estimator; mirrors the reference's LightGBMRanker API.
+
+    On one device the fit packs its queries by size class (powers of two
+    from 8 documents up to the longest query: a query of more than four
+    documents costs under four times its pairs) and trains through the
+    ordinary boost programs with :class:`LambdarankObjective`; on a mesh
+    each shard's queries are padded to its longest (module docstring).
+    """
 
     _default_objective = "lambdarank"
 
@@ -368,19 +525,26 @@ class LightGBMRankerModel(LightGBMModelBase):
 
 def ndcg_at_k(scores: np.ndarray, labels: np.ndarray, query_ids: np.ndarray,
               k: int = 10) -> float:
-    """Mean NDCG@k across queries (evaluation helper, numpy)."""
-    out, cnt = 0.0, 0
-    for q in np.unique(query_ids):
-        m = query_ids == q
-        s, l = scores[m], labels[m]
-        if len(l) < 2 or l.max() == l.min():
-            continue
-        order = np.argsort(-s)
-        gains = 2.0 ** l - 1
-        disc = 1.0 / np.log2(2 + np.arange(len(l)))
-        dcg = (gains[order][:k] * disc[:k]).sum()
-        idcg = (np.sort(gains)[::-1][:k] * disc[:k]).sum()
-        if idcg > 0:
-            out += dcg / idcg
-            cnt += 1
-    return out / max(cnt, 1)
+    """Mean NDCG@k across queries (evaluation helper, numpy): one sort of
+    the rows by query and falling score, one by query and falling label.
+    Queries of one document or one label value are left out."""
+    scores, labels = np.asarray(scores), np.asarray(labels)
+    _, qcode = np.unique(np.asarray(query_ids), return_inverse=True)
+    n = len(qcode)
+    if n == 0:
+        return 0.0
+    gains = 2.0 ** labels - 1
+    by_score = np.lexsort((-scores, qcode))
+    by_label = np.lexsort((-labels, qcode))
+    counts = np.bincount(qcode)
+    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    pos = np.arange(n) - np.repeat(starts, counts)
+    disc = np.where(pos < k, 1.0 / np.log2(2 + pos), 0.0)
+    dcg = np.add.reduceat(gains[by_score] * disc, starts)
+    idcg = np.add.reduceat(gains[by_label] * disc, starts)
+    lab_sorted = labels[by_label]
+    mixed = lab_sorted[starts] != lab_sorted[starts + counts - 1]
+    keep = (counts >= 2) & mixed & (idcg > 0)
+    if not keep.any():
+        return 0.0
+    return float(np.sum(dcg[keep] / idcg[keep]) / keep.sum())

@@ -54,6 +54,33 @@ class TestEstimate:
         assert est["hist_build"] == (bucket * features if on_chip else
                                      min(rows, 8192) * features * 320)
 
+    def test_estimate_against_the_ranking_cells_measured_peak(self):
+        """``istella_fit`` (my chip run, PR 31): 8 492 530 176 bytes at
+        7 325 625 x 220 with a query layout of 156 560 112 bytes.  Without
+        the layout and the pair pass's temporaries the estimate read
+        0.88 of it; with them 0.94."""
+        kw = dict(chunk=2, hist_on_chip=True)
+        bare = estimate_fit_bytes(7_325_625, 220, 255, 255, **kw)
+        est = estimate_fit_bytes(7_325_625, 220, 255, 255,
+                                 rank_layout_bytes=156_560_112, **kw)
+        assert bare["total"] / 8_492_530_176 < 0.9
+        assert 0.9 < est["total"] / 8_492_530_176 < 1.1
+        assert est["rank_layout"] == est["total"] - bare["total"]
+        assert "rank_layout" not in bare
+
+    def test_ranking_fit_hands_its_layout_to_the_guard(self, monkeypatch):
+        from mmlspark_tpu.gbdt import budget
+        from tests.test_istella_cell import small_rank_fit
+        seen = {}
+        real = budget.check_fit_budget
+
+        def spy(*a, **kw):
+            seen.update(kw)
+            return real(*a, **kw)
+        monkeypatch.setattr(budget, "check_fit_budget", spy)
+        _, _, grad = small_rank_fit()
+        assert seen["rank_layout_bytes"] == grad.nbytes > 0
+
     def test_bagging_and_validation_terms_counted(self):
         base = estimate_fit_bytes(1 << 20, 20, 64, 31)
         bag = estimate_fit_bytes(1 << 20, 20, 64, 31, bagging=True)
