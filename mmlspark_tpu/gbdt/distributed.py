@@ -24,11 +24,26 @@ Parallelism mapping (reference ``parallelism`` param → mesh axes):
 The whole boost step (grad/hess → grow tree → score update) runs inside one
 ``shard_map`` under ``jit``, so a single compiled program per iteration does
 compute + collectives with no host round-trips.
+
+A built step outlives its fit (:func:`_build_step`): the ``make_*``
+builders keep their ``jit(shard_map(...))`` in one module-level table keyed
+on the builder's name and its arguments as given — ``mesh``, ``obj``,
+``cfg``, ``lr`` and every flag and scalar, all hashable by value — so the
+next fit with an equal key gets the SAME jit object and its first call is
+a hit in JAX's own dispatch cache: no trace, no lowering, no executable
+load, as on one chip, where ``engine._boost_scan`` is a module-level jit.
+``efb`` stays outside the key: the bundle maps are device arrays baked
+into the program as constants, true of one fit's table only, so a builder
+called with bundles builds afresh every time and is never kept.
 """
 
 from __future__ import annotations
 
+import collections
+import copy
 import functools
+import inspect
+import threading
 from typing import Optional, Tuple
 
 import jax
@@ -46,10 +61,77 @@ from .objectives import Objective
 
 VALID_PARALLELISM = ("serial", "data", "feature", "data+feature", "voting")
 
-#: the fit's ``train.build_step`` span (docs/observability.md): building
-#: the ``jit(shard_map(...))`` of a mesh fit.  The program is traced and
-#: compiled at its first call, which is ``train.launch``.
-_build_step = get_profiler().region("train.build_step")
+#: built steps kept across fits, least recently used out.  An entry is a
+#: jit object and keeps the executables it has loaded on the chips; a
+#: dropped one is built (and traced) again by the next fit that asks.
+_STEP_TABLE_MAX = 8
+_step_table: "collections.OrderedDict[tuple, object]" = \
+    collections.OrderedDict()
+_step_table_lock = threading.Lock()
+
+
+def _build_step(builder, span: bool = True):
+    """``builder``, memoised on its arguments, under the fit's
+    ``train.build_step`` span (docs/observability.md).
+
+    The key is the builder's name and every argument as given (defaults
+    filled in): each is hashable by value and equal from fit to fit —
+    a ``Mesh`` over the same devices, ``Objective.__hash__`` over type
+    and state (``prepare``'s included), the frozen ``GrowerConfig``,
+    ``lr``, the flags.  An equal key returns the SAME ``jax.jit``
+    object, so the program is traced and compiled by the first fit's
+    ``train.launch`` alone.  ``efb`` is outside the key because it is
+    not a description of the program but a part of it: device arrays
+    that the trace bakes in as constants.  A call with bundles (or with
+    an objective whose state does not hash) is a ``bypass``: built
+    afresh, never stored, so no fit runs a program built around another
+    fit's maps, and the fault-tolerance replay still gets a new program
+    around its re-uploaded maps.  A step is built around a copy of its
+    objective, and the key holds that copy: a later ``prepare`` on the
+    caller's object reaches neither.  Built under the lock (building
+    traces nothing), so two threads asking for one key get one object.
+
+    The span's attr ``step_cache`` says ``hit``, ``miss`` or ``bypass``
+    and ``engine.train_stats`` counts ``mesh_step_hits`` /
+    ``mesh_step_builds``; ``span=False`` memoises in silence
+    (``make_tree_predict``, a helper of the dart fits' one step)."""
+    sig = inspect.signature(builder)
+
+    def lookup(*args, **kwargs):
+        bound = sig.bind(*args, **kwargs)
+        bound.apply_defaults()
+        given = {k: copy.copy(v) if isinstance(v, Objective) else v
+                 for k, v in bound.arguments.items()}
+        key = (builder.__name__, *given.values())
+        bypass = given.get("efb") is not None
+        if not bypass:
+            try:
+                hash(key)
+            except TypeError:
+                bypass = True
+        if bypass:
+            return builder(**given), "bypass"
+        with _step_table_lock:
+            step = _step_table.get(key)
+            if step is not None:
+                _step_table.move_to_end(key)
+                return step, "hit"
+            step = _step_table[key] = builder(**given)
+            if len(_step_table) > _STEP_TABLE_MAX:
+                _step_table.popitem(last=False)
+            return step, "miss"
+
+    @functools.wraps(builder)
+    def build(*args, **kwargs):
+        if not span:
+            return lookup(*args, **kwargs)[0]
+        from .engine import train_stats
+        with get_profiler().region("train.build_step") as sp:
+            step, sp["step_cache"] = lookup(*args, **kwargs)
+        train_stats.incr("mesh_step_hits" if sp["step_cache"] == "hit"
+                         else "mesh_step_builds")
+        return step
+    return build
 
 
 def _upload(prepare):
@@ -468,6 +550,7 @@ def make_dart_step(mesh: Mesh, obj: Objective, cfg: GrowerConfig,
     return jax.jit(mapped)
 
 
+@functools.partial(_build_step, span=False)
 def make_tree_predict(mesh: Mesh, num_leaves: int, num_class: int = 1):
     """Replicated-tree scoring of mesh-sharded binned rows — dart's
     dropped-tree subtraction and validation scoring.  Data-only mesh:

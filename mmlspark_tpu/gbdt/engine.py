@@ -399,8 +399,8 @@ _CKPT_MESH_STATE = _CKPT_MESH_PREFIX + "{:06d}.npz"
 train_stats = StageStats()
 for _k in ("chunks_replayed", "ckpt_saved", "ckpt_resumed",
            "ckpt_discarded", "boost_chunks", "ref_profiles",
-           "ref_profiles_device", "collective_count",
-           "collective_payload_bytes"):
+           "ref_profiles_device", "mesh_step_hits", "mesh_step_builds",
+           "collective_count", "collective_payload_bytes"):
     train_stats.incr(_k, 0)
 del _k
 # federate under the process registry: a serving process that also

@@ -146,8 +146,8 @@ class GrowerConfig:
     #: the engine sets it from debug mode at config build.  Static, and
     #: off means nothing of them is traced: checkify numbers each check
     #: from a process-wide counter, and that number, baked into the HLO
-    #: as a constant, gave every re-trace of one program (a mesh fit
-    #: re-traces per fit) another persistent-cache key, so the cache
+    #: as a constant, gave every re-trace of one program (a mesh step
+    #: built anew) another persistent-cache key, so the cache
     #: missed and the fit compiled for real (PERF.md Findings, PR 25)
     debug_checks: bool = False
 

@@ -341,7 +341,9 @@ class TestForestIdentity:
             return real(*a, **k)
 
         monkeypatch.setattr(pc, "ring_allreduce", spy)
-        self._fit("dot16", "ring", mesh2_2axis)
+        # a distinct learning rate keeps the mesh's step table from
+        # handing back the identity test's step, traced already
+        self._fit("dot16", "ring", mesh2_2axis, learning_rate=0.11)
         assert calls, "collective='ring' never reached the ring kernel"
 
     def test_resolution_recorded(self, mesh2_2axis):

@@ -835,3 +835,259 @@ class TestMeshRankingDart:
         ndcg = float(np.mean(ndcg_at_k(np.asarray(out["prediction"]),
                                        t["label"], t["query"], 5)))
         assert ndcg > 0.75
+
+
+class TestMeshStepCache:
+    """The mesh's built chunk programs outlive their fit
+    (``distributed._build_step``): an equal (builder, mesh, objective,
+    config, flags) finds the SAME jit object, so the second fit neither
+    traces nor compiles; anything in the key that differs is a miss;
+    bundles, baked into the program, bypass the table."""
+
+    @pytest.fixture(scope="class")
+    def tables(self):
+        from sklearn.datasets import make_classification
+        X, y = make_classification(n_samples=1200, n_features=10,
+                                   n_informative=6, random_state=41)
+        X3, y3 = make_classification(n_samples=900, n_features=8,
+                                     n_informative=6, n_classes=3,
+                                     random_state=42)
+        rank = TestMeshRankingDart()._rank_table()
+        return {"binary": {"features": X, "label": y.astype(float)},
+                "multi": {"features": X3, "label": y3.astype(float)},
+                "rank": rank}
+
+    @staticmethod
+    def _fit(est, table, mesh=None):
+        """One mesh fit and what it left behind: the forest's text, the
+        ``step_cache`` attr of each ``train.build_step``, the attrs of
+        each ``train.launch``, and the seconds traced and the programs
+        compiled or loaded over the WHOLE fit."""
+        from mmlspark_tpu.core.profiler import get_profiler
+        prof = get_profiler()
+        before = {s["id"] for s in prof.spans()}
+        traced0 = prof.jax_seconds("jaxpr_trace")
+        seq0 = prof.compile_seq()
+        model = est.setMesh(mesh or build_mesh(data=8, feature=1)) \
+            .fit(table)
+        new = [s for s in prof.spans() if s["id"] not in before]
+        return {
+            "forest": _forest_string(model),
+            "step_cache": [s["attrs"]["step_cache"] for s in new
+                           if s["name"] == "train.build_step"],
+            "launches": [s["attrs"] for s in new
+                         if s["name"] == "train.launch"],
+            "traced_s": prof.jax_seconds("jaxpr_trace") - traced0,
+            "compiles": prof.compile_seq() - seq0}
+
+    BASE = dict(numIterations=4, numLeaves=7, minDataInLeaf=5, verbosity=0)
+    BUILDERS = {
+        # builder the fit reaches: (estimator, its table, parameters,
+        # whether the fit dispatches through train.launch)
+        "boost": ("classifier", "binary", {}, True),
+        "goss": ("classifier", "binary", {"boostingType": "goss"}, True),
+        "multiclass": ("classifier", "multi", {}, True),
+        "rf": ("classifier", "binary",
+               {"boostingType": "rf", "baggingFraction": 0.6,
+                "baggingFreq": 1}, True),
+        "dart": ("classifier", "binary",
+                 {"boostingType": "dart", "dropRate": 0.5}, False),
+        "ranking": ("ranker", "rank", {"groupCol": "query"}, False),
+        "ranking_dart": ("ranker", "rank",
+                         {"groupCol": "query", "boostingType": "dart",
+                          "dropRate": 0.5}, False),
+    }
+
+    def _estimator(self, kind, **kw):
+        from mmlspark_tpu.gbdt import LightGBMRanker
+        cls = LightGBMRanker if kind == "ranker" else LightGBMClassifier
+        return cls(**{**self.BASE, **kw})
+
+    @pytest.mark.parametrize("builder", sorted(BUILDERS))
+    def test_second_equal_fit_hits_and_neither_traces_nor_compiles(
+            self, tables, builder):
+        from mmlspark_tpu.gbdt import distributed, engine
+        kind, table, kw, launches = self.BUILDERS[builder]
+        # a learning rate no other test of this process uses: the first
+        # fit is a miss whatever ran before
+        kw = dict(kw, learningRate=0.0625 + 0.001 * sorted(
+            self.BUILDERS).index(builder))
+        stats0 = dict(engine.train_stats.snapshot()["counters"])
+        first = self._fit(self._estimator(kind, **kw), tables[table])
+        second = self._fit(self._estimator(kind, **kw), tables[table])
+        assert first["step_cache"] == ["miss"]
+        assert first["traced_s"] > 0 and first["compiles"] > 0
+        assert second["step_cache"] == ["hit"]
+        assert bool(second["launches"]) == launches
+        for attrs in second["launches"]:
+            assert attrs["jaxpr_trace_s"] == 0
+            assert attrs["compile_misses"] == 0
+        # the independent witness, over the whole fit (the dart and
+        # ranking trainers dispatch outside train.launch)
+        assert second["traced_s"] == 0 and second["compiles"] == 0
+        assert second["forest"] == first["forest"]
+        stats = engine.train_stats.snapshot()["counters"]
+        assert stats["mesh_step_builds"] - stats0["mesh_step_builds"] == 1
+        assert stats["mesh_step_hits"] - stats0["mesh_step_hits"] == 1
+        assert len(distributed._step_table) <= distributed._STEP_TABLE_MAX
+
+    @pytest.mark.parametrize("what", ["learning_rate", "num_leaves",
+                                      "objective_state", "mesh_shape"])
+    def test_a_different_key_is_a_miss(self, tables, what):
+        t = tables["binary"]
+        kw, other_kw = {"learningRate": 0.0525, "isUnbalance": True}, {}
+        other_t, other_mesh = t, None
+        if what == "learning_rate":
+            other_kw = {"learningRate": 0.0535}
+        elif what == "num_leaves":
+            other_kw = {"numLeaves": 6}
+        elif what == "objective_state":
+            # prepare() resolves the class weight from the labels: the
+            # same estimator on labels of another balance is another
+            # program (the weight is a constant of the trace)
+            y = t["label"].copy()
+            y[np.flatnonzero(y > 0)[::3]] = 0.0
+            other_t = {"features": t["features"], "label": y}
+        else:
+            other_mesh = build_mesh(data=4, feature=2)
+        base = self._fit(self._estimator("classifier", **kw), t)
+        assert base["step_cache"] in (["miss"], ["hit"])
+        other = self._fit(self._estimator("classifier",
+                                          **{**kw, **other_kw}),
+                          other_t, mesh=other_mesh)
+        assert other["step_cache"] == ["miss"]
+        assert other["forest"] != base["forest"] or what == "mesh_shape"
+        # and the first program is still there, with the first forest
+        again = self._fit(self._estimator("classifier", **kw), t)
+        assert again["step_cache"] == ["hit"]
+        assert again["forest"] == base["forest"]
+
+    def test_a_remade_mesh_and_a_copied_objective_hit(self, tables):
+        """What the key holds compares by value: ``resolve_mesh`` makes
+        the mesh again every fit, the estimator its objective."""
+        import copy
+        from mmlspark_tpu.gbdt import distributed
+        from mmlspark_tpu.gbdt.grower import GrowerConfig
+        from mmlspark_tpu.gbdt.objectives import BinaryObjective
+        obj = BinaryObjective(is_unbalance=True)
+        obj.prepare(np.array([0., 0., 1.]), np.ones(3))
+        cfg = GrowerConfig(num_leaves=5)
+        a = distributed.make_boost_scan(
+            distributed.resolve_mesh("data"), obj, cfg, 0.03125, False)
+        b = distributed.make_boost_scan(
+            distributed.resolve_mesh("data"), copy.copy(obj),
+            GrowerConfig(num_leaves=5), 0.03125, bag_sharded=False)
+        assert a is b
+        # the table's entry owns its objective: preparing the caller's on
+        # other labels reaches neither the key nor the program's closure
+        obj.prepare(np.array([0., 1., 1.]), np.ones(3))
+        c = distributed.make_boost_scan(
+            distributed.resolve_mesh("data"), obj, cfg, 0.03125, False)
+        assert c is not a
+        again = BinaryObjective(is_unbalance=True)
+        again.prepare(np.array([0., 0., 1.]), np.ones(3))
+        assert distributed.make_boost_scan(
+            distributed.resolve_mesh("data"), again, cfg, 0.03125,
+            False) is a
+
+    def test_a_fit_with_bundles_bypasses_the_table(self):
+        """The bundle maps are constants of the program: a fit that
+        bundles builds its own step and leaves none behind, so no fit
+        ever runs a program built around another table's maps."""
+        from mmlspark_tpu.gbdt import distributed
+        from tests.test_efb import _sparse_table
+
+        def table(seed, group_size):
+            # the same shape, bundled differently
+            X, y = _sparse_table(np.random.default_rng(seed), n=1600,
+                                 groups=24 // group_size,
+                                 group_size=group_size)
+            return {"features": X, "label": y}
+
+        kw = dict(enableBundle=True, learningRate=0.0575)
+        keys0 = list(distributed._step_table)
+        fits = [self._fit(self._estimator("classifier", **kw), t)
+                for t in (table(3, 8), table(4, 6), table(3, 8))]
+        assert [f["step_cache"] for f in fits] == [["bypass"]] * 3
+        assert list(distributed._step_table) == keys0
+        assert fits[2]["forest"] == fits[0]["forest"] != fits[1]["forest"]
+
+    def test_an_objective_that_does_not_hash_bypasses_the_table(self):
+        """A mesh fit never hashed its objective before the table did: one
+        whose state holds an array still gets its step, built afresh."""
+        from mmlspark_tpu.core.profiler import get_profiler
+        from mmlspark_tpu.gbdt import distributed
+        from mmlspark_tpu.gbdt.grower import GrowerConfig
+        from mmlspark_tpu.gbdt.objectives import BinaryObjective
+        obj = BinaryObjective()
+        obj.class_weight = np.ones(2)
+        keys0 = list(distributed._step_table)
+        step = distributed.make_boost_scan(
+            build_mesh(data=8, feature=1), obj, GrowerConfig(num_leaves=5),
+            0.1, False)
+        assert callable(step)
+        assert get_profiler().spans()[-1]["attrs"] == {
+            "step_cache": "bypass"}
+        assert list(distributed._step_table) == keys0
+
+    def test_the_table_never_exceeds_its_bound(self):
+        from mmlspark_tpu.gbdt import distributed
+        from mmlspark_tpu.gbdt.grower import GrowerConfig
+        from mmlspark_tpu.gbdt.objectives import BinaryObjective
+        mesh, obj = build_mesh(data=8, feature=1), BinaryObjective()
+        cfg = GrowerConfig(num_leaves=5)
+        bound = distributed._STEP_TABLE_MAX
+        first = distributed.make_boost_scan(mesh, obj, cfg, 0.5, False)
+        for i in range(bound + 3):
+            # the first entry is asked for again and again: the least
+            # recently USED goes, not the oldest
+            assert distributed.make_boost_scan(
+                mesh, obj, cfg, 0.5, False) is first
+            distributed.make_dart_step(mesh, obj, cfg, 0.5 + i / 1024)
+            assert len(distributed._step_table) <= bound
+        assert len(distributed._step_table) == bound
+        # a dropped entry is simply built again
+        assert ("make_dart_step", mesh, obj, cfg, 0.5, 1) \
+            not in distributed._step_table
+        assert callable(distributed.make_dart_step(mesh, obj, cfg, 0.5))
+
+    def test_two_threads_fitting_at_once_both_return_the_forest(
+            self, tables):
+        """``TuneHyperparameters`` may fit from threads: both ask for
+        one key at once and get one program, never a half-built one."""
+        import sys
+        import threading
+        from mmlspark_tpu.gbdt import distributed
+        kw = dict(learningRate=0.0585)
+        n_threads = 4
+        out, errors = [None] * n_threads, []
+        gate = threading.Barrier(n_threads)
+
+        def work(i):
+            try:
+                gate.wait(timeout=60)
+                out[i] = self._fit(self._estimator("classifier", **kw),
+                                   tables["binary"])["forest"]
+            except Exception as e:  # noqa: BLE001 - reported below
+                errors.append(e)
+
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n_threads)]
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=300)
+        finally:
+            sys.setswitchinterval(switch)
+        assert not any(th.is_alive() for th in threads)
+        assert not errors, errors
+        alone = self._fit(self._estimator("classifier", **kw),
+                          tables["binary"])
+        assert alone["step_cache"] == ["hit"]
+        assert out == [alone["forest"]] * n_threads
+        keys = [k for k in distributed._step_table
+                if k[0] == "make_boost_scan" and k[4] == 0.0585]
+        assert len(keys) == 1

@@ -498,12 +498,16 @@ def lowered_op_names():
 
 
 def test_a_rebuilt_mesh_step_lowers_to_the_same_program():
-    """A mesh fit builds and traces its step anew; what it lowers to
-    must not depend on how many programs the process traced before (a
+    """A step built anew (a fit with bundles, an entry the step table
+    dropped, the next process) is traced anew; what it lowers to must
+    not depend on how many programs the process traced before (a
     checkify error number did), or the persistent compile cache misses
-    on every fit."""
-    from mmlspark_tpu.gbdt.distributed import (make_boost_scan,
-                                               prepare_arrays)
+    on every such fit."""
+    from mmlspark_tpu.gbdt import distributed
+    from mmlspark_tpu.gbdt.distributed import prepare_arrays
+    # the builder itself, under the table that would hand the same step
+    # back three times
+    make_boost_scan = distributed.make_boost_scan.__wrapped__
     from jax.sharding import NamedSharding, PartitionSpec as P
     n, f = 1000, 6
     obj = BinaryObjective()
