@@ -2,7 +2,8 @@ from .params import (Param, Params, TypeConverters, HasInputCol, HasOutputCol,
                      HasInputCols, HasOutputCols, HasFeaturesCol, HasLabelCol,
                      HasPredictionCol, HasProbabilityCol, HasRawPredictionCol,
                      HasWeightCol, HasValidationIndicatorCol, HasSeed)
-from .schema import DataTable, to_table, from_table, features_matrix
+from .schema import (DataTable, SparseColumn, to_table, from_table,
+                     features_matrix)
 from .pipeline import (PipelineStage, Transformer, Estimator, Model, Pipeline,
                        PipelineModel, STAGE_REGISTRY)
 from .mesh import (build_mesh, get_mesh, use_mesh, distributed_initialize,
@@ -22,7 +23,8 @@ __all__ = [
     "HasInputCols", "HasOutputCols", "HasFeaturesCol", "HasLabelCol",
     "HasPredictionCol", "HasProbabilityCol", "HasRawPredictionCol",
     "HasWeightCol", "HasValidationIndicatorCol", "HasSeed",
-    "DataTable", "to_table", "from_table", "features_matrix",
+    "DataTable", "SparseColumn", "to_table", "from_table",
+    "features_matrix",
     "PipelineStage", "Transformer", "Estimator", "Model", "Pipeline",
     "PipelineModel", "STAGE_REGISTRY",
     "build_mesh", "get_mesh", "use_mesh", "distributed_initialize",

@@ -37,13 +37,100 @@ ColumnLike = np.ndarray  # rows on axis 0: 1-D scalar, 2-D vector, N-D tensor
 TableLike = Union["DataTable", "pd.DataFrame", "pa.Table", Dict[str, Any]]
 
 
+class SparseColumn:
+    """A sparse vector column: ``shape[0]`` rows of ``shape[1]`` slots in
+    CSR form, the analog of a Spark ML column of ``SparseVector``s (what
+    upstream hands LightGBM through ``LGBM_DatasetCreateFromCSR``).
+
+    ``indptr`` (rows + 1,), ``indices`` (nnz,) ascending within a row,
+    ``values`` (nnz,); a slot no entry names holds 0.  Row selection
+    (a slice, a boolean mask or row numbers) gives another column;
+    ``toarray`` the dense rows, for consumers that need them and tables
+    small enough to have them."""
+
+    ndim = 2
+
+    def __init__(self, indptr, indices, values, shape):
+        self.indptr = np.asarray(indptr, np.int64)
+        self.indices = np.asarray(indices)
+        self.values = np.asarray(values)
+        self.shape = (int(shape[0]), int(shape[1]))
+        if self.indptr.shape != (self.shape[0] + 1,) \
+                or self.indices.shape != self.values.shape \
+                or int(self.indptr[-1]) != self.indices.size:
+            raise ValueError("indptr, indices and values disagree with "
+                             f"shape {self.shape}")
+
+    @classmethod
+    def from_dense(cls, X) -> "SparseColumn":
+        X = np.asarray(X)
+        rows, cols = np.nonzero(X != 0)        # (a NaN is an entry)
+        indptr = np.zeros(X.shape[0] + 1, np.int64)
+        np.cumsum(np.bincount(rows, minlength=X.shape[0]), out=indptr[1:])
+        return cls(indptr, cols.astype(np.int32), X[rows, cols], X.shape)
+
+    @property
+    def dtype(self):
+        return self.values.dtype
+
+    @property
+    def nnz(self) -> int:
+        return int(self.indices.size)
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.indptr.nbytes + self.indices.nbytes
+                   + self.values.nbytes)
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def row_ids(self) -> np.ndarray:
+        """The row of every entry, ``(nnz,)``."""
+        return np.repeat(
+            np.arange(self.shape[0], dtype=np.int32 if self.shape[0]
+                      < 2 ** 31 else np.int64), np.diff(self.indptr))
+
+    def __getitem__(self, idx) -> "SparseColumn":
+        if isinstance(idx, slice):
+            a, b, step = idx.indices(self.shape[0])
+            if step == 1:
+                lo, hi = self.indptr[a], self.indptr[max(a, b)]
+                return SparseColumn(self.indptr[a:max(a, b) + 1] - lo,
+                                    self.indices[lo:hi],
+                                    self.values[lo:hi],
+                                    (max(b - a, 0), self.shape[1]))
+            idx = np.arange(a, b, step)
+        idx = np.asarray(idx)
+        if idx.dtype == bool:
+            idx = np.flatnonzero(idx)
+        lens = self.indptr[idx + 1] - self.indptr[idx]
+        indptr = np.zeros(idx.size + 1, np.int64)
+        np.cumsum(lens, out=indptr[1:])
+        # entry k of the selection is entry ``take[k]`` of the column
+        take = (np.arange(indptr[-1]) - np.repeat(indptr[:-1], lens)
+                + np.repeat(self.indptr[idx], lens))
+        return SparseColumn(indptr, self.indices[take], self.values[take],
+                            (idx.size, self.shape[1]))
+
+    def toarray(self, dtype=np.float64) -> np.ndarray:
+        out = np.zeros(self.shape, dtype)
+        out[self.row_ids(), self.indices] = self.values
+        return out
+
+    def __repr__(self) -> str:
+        return (f"SparseColumn{self.shape}({self.nnz} entries, "
+                f"{self.dtype})")
+
+
 class DataTable:
     """An ordered, column-oriented table backed by numpy arrays.
 
     Columns are 1-D numpy arrays (scalar columns), 2-D numpy arrays
     (fixed-width vector columns — the analog of Spark ML vector columns),
     or higher-rank arrays whose leading axis is the row axis (e.g. NHWC
-    image batches).  Object-dtype 1-D columns may hold arbitrary python
+    image batches); a :class:`SparseColumn` is a vector column kept in
+    CSR form.  Object-dtype 1-D columns may hold arbitrary python
     payloads (image structs, HTTP responses) just as Spark rows may hold
     structs.
     """
@@ -132,6 +219,8 @@ class DataTable:
             raise ImportError("pandas is not available")
         data = {}
         for k, v in self._cols.items():
+            if isinstance(v, SparseColumn):
+                v = v.toarray()
             if v.ndim >= 2:
                 data[k] = list(v)  # vector/tensor column -> object column
             else:
@@ -144,6 +233,8 @@ class DataTable:
         arrays, names = [], []
         for k, v in self._cols.items():
             names.append(k)
+            if isinstance(v, SparseColumn):
+                v = v.toarray()
             if v.ndim == 2:
                 arrays.append(pa.FixedSizeListArray.from_arrays(
                     pa.array(v.reshape(-1)), v.shape[1]))
@@ -167,7 +258,10 @@ class DataTable:
 
 
 def _as_column(col: Any) -> np.ndarray:
-    """Normalize a column to a numpy array with rows on axis 0."""
+    """Normalize a column to a numpy array with rows on axis 0 (a
+    :class:`SparseColumn` stays as it is)."""
+    if isinstance(col, SparseColumn):
+        return col
     if isinstance(col, np.ndarray):
         if col.ndim >= 1:
             return col
@@ -257,9 +351,14 @@ def from_table(table: DataTable, like: TableLike) -> TableLike:
     return table
 
 
-def features_matrix(table: DataTable, featuresCol: str) -> np.ndarray:
-    """Fetch a 2-D float feature matrix from a vector column."""
+def features_matrix(table: DataTable, featuresCol: str,
+                    sparse: bool = False) -> np.ndarray:
+    """Fetch a 2-D float feature matrix from a vector column.  A
+    :class:`SparseColumn` comes back dense unless the caller takes
+    ``sparse`` rows (the GBDT estimators' fit does)."""
     col = table[featuresCol]
+    if isinstance(col, SparseColumn):
+        return col if sparse else col.toarray()
     if col.ndim != 2:
         raise ValueError(
             f"Column {featuresCol!r} is not a vector column (shape {col.shape}); "

@@ -495,11 +495,11 @@ def build_reference_profile(bins: np.ndarray, mapper,
     ``fine_counts``: ``(f, mapper.num_total_bins)`` exact integer counts
     of ``bins``' rows per fine bin, from a caller that has them already
     (the fit's device-resident table counts them in one pass:
-    ``gbdt/engine.py``); absent, each column is counted here, one
-    strided pass over the table a feature.
+    ``gbdt/engine.py``); ``bins`` then only has to have the table's
+    ``shape`` (a bundled table has no ``(n, f)`` array).  Absent, each
+    column is counted here, one strided pass over the table a feature.
     """
-    bins = np.asarray(bins)
-    n, f = bins.shape
+    n, f = np.shape(bins)
     if fine_counts is not None:
         fine_counts = np.asarray(fine_counts, np.int64)
         if fine_counts.shape != (f, mapper.num_total_bins):
@@ -520,7 +520,7 @@ def build_reference_profile(bins: np.ndarray, mapper,
         if fine_counts is not None:
             fine = fine_counts[j]
         else:
-            col = np.ascontiguousarray(bins[:, j])
+            col = np.ascontiguousarray(np.asarray(bins)[:, j])
             fine = np.bincount(col, minlength=mapper.num_total_bins
                                ).astype(np.int64)
         sk.nan = int(fine[mapper.missing_bin])

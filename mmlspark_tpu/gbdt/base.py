@@ -23,7 +23,7 @@ from ..core.params import (Param, Params, TypeConverters, HasFeaturesCol,
                            HasLabelCol, HasPredictionCol, HasWeightCol,
                            HasValidationIndicatorCol)
 from ..core.pipeline import Estimator, Model
-from ..core.schema import DataTable, features_matrix
+from ..core.schema import DataTable, SparseColumn, features_matrix
 from ..core import serialize
 from .binning import fit_bin_mapper
 from .booster import Booster
@@ -199,7 +199,12 @@ class LightGBMParams(HasFeaturesCol, HasLabelCol, HasPredictionCol,
         "Exclusive Feature Bundling (LightGBM enable_bundle): merge "
         "mutually-exclusive sparse features (one-hot blocks) into single "
         "bundle columns so histogram work scales with bundles, not "
-        "features.  Off by default; serial gbdt/rf/multiclass only",
+        "features.  The table is bundled once, at binning time, from a "
+        "dense or a sparse (SparseColumn) feature column, and at "
+        "maxConflictRate 0 no row loses a value.  Off by default; applies "
+        "to numeric tables of at most 256 bins under any boosting type, "
+        "one device or a data mesh (not with a ranker, categorical slots, "
+        "feature shards, or voting, goss or dart on a mesh)",
         default=False, typeConverter=TypeConverters.toBool)
     maxConflictRate = Param(
         "maxConflictRate",
@@ -307,7 +312,9 @@ class LightGBMBase(Estimator, LightGBMParams):
         return self._val_metric()
 
     def _fit(self, table: DataTable) -> "LightGBMModelBase":
-        X = features_matrix(table, self.getFeaturesCol())
+        # a sparse vector column stays sparse: it is binned, and with
+        # enableBundle bundled, from its entries
+        X = features_matrix(table, self.getFeaturesCol(), sparse=True)
         y = self._prepare_labels(table[self.getLabelCol()])
         n = X.shape[0]
         wcol = self.getWeightCol()
@@ -382,16 +389,16 @@ class LightGBMBase(Estimator, LightGBMParams):
                     f"initModelPath model was trained on "
                     f"{init_booster.max_feature_idx + 1} features, "
                     f"this table has {X.shape[1]}")
-            margins = np.asarray(init_booster.predict_margin(X_train),
-                                 np.float64)
+            margins = np.asarray(init_booster.predict_margin(
+                _dense_rows(X_train)), np.float64)
             init_scores = (margins if init_scores is None
                            else init_scores + margins)
             if has_val:
                 # validation margins seed the val scores too (LightGBM's
                 # init_model seeds valid sets): early stopping decides on
                 # the MERGED model's trajectory, not the residual's
-                val_init_scores = np.asarray(
-                    init_booster.predict_margin(X[val_mask]), np.float64)
+                val_init_scores = np.asarray(init_booster.predict_margin(
+                    _dense_rows(X[val_mask])), np.float64)
         ranking_info = self._ranking_info(table, train_idx)
         mesh = getattr(self, "_mesh", None)
         mesh_multi = mesh is not None and int(np.prod(
@@ -420,7 +427,23 @@ class LightGBMBase(Estimator, LightGBMParams):
                 from .distributed import resolve_mesh
                 mesh = resolve_mesh(self.getParallelism())
 
-        bins = mapper.transform_packed(X_train)
+        # binning, and bundling with it: once, here (gbdt/efb.py)
+        from .efb import bundle_for_training, bundling_applies
+        sparse = isinstance(X_train, SparseColumn)
+        binned = (mapper.bin_entries(X_train) if sparse
+                  else mapper.transform_packed(X_train))
+        bins = None
+        if bundling_applies(
+                mapper, params.enable_bundle,
+                ranker=grad_override is not None or ranking_info is not None,
+                mesh=mesh, voting=params.parallelism == "voting",
+                goss=params.boosting == "goss",
+                dart=params.boosting == "dart"):
+            bins = bundle_for_training(
+                binned, mapper, params.max_conflict_rate, params.seed,
+                params.verbosity)
+        if bins is None:
+            bins = binned.toarray() if sparse else binned
 
         val_kwargs = {}
         if has_val:
@@ -448,6 +471,12 @@ class LightGBMBase(Estimator, LightGBMParams):
         model.setParams(**{k: v for k, v in self._iterSetParams()
                            if model.hasParam(k)})
         return model
+
+
+def _dense_rows(X):
+    """Raw rows as the forest walk reads them (a continued fit's init
+    margins): a sparse column written out."""
+    return X.toarray() if isinstance(X, SparseColumn) else X
 
 
 class LightGBMModelBase(Model, HasFeaturesCol, HasPredictionCol):

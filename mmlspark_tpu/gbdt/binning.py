@@ -25,11 +25,78 @@ from typing import List, Optional
 
 import numpy as np
 
+from ..core.schema import SparseColumn
+
 #: a categorical column whose binned values stay under this is binned by
 #: table lookup (64 M int32 slots at most), above it by binary search
 _CAT_LUT_MAX = 1 << 26
 #: rows a thread bins at a time in ``transform_packed``'s categorical pass
 _CAT_BLOCK_ROWS = 1 << 20
+#: rows a thread takes at a time over a sparse column's entries
+_SPARSE_BLOCK_ROWS = 1 << 18
+
+
+def _row_blocks(fn, rows: int, block: int = _SPARSE_BLOCK_ROWS) -> list:
+    """``fn(a, b)`` over blocks of rows, in threads (numpy's passes over
+    a block release the lock); the results in block order."""
+    starts = range(0, rows, block)
+    with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
+        return list(pool.map(
+            lambda a: fn(a, min(a + block, rows)), starts))
+
+
+@dataclass
+class SparseBins:
+    """A binned table kept sparse: entry ``k`` says that row
+    ``row_ids()[k]`` holds bin ``bins[k]`` in column ``indices[k]``; a
+    cell no entry names holds ``implicit_bin[column]`` (a sparse
+    column's zeros).  What :meth:`BinMapper.bin_entries` makes of a
+    :class:`SparseColumn`, and what ``gbdt/efb.py`` bundles from."""
+
+    indptr: np.ndarray         # (rows + 1,) int64
+    indices: np.ndarray        # (nnz,) column of each entry
+    bins: np.ndarray           # (nnz,) bin of each entry
+    implicit_bin: np.ndarray   # (f,) bin of a cell without entry
+    shape: tuple
+
+    @classmethod
+    def from_dense(cls, bins: np.ndarray) -> "SparseBins":
+        """Dense ``(n, f)`` bins as entries: a column's most frequent
+        bin is left implicit, every other cell is an entry."""
+        bins = np.asarray(bins)
+        n, f = bins.shape
+        implicit = np.asarray(
+            [np.bincount(bins[:, j].astype(np.int64)).argmax()
+             for j in range(f)], bins.dtype) if n else np.zeros(f, bins.dtype)
+        cells = np.flatnonzero(bins != implicit[None, :])
+        indptr = np.zeros(n + 1, np.int64)
+        np.cumsum(np.bincount(cells // max(f, 1), minlength=n),
+                  out=indptr[1:])
+        return cls(indptr, (cells % max(f, 1)).astype(np.int32),
+                   bins.reshape(-1)[cells], implicit, (n, f))
+
+    def row_ids(self) -> np.ndarray:
+        """The row of every entry, ``(nnz,)``."""
+        return np.repeat(np.arange(self.shape[0]), np.diff(self.indptr))
+
+    def column_entries(self) -> np.ndarray:
+        """Entries per column, ``(f,)``; counted once."""
+        if getattr(self, "_per_column", None) is None:
+            self._per_column = np.bincount(self.indices,
+                                           minlength=self.shape[1])
+        return self._per_column
+
+    def take_rows(self, idx: np.ndarray) -> "SparseBins":
+        picked = SparseColumn(self.indptr, self.indices, self.bins,
+                              self.shape)[idx]
+        return SparseBins(picked.indptr, picked.indices, picked.values,
+                          self.implicit_bin, picked.shape)
+
+    def toarray(self) -> np.ndarray:
+        out = np.empty(self.shape, self.bins.dtype)
+        out[:] = self.implicit_bin[None, :]
+        out[self.row_ids(), self.indices] = self.bins
+        return out
 
 
 @dataclass
@@ -149,6 +216,10 @@ class BinMapper:
         tests/test_gbdt.py's packed-parity test.
         """
         dt = self.bin_dtype
+        if isinstance(X, SparseColumn):
+            # the unbundled table of a sparse column is dense: (n, f)
+            # bins (gbdt/efb.py writes the bundled one from the entries)
+            return self.bin_entries(X).toarray()
         if dt != np.uint8 or X.dtype not in (np.float32, np.float64):
             # > 256 total bins (or exotic dtypes): torch's batched
             # searchsorted still beats the per-column numpy loop
@@ -178,6 +249,57 @@ class BinMapper:
             with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
                 list(pool.map(block, range(0, X.shape[0], _CAT_BLOCK_ROWS)))
         return out
+
+    def bin_entries(self, X: SparseColumn) -> SparseBins:
+        """The bin of every entry of a sparse column and of its zeros,
+        by :meth:`transform`'s rule (the first bound ``>= v``, float64
+        compares; NaN to the missing bin): no dense array is made.
+        Blocks of rows in threads; a column of one bound is one compare
+        an entry, any other a binary search over the padded bounds."""
+        if X.shape[1] != self.num_features:
+            raise ValueError(
+                f"Expected {self.num_features} features, got {X.shape[1]}")
+        if self.has_categorical:
+            raise NotImplementedError(
+                "a sparse feature column with categorical slots is not "
+                "supported: pass dense rows")
+        f = self.num_features
+        nb = np.asarray([len(ub) for ub in self.upper_bounds], np.int32)
+        m = int(nb.max()) + 1 if f else 1
+        bext = np.full((f, m), np.inf, np.float64)
+        for j, ub in enumerate(self.upper_bounds):
+            bext[j, :len(ub)] = ub
+        flat = bext.reshape(-1)
+        steps = int(m).bit_length()
+        dt = self.bin_dtype
+        out = np.empty(X.nnz, dt)
+
+        def block(a, b):
+            lo_e, hi_e = X.indptr[a], X.indptr[b]
+            cols = X.indices[lo_e:hi_e]
+            vals = X.values[lo_e:hi_e].astype(np.float64)
+            nbc = nb[cols]
+            res = (flat[cols.astype(np.int64) * m] < vals).astype(np.int32)
+            deep = np.flatnonzero(nbc > 1)
+            if deep.size:
+                c = cols[deep].astype(np.int64) * m
+                v = vals[deep]
+                lo = np.zeros(deep.size, np.int64)
+                hi = nbc[deep].astype(np.int64)
+                for _ in range(steps):
+                    mid = (lo + hi) >> 1
+                    go = flat[c + mid] < v
+                    lo = np.where(go, mid + 1, lo)
+                    hi = np.where(go, hi, mid)
+                res[deep] = lo
+            res[np.isnan(vals)] = self.missing_bin
+            out[lo_e:hi_e] = res
+
+        _row_blocks(block, X.shape[0])
+        implicit = np.asarray(
+            [np.searchsorted(ub, 0.0, side="left")
+             for ub in self.upper_bounds], dt)
+        return SparseBins(X.indptr, X.indices, out, implicit, X.shape)
 
     def _transform_torch(self, X: np.ndarray, dt: np.dtype) -> np.ndarray:
         """Batched float64 searchsorted via torch — the fallback when the
@@ -347,6 +469,15 @@ def fit_bin_mapper(X: np.ndarray, max_bin: int = 255,
         sample = X[idx]
     else:
         sample = X
+    if isinstance(X, SparseColumn):
+        if categorical_features:
+            raise NotImplementedError(
+                "a sparse feature column with categorical slots is not "
+                "supported: pass dense rows")
+        from ..core.profiler import get_profiler
+        with get_profiler().region("bin.sparse_fit", rows=int(n),
+                                   nnz=int(X.nnz), bytes=int(X.nbytes)):
+            return _fit_sparse(sample, max_bin, min_data_in_bin)
     cat_set = set(int(c) for c in (categorical_features or []))
     for c in cat_set:
         if not 0 <= c < f:
@@ -375,6 +506,33 @@ def fit_bin_mapper(X: np.ndarray, max_bin: int = 255,
                      cat_values=cat_values if cat_set else None)
 
 
+def _fit_sparse(sample: SparseColumn, max_bin: int,
+                min_data_in_bin: int) -> BinMapper:
+    """:func:`fit_bin_mapper` on a sparse row sample: a column's bounds
+    from its entries and the count of its zeros, never its dense
+    column.  The same bounds as the dense rows give."""
+    sn, f = sample.shape
+    order = np.argsort(sample.indices, kind="stable")
+    vals = sample.values[order]
+    starts = np.zeros(f + 1, np.int64)
+    np.cumsum(np.bincount(sample.indices, minlength=f), out=starts[1:])
+    bounds: List[np.ndarray] = []
+    has_missing = np.zeros(f, dtype=bool)
+    for j in range(f):
+        col = vals[starts[j]:starts[j + 1]]
+        zeros = sn - col.size
+        nan = np.isnan(col)
+        if nan.any():
+            has_missing[j] = True
+            col = col[~nan]
+        bounds.append(_bounds_of_sorted(np.sort(col), zeros, max_bin,
+                                        min_data_in_bin))
+    num_total_bins = max_bin + 1
+    return BinMapper(upper_bounds=bounds, has_missing=has_missing,
+                     num_total_bins=num_total_bins,
+                     missing_bin=num_total_bins - 1)
+
+
 def _find_categories(col: np.ndarray, max_bin: int, j: int) -> np.ndarray:
     if col.size and (col < 0).any():
         raise ValueError(
@@ -396,22 +554,45 @@ def _find_bounds(col: np.ndarray, max_bin: int,
     feature; on this box's single core that was ~40% of fit_bin_mapper).
     The quantile lerp reproduces ``np.quantile(..., method="linear")``
     bit-exactly, including its ``t >= 0.5`` rearrangement."""
-    if col.size == 0:
+    return _bounds_of_sorted(np.sort(col), 0, max_bin, min_data_in_bin)
+
+
+def _bounds_of_sorted(s: np.ndarray, zeros: int, max_bin: int,
+                      min_data_in_bin: int) -> np.ndarray:
+    """Bounds of the column whose values are ``s`` (ascending) and
+    ``zeros`` more zeros that ``s`` leaves out (a sparse column's; a
+    dense column's ``s`` is all of it).  The zeros are counted where
+    they sort, never written."""
+    size = s.size + zeros
+    if size == 0:
         return np.empty(0, dtype=np.float64)
-    s = np.sort(col)
-    change = np.empty(s.size, bool)
-    change[0] = True
-    np.not_equal(s[1:], s[:-1], out=change[1:])
-    starts = np.nonzero(change)[0]
-    if starts.size <= 1:
-        return np.empty(0, dtype=np.float64)
-    if starts.size <= max_bin:
-        # Exact: midpoints between consecutive distinct values, but respect
-        # min_data_in_bin by merging tiny bins (LightGBM does the same).
+    if s.size:
+        change = np.empty(s.size, bool)
+        change[0] = True
+        np.not_equal(s[1:], s[:-1], out=change[1:])
+        starts = np.nonzero(change)[0]
         distinct = s[starts]
         counts = np.diff(np.append(starts, s.size))
+    else:
+        distinct = np.empty(0, s.dtype)
+        counts = np.empty(0, np.int64)
+    # where the run of implicit zeros sits among the sorted values
+    below = int(np.searchsorted(s, 0, side="left"))
+    if zeros:
+        at = int(np.searchsorted(distinct, 0, side="left"))
+        if at < distinct.size and distinct[at] == 0:
+            counts = counts.copy()
+            counts[at] += zeros
+        else:
+            distinct = np.insert(distinct, at, 0)
+            counts = np.insert(counts, at, zeros)
+    if distinct.size <= 1:
+        return np.empty(0, dtype=np.float64)
+    if distinct.size <= max_bin:
+        # Exact: midpoints between consecutive distinct values, but respect
+        # min_data_in_bin by merging tiny bins (LightGBM does the same).
         mids = (distinct[:-1] + distinct[1:]) / 2.0
-        if min_data_in_bin > 1 and col.size >= 2 * min_data_in_bin:
+        if min_data_in_bin > 1 and size >= 2 * min_data_in_bin:
             keep, acc = [], 0
             for i in range(len(mids)):
                 acc += counts[i]
@@ -420,13 +601,23 @@ def _find_bounds(col: np.ndarray, max_bin: int,
                     acc = 0
             mids = np.asarray(keep, dtype=np.float64)
         return np.asarray(mids, dtype=np.float64)
+
+    def at_rank(k):
+        """Value ``k`` of the whole sorted column."""
+        if not zeros:
+            return s[k]
+        k = np.asarray(k)
+        return np.where(k < below, s[np.minimum(k, s.size - 1)],
+                        np.where(k < below + zeros, s.dtype.type(0),
+                                 s[np.clip(k - zeros, 0, s.size - 1)]))
+
     # Quantile spacing over the empirical distribution.
     qs = np.linspace(0, 1, max_bin + 1)[1:-1]
-    pos = qs * (s.size - 1)
+    pos = qs * (size - 1)
     lo = pos.astype(np.int64)
     frac = pos - lo
-    a = s[lo]
-    b = s[np.minimum(lo + 1, s.size - 1)]
+    a = at_rank(lo)
+    b = at_rank(np.minimum(lo + 1, size - 1))
     # np.quantile's _lerp: the diff stays in the COLUMN dtype, the lerp
     # itself promotes to float64 — fuzz-verified bit-exact for f32 and f64
     # columns (a pure-f64 lerp differs in the low bits on f32 columns)

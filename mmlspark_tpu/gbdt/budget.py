@@ -16,8 +16,9 @@ formulation its one-hot temporaries, the Mosaic build (PR 28) only the
 bucket's transposed copy.  Against the peaks measured on a v5e it reads
 0.98x at 400 000 x 2000 and 0.96x at 1 183 747 x 968 with the kernel
 (1.03x and 0.99x of the older peaks with XLA's build), and 1.48x at
-30 000 000 x 39, where XLA keeps no second copy of a narrow table
-(PERF.md; tests/test_budget.py holds the cells).  It deliberately over-counts
+30 000 000 x 39, where XLA keeps no second copy of a narrow table, and
+1.02x at 13 184 290 rows bundled into 90 columns with the cache 4228
+features wide (PERF.md; tests/test_budget.py holds the cells).  It deliberately over-counts
 slightly (gradients and their gh-stack both appear) — a guard that errs
 a few percent high beats an OOM at iteration 40.
 """
@@ -45,7 +46,8 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
                        bagging: bool = False, n_val_local: int = 0,
                        min_bucket: int = 2048,
                        hist_on_chip: bool = False,
-                       rank_layout_bytes: int = 0) -> Dict[str, int]:
+                       rank_layout_bytes: int = 0,
+                       num_bundles: Optional[int] = None) -> Dict[str, int]:
     """Per-device resident-bytes breakdown for one training fit.
 
     ``n_local``: this device's row count (global rows / data-mesh size).
@@ -53,10 +55,15 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
     build that keeps its one-hots in VMEM (``grower.
     hist_build_schedule``).  ``rank_layout_bytes``: a ranker's query
     layout as uploaded (``ranking.QueryLayout``; 0 for any other fit).
+    ``num_bundles``: the columns of a table bundled at binning time
+    (``gbdt/efb.py``): the table, its copies and the bucket are that
+    wide, the per-leaf cache stays ``num_features`` wide, and each
+    histogram is expanded to features beside its bundle-space build.
     Returns a dict of named costs plus ``"total"``.
     """
-    n, f, B, L, K, C = (n_local, num_features, num_bins, num_leaves,
-                        num_class, chunk)
+    n, B, L, K, C = (n_local, num_bins, num_leaves, num_class, chunk)
+    # ``f``: the table's columns; the cache's are ``num_features``
+    f = num_features if num_bundles is None else num_bundles
     costs: Dict[str, int] = {}
     costs["bins"] = n * f * bin_itemsize
     # the (f, n) transposed copy the scans keep for split-column reads
@@ -66,7 +73,13 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
     # grad/hess (n, K) each + the (n, 3) gh stack the grower consumes
     costs["gradients"] = n * 4 * (2 * K + 3)
     # per-leaf histogram state: (L, f, B, 3) f32
-    costs["leaf_hist"] = L * f * B * 3 * 4
+    costs["leaf_hist"] = L * num_features * B * 3 * 4
+    if num_bundles is not None:
+        # the plan's gather map and mask, and the expansion's three
+        # (features x bins, 3) arrays (gathered, masked, transposed),
+        # whose 3 channels the TPU lays out padded to a lane tile of 128
+        # (0.55 GB each at 4228 features: PERF.md Findings, PR 33)
+        costs["bundle_expand"] = num_features * B * (5 + 3 * 128 * 4)
     # largest compaction bucket: one (2^ceil(lg n), f) bins gather plus
     # its (size, 3) gh gather — the transient peak of _segment_hist
     n_pow = 1 << (n - 1).bit_length() if n > 1 else 1
@@ -125,7 +138,8 @@ def check_fit_budget(n_local: int, num_features: int, num_bins: int,
                      n_val_local: int = 0, data_shards: int = 1,
                      verbosity: int = 1,
                      hist_on_chip: bool = False,
-                     rank_layout_bytes: int = 0) -> Dict[str, int]:
+                     rank_layout_bytes: int = 0,
+                     num_bundles: Optional[int] = None) -> Dict[str, int]:
     """Estimate, log, and fail FAST when the fit cannot fit.
 
     Raises ``MemoryError`` with the breakdown and concrete remediations
@@ -135,7 +149,7 @@ def check_fit_budget(n_local: int, num_features: int, num_bins: int,
     costs = estimate_fit_bytes(
         n_local, num_features, num_bins, num_leaves, num_class, chunk,
         bin_itemsize, bagging, n_val_local, hist_on_chip=hist_on_chip,
-        rank_layout_bytes=rank_layout_bytes)
+        rank_layout_bytes=rank_layout_bytes, num_bundles=num_bundles)
     cap = device_capacity_bytes()
     if verbosity > 0:
         import logging

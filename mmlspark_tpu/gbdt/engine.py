@@ -1467,28 +1467,6 @@ def _truncate_no_growth(host_trees: List[HostTree], nls: np.ndarray, K: int,
 
 
 
-def _build_efb(bins, mapper, params, f, verbosity_tag=""):
-    """Shared EFB setup: plan bundles, build the device expansion maps and
-    the bundled host matrix.  Returns ``(efb_dev, efb_host, bundled)`` or
-    ``(None, None, None)`` when bundling is trivial — callers decide the
-    path-specific gate conditions."""
-    from .efb import bundle_matrix, expansion_arrays, find_bundles
-    nb_list = [mapper.feature_num_bins(j) for j in range(f)]
-    spec = find_bundles(np.asarray(bins), nb_list, mapper.missing_bin,
-                        params.max_conflict_rate,
-                        max_bundle_bins=mapper.num_total_bins,
-                        seed=params.seed)
-    if spec.is_trivial:
-        return None, None, None
-    efb_host = expansion_arrays(spec, mapper.num_total_bins,
-                                mapper.missing_bin)
-    bundled = bundle_matrix(np.asarray(bins), spec, mapper.missing_bin)
-    if params.verbosity > 0:
-        log.info("EFB%s: %d features -> %d bundle columns",
-                 verbosity_tag, f, spec.num_bundles)
-    return _efb_dev_from_host(efb_host), efb_host, bundled
-
-
 def _efb_dev_from_host(efb_host):
     """Upload the six EFB map arrays (dtypes pinned so a replay re-upload
     never retraces)."""
@@ -1609,6 +1587,18 @@ def _representative_table(mapper: BinMapper) -> np.ndarray:
     return table.astype(np.float32)
 
 
+@functools.partial(jax.jit, static_argnames=("missing_bin",))
+def _decode_bundled_rows(sample_b, efb, missing_bin: int):
+    """Bundled rows ``(r, G)`` to their ``(r, f)`` bins: the decode of
+    ``grower.efb_feature_column`` for every feature at once."""
+    raw = (jnp.take(sample_b, efb.bundle_of, axis=1).astype(jnp.int32)
+           - efb.off_of[None, :])
+    inr = (raw >= 0) & (raw <= efb.nb_of[None, :])
+    return jnp.where(inr, jnp.where(raw == efb.nb_of[None, :], missing_bin,
+                                    raw),
+                     efb.default_of[None, :]).astype(sample_b.dtype)
+
+
 @jax.jit
 def _representative_rows(sample, table):
     """``out[r, j] = table[j, sample[r, j]]``: binned rows to their
@@ -1633,46 +1623,71 @@ def _capture_reference_profile(booster: Booster, bins, mapper,
     span, ``rows`` being the rows sketched and ``counts`` where they were
     counted: ``device`` when the fit left ``device_table`` (see
     :func:`_train_impl`), whose table is counted there and released,
-    ``host`` when ``bins`` is counted column by column."""
+    ``host`` when ``bins`` is counted column by column.  A bundled fit
+    (``bins`` a ``BundledTable``) counts its ``(n, G)`` table, expands
+    the counts to features by the map ``grower._efb_expand`` gathers by,
+    and decodes the sampled rows as ``grower.efb_feature_column`` does:
+    the same profile as the unbundled table's."""
     if os.environ.get(REF_PROFILE_ENV, "1") == "0" or mapper is None:
         return
     with get_profiler().region("train.reference_profile",
                                rows=0, counts="host") as sp:
         try:
             from ..core.sketch import build_reference_profile
-            if isinstance(bins, (list, tuple)):
-                bins = np.concatenate([np.asarray(b) for b in bins], axis=0)
-            bins = np.asarray(bins)
-            if bins.ndim != 2 or bins.shape[1] != mapper.num_features:
+            from .efb import BundledTable, decode_rows, expand_counts
+            bundled = bins if isinstance(bins, BundledTable) else None
+            if bundled is None:
+                if isinstance(bins, (list, tuple)):
+                    bins = np.concatenate([np.asarray(b) for b in bins],
+                                          axis=0)
+                bins = np.asarray(bins)
+            if len(bins.shape) != 2 or bins.shape[1] != mapper.num_features:
                 return
             n, f = bins.shape
             sp["rows"] = int(n)
+            # the host rows as the fit uploaded them: (n, G) when bundled
+            rows_h = bins if bundled is None else bundled.table
             counts_d = None
             if device_table:
                 counts_d = _table_bin_counts(
                     device_table.pop("bins"), mapper.num_total_bins,
                     device_table.get("mesh"))
-            sample = bins
+            sample = rows_h
             if n > _REF_PROFILE_MARGIN_ROWS:
                 idx = np.random.default_rng(0).choice(
                     n, size=_REF_PROFILE_MARGIN_ROWS, replace=False)
                 idx.sort()
-                sample = bins[idx]
+                sample = rows_h[idx]
             fine_counts = None
             if counts_d is not None:
                 # the wait is the capture's; with it the fit's table
                 # leaves the device, before the sampled rows arrive
-                fine_counts = np.asarray(counts_d)[:f].astype(np.int64)
+                fine_counts = np.asarray(counts_d)[:rows_h.shape[1]].astype(
+                    np.int64)
                 fine_counts[:, 0] -= device_table["pad_rows"]
                 sp["counts"] = "device"
+            elif bundled is not None:
+                fine_counts = np.stack([
+                    np.bincount(rows_h[:, g],
+                                minlength=mapper.num_total_bins)
+                    for g in range(rows_h.shape[1])])
+            if bundled is not None:
+                fine_counts = expand_counts(fine_counts, bundled.maps(), n)
             table = _representative_table(mapper)
             if jax.default_backend() == "cpu":
                 # predict_margin walks numpy rows natively there
+                if bundled is not None:
+                    sample = decode_rows(sample, bundled.maps(),
+                                         mapper.missing_bin)
                 Xr = table[np.arange(f), sample]
             else:
-                Xr = _representative_rows(
-                    jnp.asarray(sample, mapper.bin_dtype),
-                    jnp.asarray(table))
+                sample_d = jnp.asarray(sample, mapper.bin_dtype)
+                if bundled is not None:
+                    sample_d = _decode_bundled_rows(
+                        sample_d, (device_table or {}).get("efb")
+                        or _efb_dev_from_host(bundled.maps()),
+                        mapper.missing_bin)
+                Xr = _representative_rows(sample_d, jnp.asarray(table))
             margins = np.asarray(
                 _bin_space_forest(booster, mapper).predict_margin(Xr))
             booster.reference_profile = build_reference_profile(
@@ -1682,7 +1697,7 @@ def _capture_reference_profile(booster: Booster, bins, mapper,
                       "fit_span": _tm.current_fit_span()},
                 fine_counts=fine_counts)
             train_stats.incr("ref_profiles")
-            if fine_counts is not None:
+            if counts_d is not None:
                 train_stats.incr("ref_profiles_device")
         except Exception:  # noqa: BLE001 - the profile is advisory
             log.exception("reference-profile capture failed; drift "
@@ -1760,7 +1775,12 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
     three.  A fit whose gradient was a ranker's query layout
     (``ranking.LambdarankGrad``) says how many queries and size classes
     it held and, times the trees, the pairs of the queries' exact sizes
-    and the pair slots its programs computed."""
+    and the pair slots its programs computed.  ``hist_cache_bytes`` is a
+    device's per-leaf histogram cache, in feature space whatever the
+    table; a fit on a table bundled at binning time (``gbdt/efb.py``)
+    also says its features, bundle columns, the bundled table's bytes and
+    the rows that lost a value to a conflict (0 at ``maxConflictRate``
+    0); any other fit has none of the four."""
     shards = bins if isinstance(bins, (list, tuple)) else [bins]
     shapes = [np.shape(b) for b in shards if b is not None]
     trees = len(booster.trees)
@@ -1777,7 +1797,10 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
         "collective_bytes": per_tree("collective_payload_bytes_per_tree"),
         "hist_build": last_fit_info.get("hist_build", ""),
         "hist_build_rungs": last_fit_info.get("hist_build_rungs", ""),
+        "hist_cache_bytes": int(last_fit_info.get("hist_cache_bytes", 0)),
     }
+    attrs.update({k: int(v) for k, v in last_fit_info.items()
+                  if k.startswith("efb_")})
     if "rank_queries" in last_fit_info:
         attrs.update(
             rank_queries=int(last_fit_info["rank_queries"]),
@@ -1895,11 +1918,17 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
     init scores, goss, rf, dart and lambdarank — for ranking each
     query's rows must live on one shard).
 
+    ``bins`` may be a ``BundledTable`` (``gbdt/efb.py``: the table
+    bundled at binning time, with its plan): its ``(n, G)`` table is
+    what goes to the device, histograms expand to original features
+    through the plan's maps, and nothing is bundled here.
+
     ``device_table``: out, for :func:`train`'s reference profile.  A fit
-    that uploaded ``bins`` as it is (one host table, not bundled: here,
-    and ``_train_distributed`` through ``prepare_arrays``) leaves the
-    device array under ``bins``, its ``pad_rows`` (bin 0 of every
-    feature) and, on a mesh, the ``mesh``; any other fit leaves it empty.
+    that uploaded one host table (here, and ``_train_distributed``
+    through ``prepare_arrays``) leaves the device array under ``bins``,
+    its ``pad_rows`` (bin 0 of every column), a bundled table's maps
+    under ``efb`` (one device) and, on a mesh, the ``mesh``; any other
+    fit leaves it empty.
     """
     if isinstance(bins, (list, tuple)):
         return _train_distributed_sharded(
@@ -1917,6 +1946,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             "(ranking.make_lambdarank_grad_fn), whose query layout the "
             "compiled programs take as an argument; got "
             f"{type(grad_fn_override).__name__}")
+    from .efb import BundledTable, bundling_applies
+    bundled = bins if isinstance(bins, BundledTable) else None
     n, f = bins.shape
     K = objective.num_model_per_iteration
     rng = np.random.default_rng(params.seed)
@@ -1954,6 +1985,16 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
     hist_sched = _hist_sched_for(cfg, mesh, n)
     _record_fit_resolution(cfg, collective, coll_downgrade, coll_sched,
                            quantized_downgrade=qdown, hist_sched=hist_sched)
+    from ..core.mesh import FEATURE_AXIS
+    _fs = int(dict(mesh.shape).get(FEATURE_AXIS, 1)) if mesh is not None \
+        else 1
+    last_fit_info.update(hist_cache_bytes=str(
+        cfg.num_leaves * -(-f // _fs) * cfg.num_bins * 3 * 4))
+    if bundled is not None:
+        last_fit_info.update(
+            efb_features=str(f), efb_bundles=str(bundled.table.shape[1]),
+            efb_table_bytes=str(bundled.table.nbytes),
+            efb_conflict_rows=str(bundled.conflict_rows))
 
     if params.boosting not in ("gbdt", "goss", "dart", "rf"):
         raise NotImplementedError(
@@ -2003,6 +2044,13 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
 
     use_mesh = mesh is not None and int(np.prod(
         [mesh.shape[a] for a in mesh.axis_names])) > 1
+    if bundled is not None and not bundling_applies(
+            mapper, True, ranker=grad_fn_override is not None
+            or ranking_info is not None, mesh=mesh if use_mesh else None,
+            voting=use_voting, goss=use_goss, dart=use_dart):
+        raise ValueError(
+            "this fit cannot take a bundled table (efb.bundling_applies "
+            "says where bundles apply): hand it the unbundled bins")
     # scale guard (BASELINE config 5): estimate per-device HBM before the
     # first compile and fail fast with remediation if the fit can't fit
     from .budget import check_fit_budget
@@ -2023,6 +2071,7 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         _chunk = min(_chunk, max(1, params.checkpoint_chunk))
     check_fit_budget(
         n_local=-(-n // _dn), num_features=f,
+        num_bundles=bundled.table.shape[1] if bundled is not None else None,
         num_bins=mapper.num_total_bins, num_leaves=params.num_leaves,
         num_class=K, chunk=_chunk,
         bin_itemsize=np.dtype(mapper.bin_dtype).itemsize,
@@ -2073,19 +2122,17 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             callbacks=callbacks, val_init_scores=val_init_scores,
             device_table=device_table)
 
-    # Exclusive Feature Bundling (serial paths; uint8 bins only — a
-    # bundle's encoded width is capped at num_total_bins).  goss/dart
+    # Exclusive Feature Bundling: a table bundled at binning time
+    # (gbdt/efb.py) goes up as it is, with its plan's maps.  goss/dart
     # score the bundled TRAINING matrix through the EFB-aware walk
     # (predict_tree_binned_efb decodes each level's bundle column back
-    # to the node's original feature); a ranker's fit
-    # (grad_fn_override) stays unbundled.
+    # to the node's original feature).
     efb_dev = None
     bins_host_final = bins
-    if params.enable_bundle and not mapper.has_categorical \
-            and mapper.num_total_bins <= 256 and grad_fn_override is None:
-        efb_dev, efb_host, bundled = _build_efb(bins, mapper, params, f)
-        if efb_dev is not None:
-            bins_host_final = bundled
+    if bundled is not None:
+        efb_host = bundled.maps()
+        efb_dev = _efb_dev_from_host(efb_host)
+        bins_host_final = bundled.table
     with get_profiler().region("train.upload") as sp:
         bins_d = jnp.asarray(bins_host_final, mapper.bin_dtype)
         labels_d = jnp.asarray(labels,
@@ -2184,8 +2231,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
     if ckpt:
         # bounded chunks = bounded lost work after a process death
         chunk = min(chunk, max(1, params.checkpoint_chunk))
-        ckpt_fp = _ckpt_fingerprint(n, f, K, params, labels, bins, w,
-                                    init_scores)
+        ckpt_fp = _ckpt_fingerprint(n, f, K, params, labels,
+                                    bins_host_final, w, init_scores)
 
     trees_chunks: List[TreeArrays] = []
     stop_iter = T
@@ -2458,8 +2505,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         if ckpt:
             _ckpt_clear(ckpt)
 
-    if device_table is not None and efb_dev is None:
-        device_table.update(bins=bins_d, pad_rows=0)
+    if device_table is not None:
+        device_table.update(bins=bins_d, pad_rows=0, efb=efb_dev)
     return _export_booster(trees_chunks, K, stop_iter, init, params,
                            objective, mapper, feature_names, f,
                            dart_scales=scales if use_dart else None,
@@ -3154,6 +3201,10 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
         n_padded = len(sizes) * S_sh
     else:
         n, f = bins.shape
+    from .efb import BundledTable
+    bundled = None
+    if isinstance(bins, BundledTable):      # bundled at binning time
+        bundled, bins = bins, bins.table
     K = objective.num_model_per_iteration
     T = params.num_iterations
     esr = params.early_stopping_round
@@ -3192,8 +3243,8 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
                 jax.random.PRNGKey(params.bagging_seed),
                 params.num_iterations)
     # Mesh checkpointing (checkpoint_dir is LIVE here, serial-style):
-    # the fingerprint is computed from the ORIGINAL inputs — before any
-    # EFB rebundling rebinds ``bins`` — plus the mesh topology, so a
+    # the fingerprint is computed from the inputs as given (a bundled
+    # table as bundled) plus the mesh topology, so a
     # resume under a different (process count, shard layout) starts
     # fresh instead of scattering shards wrongly.
     ckpt = params.checkpoint_dir
@@ -3205,25 +3256,17 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
                                          shard_data)
         ckpt_local = _local_bins_digest(shard_data)
 
-    # EFB under a data mesh: one bundling plan from the full host matrix
-    # (columns are global), per-shard bundled rows, shard-local expansion
-    # before the psum.  GOSS scores through the training matrix by
-    # original feature id and a feature-sharded mesh would split bundles,
-    # so both are excluded; voting's shard-local vote scan likewise.
+    # EFB under a data mesh: the one plan made at binning time (columns
+    # are global), per-shard bundled rows, shard-local expansion before
+    # the psum (``efb.bundling_applies``, checked by ``_train_impl``,
+    # keeps goss, voting and feature shards out).
     efb_dev_m, efb_host_m = None, None
-    from .distributed import _feat_n as _feat_shards
     # per-tree collective accounting for the chunk monitor: evaluated on
     # the sharded cfg (axis names attach inside the scan builders)
     coll_sched_m = _collective_sched_for(cfg, mesh, n, f)
-    if params.enable_bundle and not mapper.has_categorical \
-            and mapper.num_total_bins <= 256 \
-            and _feat_shards(mesh) == 1 \
-            and cfg.voting_k == 0 and not use_goss_m \
-            and shard_data is None:  # EFB plans need the full host matrix
-        efb_dev_m, efb_host_m, bundled = _build_efb(
-            bins, mapper, params, f, verbosity_tag=" (mesh)")
-        if efb_dev_m is not None:
-            bins = bundled
+    if bundled is not None:
+        efb_host_m = bundled.maps()
+        efb_dev_m = _efb_dev_from_host(efb_host_m)
 
     def build_step(efb_arg):
         """(Re)build the shard_mapped chunk program — the fault-tolerance
@@ -3550,7 +3593,7 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
         if jax.process_index() == 0:
             _ckpt_clear(ckpt)
 
-    if device_table is not None and efb_dev_m is None \
+    if device_table is not None \
             and shard_data is None and bins_d.is_fully_addressable:
         device_table.update(bins=bins_d, pad_rows=rp, mesh=mesh)
     return _export_booster(chunks, K, stop_iter, init, params, objective,

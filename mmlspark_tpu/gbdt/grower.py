@@ -60,13 +60,14 @@ def _efb_expand(hist_b, efb):
     bins partition every row, so its sum IS the leaf total.
     """
     f = efb.gather_idx.shape[0]
-    flat = hist_b.reshape(-1, hist_b.shape[-1])          # (G*B, 3)
-    hist = jnp.take(flat, efb.gather_idx.reshape(-1), axis=0)
-    hist = hist.reshape(f, hist_b.shape[1], hist_b.shape[2])
-    hist = hist * efb.valid[:, :, None]
-    tot = jnp.sum(hist_b[0], axis=0)                      # (3,) leaf total
-    deficit = tot[None, :] - jnp.sum(hist, axis=1)        # (f, 3)
-    return hist.at[jnp.arange(f), efb.default_of].add(deficit)
+    with jax.named_scope("efb_expand"):
+        flat = hist_b.reshape(-1, hist_b.shape[-1])          # (G*B, 3)
+        hist = jnp.take(flat, efb.gather_idx.reshape(-1), axis=0)
+        hist = hist.reshape(f, hist_b.shape[1], hist_b.shape[2])
+        hist = hist * efb.valid[:, :, None]
+        tot = jnp.sum(hist_b[0], axis=0)                  # (3,) leaf total
+        deficit = tot[None, :] - jnp.sum(hist, axis=1)    # (f, 3)
+        return hist.at[jnp.arange(f), efb.default_of].add(deficit)
 
 
 def efb_feature_column(binsT, feat, efb, num_bins):
@@ -1086,7 +1087,10 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                     0)
                 col = jax.lax.psum(col_local, cfg.feature_axis_name)
             elif efb is not None:
-                col = efb_feature_column(binsT, feat, efb, cfg.num_bins)
+                with jax.named_scope("partition"), \
+                        jax.named_scope("efb_column"):
+                    col = efb_feature_column(binsT, feat, efb,
+                                             cfg.num_bins)
             else:
                 col = jnp.take(binsT, feat, axis=0)
 
@@ -1120,7 +1124,8 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 if efb is not None:
                     # expansion is linear, so it commutes with the
                     # reduction below
-                    hist_small = _efb_expand(hist_small, efb)
+                    with jax.named_scope("segment_hist"):
+                        hist_small = _efb_expand(hist_small, efb)
                 if cfg.axis_name is not None and not _is_voting(cfg):
                     # voting keeps per-leaf histograms local; only voted
                     # candidate slices are reduced inside _find_split

@@ -54,6 +54,23 @@ class TestEstimate:
         assert est["hist_build"] == (bucket * features if on_chip else
                                      min(rows, 8192) * features * 320)
 
+    def test_estimate_against_the_bundled_cells_measured_peak(self):
+        """``allstate_fit`` (my chip run, PR 33): 10 867 609 600 bytes at
+        13 184 290 rows bundled into 90 columns, the per-leaf cache 4228
+        features wide.  Counted 4228 wide the table alone is 55.7 GB;
+        counted 90 wide with a 90-wide cache the estimate reads 0.57 of
+        the peak."""
+        kw = dict(chunk=2, hist_on_chip=True)
+        est = estimate_fit_bytes(13_184_290, 4228, 256, 255,
+                                 num_bundles=90, **kw)
+        assert 0.9 < est["total"] / 10_867_609_600 < 1.1
+        assert est["leaf_hist"] == 255 * 4228 * 256 * 12
+        assert est["bins"] == 13_184_290 * 90
+        narrow = estimate_fit_bytes(13_184_290, 90, 256, 255, **kw)
+        assert narrow["total"] / 10_867_609_600 < 0.6
+        assert estimate_fit_bytes(13_184_290, 4228, 256, 255,
+                                  **kw)["bins"] > 55e9
+
     def test_estimate_against_the_ranking_cells_measured_peak(self):
         """``istella_fit`` (my chip run, PR 31): 8 492 530 176 bytes at
         7 325 625 x 220 with a query layout of 156 560 112 bytes.  Without

@@ -283,14 +283,14 @@ def _one_hot_table(n=4000, groups=3, width=6, seed=1):
 
 
 @pytest.mark.parametrize("case,counts", [
-    ("serial", "device"), ("mesh4", "device"), ("bundled", "host"),
+    ("serial", "device"), ("mesh4", "device"), ("bundled", "device"),
     ("incremental", "host")])
 def test_reference_profile_says_where_its_rows_were_counted(
         fit_inputs, case, counts):
     """``train.reference_profile``'s ``counts``: ``device`` where the fit
-    left the caller's table on the device as it was, ``host`` where the
-    device holds bundles and for a capture that is no fit's own (the
-    merged forest's, after ``train_incremental``'s fit)."""
+    left its table on the device (bundle columns too: their counts
+    are expanded to features), ``host`` for a capture that is no fit's
+    own (the merged forest's, after ``train_incremental``'s fit)."""
     inp = fit_inputs
     stats0 = engine.train_stats.snapshot()["counters"]
     if case == "bundled":
@@ -299,7 +299,9 @@ def test_reference_profile_says_where_its_rows_were_counted(
                                  verbosity=0, enableBundle=True)
         mapper = fit_bin_mapper(X, max_bin=est.getMaxBin(),
                                 seed=est.getSeed())
-        inp = dict(fit_inputs, bins=mapper.transform_packed(X),
+        from mmlspark_tpu.gbdt.efb import bundle_for_training
+        dense = mapper.transform_packed(X)
+        inp = dict(fit_inputs, bins=bundle_for_training(dense, mapper),
                    labels=est._prepare_labels(y), mapper=mapper,
                    params=est._train_params())
     prof = get_profiler()
@@ -320,7 +322,13 @@ def test_reference_profile_says_where_its_rows_were_counted(
     if case == "bundled":
         # fewer bytes than the table has cells: the bundles went up
         upload, = [s for s in new if s["name"] == "train.upload"]
-        assert upload["attrs"]["bytes"] < inp["bins"].size
+        assert upload["attrs"]["bytes"] < dense.size
+        # the profile of the bundled table is the unbundled table's,
+        # which the host counts column by column
+        from mmlspark_tpu.core.sketch import build_reference_profile
+        host = build_reference_profile(dense, mapper)
+        assert booster.reference_profile.feature_sketches \
+            == host.feature_sketches
     spans = [s for s in new if s["name"] == "train.reference_profile"]
     # the incremental fit's own capture, then the merged forest's
     assert [s["attrs"]["counts"] for s in spans] == \
@@ -333,7 +341,7 @@ def test_reference_profile_says_where_its_rows_were_counted(
     assert done == {
         "serial": {"ref_profiles": 1, "ref_profiles_device": 1},
         "mesh4": {"ref_profiles": 1, "ref_profiles_device": 1},
-        "bundled": {"ref_profiles": 1, "ref_profiles_device": 0},
+        "bundled": {"ref_profiles": 1, "ref_profiles_device": 1},
         "incremental": {"ref_profiles": 3, "ref_profiles_device": 2},
     }[case]
 

@@ -185,6 +185,51 @@ def test_boost_scan_at_epsilons_shape_holds_no_one_hot(
     assert compiled.memory_analysis().temp_size_in_bytes < 7.5e9
 
 
+def test_bundled_boost_scan_keeps_one_cache_and_no_feature_wide_rows(
+        v5e, decides_as_on_the_tpu):
+    """The fit's program on a table bundled at binning time, at
+    ``allstate_fit``'s 90 bundle columns and 4228 features (65 536 rows:
+    what is asked scales with columns, not rows): the per-leaf cache
+    stays in FEATURE space, 255 x 4228 x 256 x 3 floats (3.31 GB), and
+    the program must hold it once (no whole copy, temporaries under two
+    of it) and nothing of ``(rows, 4228)``: rows exist only 90 wide.
+    About a minute to compile."""
+    from mmlspark_tpu.core.profiling import (compiled_copies,
+                                             compiled_instructions)
+    from mmlspark_tpu.gbdt import engine
+    from mmlspark_tpu.gbdt.grower import EFBArrays, GrowerConfig
+    from mmlspark_tpu.gbdt.objectives import BinaryObjective
+    n, G, f, B, L = 65_536, 90, 4228, 256, 255
+    one = SingleDeviceSharding(v5e[0])
+    obj = BinaryObjective()
+    obj.prepare(np.zeros(8), np.ones(8))
+    efb = EFBArrays(
+        gather_idx=_sds((f, B), jnp.int32, one),
+        valid=_sds((f, B), jnp.bool_, one),
+        **{k: _sds((f,), jnp.int32, one)
+           for k in ("bundle_of", "off_of", "nb_of", "default_of")})
+    compiled = engine._boost_scan.lower(
+        _sds((n, G), jnp.uint8, one), _sds((n,), jnp.float32, one),
+        _sds((n,), jnp.float32, one), _sds((n,), jnp.float32, one),
+        _sds((2, 1), jnp.float32, one), _sds((2, f, 3), jnp.float32, one),
+        _sds((1, f), jnp.uint8, one), _sds((1,), jnp.float32, one),
+        obj=obj, cfg=GrowerConfig(
+            num_leaves=L, num_bins=B, hist_method="dot16",
+            min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0),
+        lr=0.1, has_val=False, efb=efb).compile()
+    cache = (L, f, B, 3)
+    assert compiled.as_text().count("tpu_custom_call") >= 6
+    assert [c for c in compiled_copies(compiled) if c[1] == cache] == []
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * L * f * B * 3 * 4
+    wide = [(name, shape) for name, shape, _ in compiled_instructions(
+        compiled, min_bytes=2048 * f)
+        if len(shape) >= 2 and f in shape[1:]
+        and max(d for d in shape if d != f) >= 2048 and shape != cache
+        and shape[0] >= 2048]
+    assert wide == []
+
+
 # ------------------- the reference profile's passes over the fit's table
 
 
