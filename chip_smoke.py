@@ -356,7 +356,7 @@ def phase_kernels(cfg, on_tpu, X, y, auc_psum):
     from mmlspark_tpu.core.mesh import DATA_AXIS
     from mmlspark_tpu.gbdt import LightGBMClassifier
     from mmlspark_tpu.gbdt import engine
-    from mmlspark_tpu.gbdt.grower import GrowerConfig, _bucket_sizes
+    from mmlspark_tpu.gbdt.grower import GrowerConfig, _build_sizes
     from mmlspark_tpu.ops.histogram import (compute_histogram,
                                             histogram_build)
     from mmlspark_tpu.ops.pallas_collectives import (ring_allreduce,
@@ -370,7 +370,7 @@ def phase_kernels(cfg, on_tpu, X, y, auc_psum):
     # -- the dot16 build at every shape the flagship grower issues: the
     # bucket ladder and the root's full matrix (on the TPU the Mosaic
     # kernel; XLA's formulation of it in a rehearsal)
-    sizes = _bucket_sizes(rows, GrowerConfig()) + [rows]
+    sizes = _build_sizes(rows, GrowerConfig()) + [rows]
     build = histogram_build("dot16", B, quantized=False)
     say("kernels", f"method dot16 compiles: {build}")
     check(build == "dot16/mosaic" or not on_tpu,

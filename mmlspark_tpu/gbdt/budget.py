@@ -10,14 +10,16 @@ src/treelearner/serial_tree_learner.cpp, UNVERIFIED; SURVEY.md §7.)
 
 The model below counts the resident arrays of one device's shard for the
 dominant training path (the DataPartition grower inside the chunked
-scan), plus the largest transient the bucket-ladder compaction
-materializes and what the MXU histogram build holds beside it: XLA's
-formulation its one-hot temporaries, the Mosaic build (PR 28) only the
-bucket's transposed copy.  Against the peaks measured on a v5e it reads
-0.98x at 400 000 x 2000 and 0.96x at 1 183 747 x 968 with the kernel
-(1.03x and 0.99x of the older peaks with XLA's build), and 1.48x at
-30 000 000 x 39, where XLA keeps no second copy of a narrow table, and
-1.02x at 13 184 290 rows bundled into 90 columns with the cache 4228
+scan), plus the transients of the bucket-ladder compaction (a child's
+rows on the build ladder's top rung, ``grower.SEGMENT_CHUNK_ROWS`` rows
+at most, and the partition's slices of a node at the next power of two)
+and what the MXU histogram build holds beside them: XLA's formulation
+its one-hot temporaries, the Mosaic build (PR 28) only the bucket's
+transposed copy.  Against the peaks measured on a v5e (PR 34) it reads
+0.98x at 400 000 x 2000, 0.98x at 1 183 747 x 968, 0.98x at 7 325 625 x
+220 with a ranker's layout, 1.42x at 30 000 000 x 39, where XLA keeps no
+second copy of a narrow table, and 1.01x at 13 184 290 rows bundled into
+90 columns with the cache 4228
 features wide (PERF.md; tests/test_budget.py holds the cells).  It deliberately over-counts
 slightly (gradients and their gh-stack both appear) — a guard that errs
 a few percent high beats an OOM at iteration 40.
@@ -80,11 +82,17 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
         # whose 3 channels the TPU lays out padded to a lane tile of 128
         # (0.55 GB each at 4228 features: PERF.md Findings, PR 33)
         costs["bundle_expand"] = num_features * B * (5 + 3 * 128 * 4)
-    # largest compaction bucket: one (2^ceil(lg n), f) bins gather plus
-    # its (size, 3) gh gather — the transient peak of _segment_hist
-    n_pow = 1 << (n - 1).bit_length() if n > 1 else 1
-    bucket = max(min_bucket, n_pow)
+    # largest bucket of rows ``_segment_hist`` gathers, its ladder's top
+    # rung (a longer segment is walked in chunks of it): one (rung, f)
+    # bins gather plus its (rung, 3) gh gather
+    from .grower import GrowerConfig, _bucket_sizes, _build_sizes
+    ladder = GrowerConfig(min_bucket=min_bucket)
+    bucket = _build_sizes(n, ladder)[-1]
     costs["bucket_transient"] = bucket * (f * bin_itemsize + 12)
+    # ``_partition_switch`` slices a node at the next power of two: its
+    # row ids, their split-column values, two running counts and the
+    # slots they scatter to, 4 bytes each
+    costs["partition_transient"] = _bucket_sizes(n, ladder)[-1] * 4 * 5
     # the MXU histogram build's temporaries for one chunk of rows: per
     # feature 16 x 3 products in f32 and again as bf16 operands, and the
     # 16-wide one-hot in bf16 (the TPU runtime reserves them with the

@@ -32,7 +32,8 @@ from .booster import Booster, HostTree, host_tree_from_arrays
 from .grower import (EFBArrays, GrowerConfig, TreeArrays, apply_shrinkage,
                      collective_schedule, hist_build_schedule,
                      predict_tree_binned, predict_tree_binned_any,
-                     predict_tree_binned_efb, _grow_tree_impl)
+                     predict_tree_binned_efb, segment_walk_stats,
+                     _grow_tree_impl)
 from .objectives import Objective, MulticlassObjective
 
 
@@ -1763,6 +1764,26 @@ def train(*args, **kwargs) -> Booster:
     return booster
 
 
+def _segment_walk_attrs(trees, n_rows: int, min_bucket: int) -> dict:
+    """``seg_rows``, ``seg_rows_walked`` and ``seg_chunked_nodes`` of a
+    one-device fit (``grower.segment_walk_stats``), from the host trees'
+    node counts: every split partitioned its node's rows and
+    histogrammed its smaller child's (counts are of the rows in the bag,
+    so under bagging or goss they understate the partition's)."""
+    parents, smaller = [], []
+    for t in trees:
+        left, right = (
+            np.where(child >= 0, t.internal_count[np.maximum(child, 0)],
+                     t.leaf_count[np.maximum(~child, 0)])
+            for child in (t.left_child, t.right_child))
+        parents.append(t.internal_count)
+        smaller.append(np.minimum(left, right))
+    return segment_walk_stats(
+        np.concatenate(parents) if parents else [],
+        np.concatenate(smaller) if smaller else [], n_rows,
+        GrowerConfig(min_bucket=min_bucket))
+
+
 def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
     """What the ``train.fit`` span says of its fit: the trees returned,
     the table's shape, the devices it ran on, and the collectives the
@@ -1780,7 +1801,9 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
     table; a fit on a table bundled at binning time (``gbdt/efb.py``)
     also says its features, bundle columns, the bundled table's bytes and
     the rows that lost a value to a conflict (0 at ``maxConflictRate``
-    0); any other fit has none of the four."""
+    0); any other fit has none of the four.  A one-device fit that
+    compacts rows says what its splits asked of the bucket ladders
+    (``_segment_walk_attrs``); a mesh fit has none of the three."""
     shards = bins if isinstance(bins, (list, tuple)) else [bins]
     shapes = [np.shape(b) for b in shards if b is not None]
     trees = len(booster.trees)
@@ -1807,6 +1830,10 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
             rank_size_classes=int(last_fit_info["rank_size_classes"]),
             rank_pairs_useful=per_tree("rank_pairs_useful_per_tree"),
             rank_pairs_computed=per_tree("rank_pairs_computed_per_tree"))
+    if "seg_min_bucket" in last_fit_info:
+        attrs.update(_segment_walk_attrs(
+            booster.trees, attrs["rows"],
+            int(last_fit_info["seg_min_bucket"])))
     if mapper is not None and mapper.has_categorical:
         attrs.update(
             cat_features=int(mapper.categorical.sum()),
@@ -1990,6 +2017,11 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
         else 1
     last_fit_info.update(hist_cache_bytes=str(
         cfg.num_leaves * -(-f // _fs) * cfg.num_bins * 3 * 4))
+    if mesh is None and cfg.compact_rows:
+        # one device: the host knows every segment's rows from the
+        # returned trees' node counts (``_segment_walk_attrs``); a
+        # mesh's shards each hold their own share of a node
+        last_fit_info.update(seg_min_bucket=str(cfg.min_bucket))
     if bundled is not None:
         last_fit_info.update(
             efb_features=str(f), efb_bundles=str(bundled.table.shape[1]),

@@ -33,6 +33,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.histogram import compute_histogram
 
@@ -97,10 +98,11 @@ class GrowerConfig:
     min_gain_to_split: float = 0.0
     hist_method: str = "auto"
     #: histogram only the smaller child's rows, gathered into a power-of-two
-    #: bucket picked by ``lax.switch`` (LightGBM's DataPartition +
-    #: smaller-child trick, re-shaped for static-shape jit); the sibling
-    #: comes from subtraction.  ~L full-data scans per tree become ~2-3
-    #: full-data equivalents.  Disable to force full masked scans.
+    #: bucket picked by ``lax.switch`` or, over ``SEGMENT_CHUNK_ROWS`` rows,
+    #: chunk by chunk (LightGBM's DataPartition + smaller-child trick,
+    #: re-shaped for static-shape jit); the sibling comes from subtraction.
+    #: ~L full-data scans per tree become ~2-3 full-data equivalents.
+    #: Disable to force full masked scans.
     compact_rows: bool = True
     #: smallest compaction bucket (rows); buckets double up to 2^ceil(lg n)
     min_bucket: int = 2048
@@ -675,6 +677,57 @@ def _bucket_sizes(n: int, cfg: GrowerConfig):
     return sizes
 
 
+#: rows of the top rung of the ladder ``_segment_hist`` gathers rows on,
+#: and of one chunk of its walk over a segment that no rung holds.
+#: Chosen once from a sweep on the v5e over 2^14 .. 2^18 at the three
+#: largest cells' shapes (PERF.md Findings, PR 34); not a parameter: the
+#: code adapts by a node's row count.
+SEGMENT_CHUNK_ROWS = 1 << 16
+
+
+def _build_sizes(n: int, cfg: GrowerConfig):
+    """The rungs of ``_bucket_sizes`` up to ``SEGMENT_CHUNK_ROWS``: the
+    sizes at which ``_segment_hist`` gathers a segment's rows whole.  A
+    longer segment is walked in chunks of the top rung's size, never
+    gathered at the next power of two.  (``_partition_switch`` keeps the
+    whole ladder: a node's split column gathered 2^16 elements at a time
+    costs 18.5 ns an element on the v5e where the whole rung's gather
+    costs 11.2, more than the rung's padding; PERF.md Findings, PR 34.)"""
+    sizes = _bucket_sizes(n, cfg)
+    return [s for s in sizes
+            if s <= max(SEGMENT_CHUNK_ROWS, sizes[0])]
+
+
+def _walked_rows(cnt, sizes):
+    """Rows the ladder ``sizes`` covers for segments of ``cnt`` rows
+    (numpy, on the host): the smallest rung that holds the segment, or
+    whole chunks of the top rung."""
+    cnt = np.asarray(cnt, np.int64)
+    rungs = np.asarray(sizes, np.int64)
+    rung = rungs[np.minimum(np.searchsorted(rungs, cnt), len(rungs) - 1)]
+    top = rungs[-1]
+    return np.where(cnt > top, -(-cnt // top) * top, rung)
+
+
+def segment_walk_stats(parents, smaller, n_rows: int,
+                       cfg: GrowerConfig) -> dict:
+    """What a fit's splits asked of the ladders over ``n_rows`` rows, from
+    node counts on the host: each split partitions its parent's rows on
+    a rung of ``_bucket_sizes`` and histograms its smaller child's on a
+    rung of ``_build_sizes`` or chunk by chunk.  ``seg_rows``: the rows
+    those segments hold; ``seg_rows_walked``: the rows the rungs and
+    chunks covered for them; ``seg_chunked_nodes``: children that took
+    the chunk loop."""
+    parents = np.asarray(parents, np.int64)
+    smaller = np.asarray(smaller, np.int64)
+    build = _build_sizes(n_rows, cfg)
+    walked = (_walked_rows(parents, _bucket_sizes(n_rows, cfg)).sum()
+              + _walked_rows(smaller, build).sum())
+    return {"seg_rows": int(parents.sum() + smaller.sum()),
+            "seg_rows_walked": int(walked),
+            "seg_chunked_nodes": int((smaller > build[-1]).sum())}
+
+
 @jax.named_scope("partition")
 def _partition_switch(row_order, col, off, cnt, thr, use_cat, cat_bits,
                       n, sizes, cfg: GrowerConfig):
@@ -734,10 +787,11 @@ def _partition_switch(row_order, col, off, cnt, thr, use_cat, cat_bits,
 def _segment_hist(bins, gh, row_order, off, cnt, n, sizes,
                   cfg: GrowerConfig):
     """Histogram the contiguous ``row_order[off:off+cnt]`` segment via the
-    smallest power-of-two bucket gather.  Local (no psum) — the caller
-    reduces over the data axis, keeping collectives out of switch
-    branches.  On the CPU backend the gather fuses into the native FFI
-    kernel (no (size, f) materialization)."""
+    smallest power-of-two bucket gather, or, where no rung holds it, by
+    summing the histograms of its chunks of the top rung's size.  Local
+    (no psum) — the caller reduces over the data axis, keeping
+    collectives out of switch branches.  On the CPU backend the gather
+    fuses into the native FFI kernel (no (size, f) materialization)."""
     from ..ops.histogram import native_segment_hist
     if cfg.hist_method in ("auto", "native"):
         fused = native_segment_hist(bins, gh, row_order, off, cnt,
@@ -746,29 +800,41 @@ def _segment_hist(bins, gh, row_order, off, cnt, n, sizes,
         if fused is not None:
             return fused
 
-    def make(size):
-        def fn(_):
-            seg = jax.lax.dynamic_slice(row_order, (off,), (size,))
-            valid = jnp.arange(size, dtype=jnp.int32) < cnt
-            rows = jnp.minimum(seg, n - 1)
-            with jax.named_scope("row_gather"):
-                # rows are clamped above: "clip" says so, and spares
-                # the bucket the fill mode's select, a whole pass
-                # over it that also kept XLA from handing the
-                # histogram kernel its rows-minor layout straight
-                # from the gather (PERF.md Findings, PR 28)
-                b_sub = jnp.take(bins, rows, axis=0, mode="clip")
-                gh_sub = jnp.take(gh, rows, axis=0, mode="clip") * \
-                    valid.astype(gh.dtype)[:, None]
-            with jax.named_scope("segment_hist"):
-                return compute_histogram(b_sub, gh_sub, cfg.num_bins,
-                                         method=cfg.hist_method,
-                                         max_code=cfg.quantized_max_code)
-        return fn
+    def build(size, base):
+        """The histogram of ``size`` slots from ``base`` into the
+        segment, those beyond its end masked."""
+        seg = jax.lax.dynamic_slice(row_order, (off + base,), (size,))
+        valid = jnp.arange(size, dtype=jnp.int32) < cnt - base
+        rows = jnp.minimum(seg, n - 1)
+        with jax.named_scope("row_gather"):
+            # rows are clamped above: "clip" says so, and spares
+            # the bucket the fill mode's select, a whole pass
+            # over it that also kept XLA from handing the
+            # histogram kernel its rows-minor layout straight
+            # from the gather (PERF.md Findings, PR 28)
+            b_sub = jnp.take(bins, rows, axis=0, mode="clip")
+            gh_sub = jnp.take(gh, rows, axis=0, mode="clip") * \
+                valid.astype(gh.dtype)[:, None]
+        with jax.named_scope("segment_hist"):
+            return compute_histogram(b_sub, gh_sub, cfg.num_bins,
+                                     method=cfg.hist_method,
+                                     max_code=cfg.quantized_max_code)
 
+    def chunked(_):
+        C = sizes[-1]
+        out = jax.eval_shape(lambda: build(C, 0))
+        return jax.lax.fori_loop(
+            0, (cnt + C - 1) // C,
+            lambda k, acc: acc + build(C, k * C),
+            jnp.zeros(out.shape, out.dtype))
+
+    branches = [lambda _, s=s: build(s, 0) for s in sizes]
+    if n > sizes[-1]:
+        branches.append(chunked)
+    # ``len(sizes)``, the chunk loop, where no rung holds ``cnt`` rows
     branch = jnp.searchsorted(jnp.asarray(sizes, jnp.int32), cnt,
                               side="left")
-    return jax.lax.switch(branch, [make(s) for s in sizes], 0)
+    return jax.lax.switch(branch, branches, 0)
 
 
 def _leaf_of_position(leaf_start, leaf_cnt, n):
@@ -844,12 +910,16 @@ def _find_split(hist, pg, ph, pc, fi, depth_ok, cfg: GrowerConfig,
 def hist_build_schedule(cfg: GrowerConfig, n_rows: int) -> dict:
     """Which build of the histogram a tree's call sites compile: the root
     (``n_rows`` rows a shard) and, where rows are compacted, each rung of
-    the bucket ladder.  ``build`` names the implementation
+    the bucket ladder and, where the rows pass its top rung, the chunk
+    loop.  ``build`` names the implementation
     (``ops.histogram.histogram_build``: one for all sites, since no build
     is chosen by the row count), ``fused`` counts the sites whose one-hot
     product stays on the chip, ``sites`` all of them."""
     from ..ops.histogram import histogram_build
-    sites = 1 + (len(_bucket_sizes(n_rows, cfg)) if cfg.compact_rows else 0)
+    sites = 1
+    if cfg.compact_rows:
+        sizes = _build_sizes(n_rows, cfg)
+        sites += len(sizes) + (n_rows > sizes[-1])
     build = histogram_build(cfg.hist_method, cfg.num_bins,
                             _is_quantized(cfg))
     return {"build": build,
@@ -986,6 +1056,7 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
     L = cfg.num_leaves
     W = cfg.cat_words
     sizes = _bucket_sizes(n, cfg)
+    build_sizes = _build_sizes(n, cfg)
     neg_inf = jnp.float32(-jnp.inf)
     # Transposed copy for split-column reads: a column of row-major (n, f)
     # is a stride-f gather (slow on TPU); a row of (f, n) is one contiguous
@@ -1119,8 +1190,8 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 child_off = jnp.where(use_right, off + cnt_l_p, off)
                 child_cnt = jnp.where(use_right, cnt_r_p, cnt_l_p)
                 hist_small = _segment_hist(
-                    bins, gh, row_order, child_off, child_cnt, n, sizes,
-                    cfg)
+                    bins, gh, row_order, child_off, child_cnt, n,
+                    build_sizes, cfg)
                 if efb is not None:
                     # expansion is linear, so it commutes with the
                     # reduction below

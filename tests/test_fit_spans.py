@@ -352,14 +352,16 @@ def test_fit_names_the_histogram_build_it_compiled(fit_inputs, method,
                                                    build):
     """``hist_build`` / ``hist_build_rungs`` on ``train.fit`` and in
     ``last_fit_info``: the implementation the fit's programs compiled,
-    and at how many of a tree's call sites (the root and each bucket
-    rung) the one-hot product stays on the chip: none on the CPU."""
-    from mmlspark_tpu.gbdt.grower import GrowerConfig, _bucket_sizes
+    and at how many of a tree's call sites (the root, each bucket rung
+    and the chunk loop) the one-hot product stays on the chip: none on
+    the CPU."""
+    from mmlspark_tpu.gbdt.grower import GrowerConfig, _build_sizes
     params = fit_inputs["params"].__class__(
         **{**fit_inputs["params"].__dict__, "histogram_method": method})
     _, root, _ = _fit({**fit_inputs, "params": params})
     n = fit_inputs["bins"].shape[0]
-    sites = 1 + len(_bucket_sizes(n, GrowerConfig()))
+    sizes = _build_sizes(n, GrowerConfig())
+    sites = 1 + len(sizes) + (n > sizes[-1])
     a = root["attrs"]
     assert (a["hist_build"], a["hist_build_rungs"]) == (build, f"0/{sites}")
     assert (engine.last_fit_info["hist_build"],
@@ -368,12 +370,13 @@ def test_fit_names_the_histogram_build_it_compiled(fit_inputs, method,
 
 
 @pytest.mark.parametrize("case,want", [
-    ("epsilon", ("dot16/mosaic", 10, 10)),      # root + rungs 2^11..2^19
+    # root + rungs 2^11..2^16 + the chunk loop (2^11..2^19 before PR 34)
+    ("epsilon", ("dot16/mosaic", 8, 8)),
     ("epsilon a chip of four", ("dot16/mosaic", 8, 8)),
     ("masked", ("dot16/mosaic", 1, 1)),         # every split a full pass
-    ("quantized", ("dot16/xla", 0, 10)),        # int32 kernel is refused
-    ("bundles over 256 bins", ("dot16/xla", 0, 10)),
-    ("another method", ("segment", 0, 10)),
+    ("quantized", ("dot16/xla", 0, 8)),         # int32 kernel is refused
+    ("bundles over 256 bins", ("dot16/xla", 0, 8)),
+    ("another method", ("segment", 0, 8)),
 ])
 def test_hist_build_schedule_counts_the_fused_call_sites(monkeypatch, case,
                                                          want):
@@ -396,6 +399,36 @@ def test_hist_build_schedule_counts_the_fused_call_sites(monkeypatch, case,
         cfg = cfg.__class__(**{**cfg.__dict__, "hist_method": "segment"})
     got = hist_build_schedule(cfg, n)
     assert (got["build"], got["fused"], got["sites"]) == want
+
+
+@pytest.mark.parametrize("devices", [1, 4], ids=["serial", "mesh4"])
+def test_fit_counts_the_rows_its_segments_walked(fit_inputs, devices):
+    """``seg_rows`` / ``seg_rows_walked`` / ``seg_chunked_nodes`` on
+    ``train.fit``: a one-device fit's splits against the bucket ladder,
+    from the returned trees' node counts; a mesh fit knows no shard's
+    counts on the host and says nothing."""
+    from mmlspark_tpu.gbdt.grower import GrowerConfig
+    booster, root, _ = _fit(fit_inputs, _mesh4() if devices == 4 else None)
+    a = root["attrs"]
+    seg = {k: v for k, v in a.items() if k.startswith("seg_")}
+    if devices == 4:
+        assert seg == {}
+        return
+    assert sorted(seg) == ["seg_chunked_nodes", "seg_rows",
+                           "seg_rows_walked"]
+    n = fit_inputs["bins"].shape[0]
+    # every tree's root is partitioned whole and every row lands in one
+    # smaller child at most once a level
+    assert seg["seg_rows"] >= len(booster.trees) * n
+    assert seg["seg_rows_walked"] >= seg["seg_rows"]
+    assert seg["seg_chunked_nodes"] == 0      # 60 000 rows fit a rung
+    # the attrs are sums over the trees, and a tree's root is the first
+    # segment it partitions
+    per_tree = [engine._segment_walk_attrs([t], n, GrowerConfig().min_bucket)
+                for t in booster.trees]
+    assert sum(p["seg_rows"] for p in per_tree) == seg["seg_rows"]
+    assert all(p["seg_rows"] >= t.internal_count[0] == n
+               for p, t in zip(per_tree, booster.trees))
 
 
 def test_chunked_fit_emits_launch_wait_monitor_per_chunk(fit_inputs):

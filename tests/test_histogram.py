@@ -317,7 +317,9 @@ class TestMethodNames:
         assert sched["build"] == H.histogram_build("auto", 255, False) == (
             "dot16/mosaic" if want == "dot16" else want)
         assert sched["fused"] in (0, sched["sites"])
-        assert sched["sites"] >= 2
+        # the root and one rung; the root, rungs 2^11 .. 2^16 and the
+        # chunk loop (the root and 15 rungs before PR 34)
+        assert sched["sites"] == (2 if rows == 2048 else 8)
 
     def test_the_param_text_names_the_methods_the_code_accepts(self):
         """``histogramMethod``'s description and ``METHODS`` cannot

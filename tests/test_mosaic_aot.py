@@ -125,6 +125,32 @@ def test_split_loop_updates_the_histogram_cache_in_place(v5e, case):
     assert [c for c in copies if c[1] == cache] == []
 
 
+# ------------------------------- a long segment, built chunk by chunk
+
+_WALK_N, _WALK_F = 300_000, 39
+
+
+def test_chunked_build_holds_no_bucket_of_rows(v5e, decides_as_on_the_tpu):
+    """The grower at 300 000 x 39, up to 5 chunks of 2^16 rows a node: a
+    child over the chunk is histogrammed chunk by chunk, so the program
+    holds no rows of a 2^19-row bucket (the rung that held the root's
+    child before PR 34: its gathered ``(2^19, 39)`` rows, their
+    transpose, its ``(2^19, 3)`` gradients; the partition's 1-D slices
+    of that rung stay), and a kernel call site at the root, on each of
+    the six rungs and in the loop, where the ladder to 2^19 had ten.
+    About a minute to compile."""
+    from mmlspark_tpu.core.profiling import compiled_instructions
+    from mmlspark_tpu.gbdt import grower
+    n, f = _WALK_N, _WALK_F
+    assert n > 4 * grower.SEGMENT_CHUNK_ROWS
+    compiled = _compile_grower(v5e, "serial", n=n, f=f)
+    bucket = 1 << (n - 1).bit_length()
+    assert [(name, shape) for name, shape, _ in compiled_instructions(
+        compiled, min_bytes=bucket)
+        if len(shape) >= 2 and bucket in shape] == []
+    assert compiled.as_text().count("tpu_custom_call") == 8
+
+
 # --------------------------- the histogram build's one-hots, on the chip
 
 
@@ -174,15 +200,16 @@ def test_dot16_build_keeps_its_one_hots_on_the_chip(v5e,
 def test_boost_scan_at_epsilons_shape_holds_no_one_hot(
         v5e, decides_as_on_the_tpu):
     """The whole fit's program at 400 000 x 2000, 255 leaves and bins: a
-    kernel at the root and in every bucket rung, no ``(rows, F, 16)``
-    array anywhere, and 6.1 GB of temporaries (the cache, the row-major
-    table and the largest bucket, twice each) where XLA's formulation
-    held 9.9 (about a minute to compile)."""
+    kernel at the root, in each of the six bucket rungs and in the chunk
+    loop, no ``(rows, F, 16)`` array anywhere, and 2.9 GB of temporaries
+    (the cache, the row-major table and a 2^16-row chunk twice) where
+    the ladder to 2^19 rows held 6.1 and XLA's formulation 9.9 (about a
+    minute to compile)."""
     compiled = _compile_grower(v5e, "boost_scan", n=400_000, f=2000,
                                num_bins=255)
-    assert compiled.as_text().count("tpu_custom_call") >= 10
+    assert compiled.as_text().count("tpu_custom_call") == 8
     assert _wider_than_the_bins(compiled, 2000) == []
-    assert compiled.memory_analysis().temp_size_in_bytes < 7.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
 
 
 def test_bundled_boost_scan_keeps_one_cache_and_no_feature_wide_rows(
