@@ -24,18 +24,14 @@ in production:
   (``backend_compile``, ``jaxpr_trace``, ...), and a process-monotonic
   :meth:`compile_seq` lets any dispatch site classify its own calls as
   cache HIT vs MISS without touching jit internals: read the sequence
-  before and after the call — if it moved, this dispatch compiled.
-  :meth:`dispatch` records the split host-dispatch /
-  materialization-wait timings (the ``block_until_ready``-style
-  bracketing PERF.md's "per-dispatch host glue" hunt needs) plus the
-  hit/miss ledger per site.  Device/HBM watermarks are sampled from
+  before and after the call — if it moved, this dispatch compiled
+  (:meth:`count_dispatch` keeps the hit/miss ledger per site; the
+  host-dispatch / materialization-wait split of a call is its site's
+  own phases or regions).  Device/HBM watermarks are sampled from
   ``device.memory_stats()`` where the backend exposes it (TPU/GPU;
-  CPU returns none).
-* **Sampling** — an OPT-IN ~100 Hz thread-stack sampler over the
-  worker/pump threads producing collapsed-stack flamegraph lines
-  (``a;b;c 42``).  Off by default; when on, a duty-cycle gate keeps
-  its own cost under ~5% of a core no matter how slow
-  ``sys._current_frames`` is on the host.
+  CPU returns none): in use and reserved, now and at their peaks.
+* **Sampling** — :meth:`start_sampler`, opt-in, off by default:
+  collapsed thread stacks for a flamegraph.
 
 Exposition: the ``mmlspark_tpu_profile_*`` families join every
 ``/metrics`` scrape through the registry's exposition-provider hook
@@ -107,9 +103,9 @@ class Profiler:
     #: bounded journal ring from flooding with per-request spans);
     #: callers may force with ``journal=True``
     SPAN_JOURNAL_MS = 50.0
-    #: regions kept in memory (:meth:`spans`): a fit records about a
-    #: dozen, so the ring holds the last few hundred fits
-    SPAN_RING = 4096
+    #: regions kept in memory (:meth:`spans`): a fit of one chunk records
+    #: 22, so the ring holds the last 460 fits or so
+    SPAN_RING = 10240
     #: newest regions carried by :meth:`snapshot`
     SPAN_SNAPSHOT_TAIL = 64
 
@@ -312,22 +308,6 @@ class Profiler:
             else:
                 ent["hits"] += 1
 
-    def dispatch(self, site: str, host_s: float, wait_s: float,
-                 misses: int = 0) -> None:
-        """One bracketed dispatch at ``site``: ``host_s`` is the wall
-        time until the jitted call returned (tracing + dispatch glue,
-        the PERF.md "host glue"), ``wait_s`` the further wall time
-        until the result materialized (``block_until_ready`` /
-        ``np.asarray`` bracketing — device compute plus D2H).
-        ``misses`` is the :meth:`compile_seq` delta over the call.
-        Per-batch call sites pre-resolve the two timers and call
-        :meth:`count_dispatch` instead."""
-        if not self.enabled:
-            return
-        self.record_phase(f"{site}.dispatch_host", host_s)
-        self.record_phase(f"{site}.device_wait", wait_s)
-        self.count_dispatch(site, misses)
-
     # ---- memory watermarks ----
 
     def record_memory(self, device: str, kind: str,
@@ -361,7 +341,11 @@ class Profiler:
                 if not stats:
                     continue
                 label = f"{d.platform}:{d.id}"
+                # the TPU runtime counts the loaded programs'
+                # temporaries apart, as reserved: a chip's watermark is
+                # peak_bytes_in_use + peak_bytes_reserved
                 for kind in ("bytes_in_use", "peak_bytes_in_use",
+                             "bytes_reserved", "peak_bytes_reserved",
                              "bytes_limit"):
                     if kind in stats:
                         self.record_memory(label, kind, stats[kind])

@@ -11,6 +11,8 @@ in-package capability rather than a side tool:
 * :func:`summarize_trace` — parse the written ``.xplane.pb`` (no
   TensorBoard needed) into device self time by the program's named
   scopes; ``tools/profile_boost_step.py`` prints it.
+* :func:`idle_by_span` — the same trace's idle device seconds, each
+  charged to the ``Profiler.region`` the host had open then.
 * :func:`compiled_instructions` / :func:`compiled_copies` — the
   instructions of a compiled program over a byte threshold (its ``copy``
   instructions), read from its text: what a loop's carry costs when it
@@ -461,6 +463,18 @@ def _self_times(events) -> Dict[str, float]:
     return totals
 
 
+def _newest_xplane(out_dir: str) -> Optional[str]:
+    """The newest ``.xplane.pb`` under ``out_dir``, by mtime: the
+    profiler names exports by timestamp strings whose lexicographic
+    order diverges from chronology across hosts/sessions (and a re-run
+    into the same dir must win)."""
+    paths = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not paths:
+        return None
+    return max(paths, key=lambda p: (os.path.getmtime(p), p))
+
+
 def summarize_trace(out_dir: str, top: int = 25
                     ) -> List[Tuple[float, str]]:
     """Device self time by named scope, from the newest ``.xplane.pb``
@@ -477,16 +491,10 @@ def summarize_trace(out_dir: str, top: int = 25
     ``root_hist/reduce``...  An op with no path (the ``while`` itself, a
     parameter copy) keeps its HLO name without the number.  A trace with
     no device plane (the CPU backend) is summarized from the host
-    threads' HLO-op events, by op.
-
-    "Newest" is by mtime: the profiler names exports by timestamp
-    strings whose lexicographic order diverges from chronology across
-    hosts/sessions (and a re-run into the same dir must win)."""
-    paths = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not paths:
+    threads' HLO-op events, by op."""
+    newest = _newest_xplane(out_dir)
+    if newest is None:
         return []
-    newest = max(paths, key=lambda p: (os.path.getmtime(p), p))
     from jax.profiler import ProfileData
     data = ProfileData.from_file(newest)
     op_names = _op_name_paths(newest)
@@ -516,6 +524,142 @@ def summarize_trace(out_dir: str, top: int = 25
                   reverse=True)
     total_ms = round(sum(ms for ms, _ in rows), 3)
     return rows[:top] + [(total_ms, "total_device_ms")]
+
+
+#: the root of a fit's regions (``gbdt/engine.train``), and where an
+#: idle stretch goes that lies between two of them
+FIT_SPAN = "train.fit"
+BETWEEN_FITS = "between fits"
+#: names of the profiler's regions among a trace's host annotations
+_REGION_PREFIXES = ("train.", "bin.")
+
+
+def _busy_union(intervals) -> List[List[float]]:
+    """Sorted, disjoint ``[start, end]`` covering the same points.  A
+    trace's line comes in start order and is merged as it streams (a
+    plane may hold millions of events); what comes out of order is
+    sorted in at the end."""
+    out: List[List[float]] = []
+    late = []
+    for a, b in intervals:
+        if b <= a:
+            continue
+        if out and a < out[-1][0]:
+            late.append((a, b))
+        elif out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    if not late:
+        return out
+    return _busy_union(sorted(late + [tuple(i) for i in out]))
+
+
+def charge_idle(events, annotations) -> List[Tuple[float, str]]:
+    """Idle seconds of one device by the host span open at the time, on
+    plain lists (``tests/test_fit_spans.py`` checks it on a hand-made
+    one): ``events`` the device's ``(start, end)`` op intervals,
+    ``annotations`` the host's ``(name, start, end)`` regions, same
+    clock.  From the first :data:`FIT_SPAN`'s start to the last one's
+    end, every stretch in which no event runs is cut at the regions'
+    boundaries and each piece charged to the innermost region open then
+    (the latest to start; the fit itself where no child is open,
+    :data:`BETWEEN_FITS` outside any).  ``[(seconds, name), ...]``,
+    largest first; empty without a fit."""
+    fits = [a for a in annotations if a[0] == FIT_SPAN]
+    if not fits:
+        return []
+    lo = min(a[1] for a in fits)
+    hi = max(a[2] for a in fits)
+    # the host's timeline as disjoint (start, end, innermost name)
+    edges = sorted({lo, hi, *(t for a in annotations for t in a[1:]
+                              if lo < t < hi)})
+    opened = sorted((a for a in annotations if a[2] > lo and a[1] < hi),
+                    key=lambda a: a[1])
+    timeline, stack, nxt = [], [], 0
+    for a, b in zip(edges, edges[1:]):
+        while nxt < len(opened) and opened[nxt][1] <= a:
+            stack.append(opened[nxt])
+            nxt += 1
+        stack = [r for r in stack if r[2] > a]
+        timeline.append((a, b, stack[-1][0] if stack else BETWEEN_FITS))
+    # the device's busy union, walked once beside the timeline
+    busy = _busy_union(e for e in events if e[1] > lo and e[0] < hi)
+    totals: Dict[str, float] = defaultdict(float)
+    k = 0
+    for a, b, name in timeline:
+        idle = b - a
+        while k < len(busy) and busy[k][1] <= a:
+            k += 1
+        j = k
+        while j < len(busy) and busy[j][0] < b:
+            idle -= min(busy[j][1], b) - max(busy[j][0], a)
+            j += 1
+        totals[name] += idle
+    return sorted(((secs, name) for name, secs in totals.items()
+                   if secs > 0), reverse=True)
+
+
+def idle_by_span(out_dir: str) -> List[Tuple[float, str]]:
+    """Where the device waited for the host, by the program's own spans,
+    from the newest ``.xplane.pb`` under ``out_dir``: the host planes'
+    ``TraceAnnotation`` events that are the profiler's regions
+    (``Profiler.region``: ``train.*``, ``bin.*``) and the "XLA Ops"
+    events of the busiest device (the CPU backend has no device plane:
+    its host threads' HLO-op events stand in), reduced by
+    :func:`charge_idle`.  ``[(idle_ms, span name), ...]`` largest first,
+    with one trailing ``(total_idle_ms, "total_idle_ms")`` row: the fits'
+    seconds (and what lies between them) less that device's busy union.
+    Empty when there is no trace or no ``train.fit`` in it."""
+    newest = _newest_xplane(out_dir)
+    if newest is None:
+        return []
+    from jax.profiler import ProfileData
+    devices: Dict[str, list] = {}
+    host_ops, annotations = [], []
+    planes = list(ProfileData.from_file(newest).planes)
+    on_host = not any(p.name.startswith("/device:") for p in planes)
+    for plane in planes:
+        if plane.name.startswith("/device:"):
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    devices[plane.name] = _busy_union(
+                        (e.start_ns / 1e9,
+                         (e.start_ns + e.duration_ns) / 1e9)
+                        for e in line.events)
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(_REGION_PREFIXES):
+                    annotations.append((e.name, e.start_ns / 1e9,
+                                        (e.start_ns + e.duration_ns) / 1e9))
+                elif on_host and e.duration_ns > 0 and any(
+                        k == "hlo_op" for k, _ in e.stats):
+                    host_ops.append((e.start_ns / 1e9,
+                                     (e.start_ns + e.duration_ns) / 1e9))
+    # the busiest device: a plane that came back short is not it
+    busiest = host_ops if on_host else max(
+        devices.values(), key=lambda u: sum(b - a for a, b in u),
+        default=[])
+    rows = [(secs * 1e3, name)
+            for secs, name in charge_idle(busiest, annotations)]
+    if not rows:
+        return []
+    return rows + [(round(sum(ms for ms, _ in rows), 3), "total_idle_ms")]
+
+
+def trace_tables(out_dir: str) -> str:
+    """:func:`summarize_trace` and :func:`idle_by_span` of the newest
+    trace under ``out_dir`` as text, one ``milliseconds  name`` row a
+    line: what ``LightGBMBase._fit`` logs where ``profileTraceDir`` is
+    set."""
+    out = []
+    for title, rows in (
+            ("device self time by named scope", summarize_trace(out_dir)),
+            ("idle device time by the host's span", idle_by_span(out_dir))):
+        out.append(f"{title} (ms):")
+        out.extend(f"  {ms:12.3f}  {name[:100]}" for ms, name in rows)
+    return "\n".join(out)
 
 
 def _hlo_base(name: str) -> str:

@@ -52,6 +52,8 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .profiler import get_profiler
+
 __all__ = [
     "MAX_PROFILE_EDGES", "MatrixSketch", "ReferenceProfile",
     "StreamSketch", "build_reference_profile", "js_divergence",
@@ -497,68 +499,74 @@ def build_reference_profile(bins: np.ndarray, mapper,
     (the fit's device-resident table counts them in one pass:
     ``gbdt/engine.py``); ``bins`` then only has to have the table's
     ``shape`` (a bundled table has no ``(n, f)`` array).  Absent, each
-    column is counted here, one strided pass over the table a feature.
+    column is counted here, one strided pass over the table a feature
+    (span ``train.refprofile_counts``, ``where`` = ``host``).  The rest,
+    counts to sketches feature by feature and the margin sketch, is the
+    span ``train.refprofile_rollup`` (attr ``features``).
     """
     n, f = np.shape(bins)
-    if fine_counts is not None:
-        fine_counts = np.asarray(fine_counts, np.int64)
-        if fine_counts.shape != (f, mapper.num_total_bins):
-            raise ValueError(
-                f"fine_counts has shape {fine_counts.shape}; the table "
-                f"has {f} features of {mapper.num_total_bins} bins")
-    edges_list: List[np.ndarray] = []
-    sketches: List[Dict[str, Any]] = []
-    for j in range(f):
-        ub = mapper.upper_bounds[j]
-        if mapper.is_categorical(j) or len(ub) == 0:
-            edges = np.empty(0, np.float64)
-        else:
-            edges = downsample_edges(ub, max_edges)
-        lo, hi = ((float(edges[0]), float(edges[-1]))
-                  if len(edges) else (None, None))
-        sk = StreamSketch(edges, lo, hi)
-        if fine_counts is not None:
-            fine = fine_counts[j]
-        else:
-            col = np.ascontiguousarray(np.asarray(bins)[:, j])
-            fine = np.bincount(col, minlength=mapper.num_total_bins
-                               ).astype(np.int64)
-        sk.nan = int(fine[mapper.missing_bin])
-        if mapper.is_categorical(j):
-            # category identity occupies the fine bins; the coarse
-            # ladder is empty → everything finite in bucket 0
-            finite = int(fine[:mapper.missing_bin].sum())
-            sk.counts[0] = finite
-            sk.count = finite
-        else:
-            value_bins = fine[:len(ub) + 1]
-            if len(edges):
-                # fine bin b (first bound >= v is ub[b]) rolls up to
-                # the first coarse edge position >= b
-                idx = np.searchsorted(ub, edges, side="left")
-                coarse_of_fine = np.searchsorted(
-                    idx, np.arange(len(ub) + 1), side="left")
-                sk.counts += np.bincount(
-                    coarse_of_fine, weights=value_bins,
-                    minlength=len(sk.counts)).astype(np.int64)
+    prof = get_profiler()
+    if fine_counts is None:
+        with prof.region("train.refprofile_counts", where="host"):
+            table, nb = np.asarray(bins), mapper.num_total_bins
+            fine_counts = np.zeros((f, nb), np.int64)
+            for j in range(f):
+                fine_counts[j] = np.bincount(
+                    np.ascontiguousarray(table[:, j]), minlength=nb)[:nb]
+    fine_counts = np.asarray(fine_counts, np.int64)
+    if fine_counts.shape != (f, mapper.num_total_bins):
+        raise ValueError(
+            f"fine_counts has shape {fine_counts.shape}; the table "
+            f"has {f} features of {mapper.num_total_bins} bins")
+    with prof.region("train.refprofile_rollup", features=int(f)):
+        edges_list: List[np.ndarray] = []
+        sketches: List[Dict[str, Any]] = []
+        for j in range(f):
+            ub = mapper.upper_bounds[j]
+            if mapper.is_categorical(j) or len(ub) == 0:
+                edges = np.empty(0, np.float64)
             else:
-                sk.counts[0] = int(value_bins.sum())
-            sk.count = int(value_bins.sum())
-        edges_list.append(edges)
-        sketches.append(sk.snapshot())
-    if margins is not None and np.asarray(margins).size:
-        mg = np.asarray(margins, np.float64).ravel()
-        mg = mg[np.isfinite(mg)]
-        qs = np.linspace(0.0, 1.0, margin_buckets + 1)[1:-1]
-        medges = np.unique(np.quantile(mg, qs)) if mg.size \
-            else np.empty(0, np.float64)
-        msk = StreamSketch(medges)
-        msk.update(mg)
-    else:
-        medges = np.empty(0, np.float64)
-        msk = StreamSketch(medges)
-    return ReferenceProfile(
-        edges_list, sketches, medges, msk.snapshot(),
-        feature_names=feature_names,
-        meta={"n_rows": int(n), "created": round(time.time(), 3),
-              **(meta or {})})
+                edges = downsample_edges(ub, max_edges)
+            lo, hi = ((float(edges[0]), float(edges[-1]))
+                      if len(edges) else (None, None))
+            sk = StreamSketch(edges, lo, hi)
+            fine = fine_counts[j]
+            sk.nan = int(fine[mapper.missing_bin])
+            if mapper.is_categorical(j):
+                # category identity occupies the fine bins; the coarse
+                # ladder is empty → everything finite in bucket 0
+                finite = int(fine[:mapper.missing_bin].sum())
+                sk.counts[0] = finite
+                sk.count = finite
+            else:
+                value_bins = fine[:len(ub) + 1]
+                if len(edges):
+                    # fine bin b (first bound >= v is ub[b]) rolls up to
+                    # the first coarse edge position >= b
+                    idx = np.searchsorted(ub, edges, side="left")
+                    coarse_of_fine = np.searchsorted(
+                        idx, np.arange(len(ub) + 1), side="left")
+                    sk.counts += np.bincount(
+                        coarse_of_fine, weights=value_bins,
+                        minlength=len(sk.counts)).astype(np.int64)
+                else:
+                    sk.counts[0] = int(value_bins.sum())
+                sk.count = int(value_bins.sum())
+            edges_list.append(edges)
+            sketches.append(sk.snapshot())
+        if margins is not None and np.asarray(margins).size:
+            mg = np.asarray(margins, np.float64).ravel()
+            mg = mg[np.isfinite(mg)]
+            qs = np.linspace(0.0, 1.0, margin_buckets + 1)[1:-1]
+            medges = np.unique(np.quantile(mg, qs)) if mg.size \
+                else np.empty(0, np.float64)
+            msk = StreamSketch(medges)
+            msk.update(mg)
+        else:
+            medges = np.empty(0, np.float64)
+            msk = StreamSketch(medges)
+        return ReferenceProfile(
+            edges_list, sketches, medges, msk.snapshot(),
+            feature_names=feature_names,
+            meta={"n_rows": int(n), "created": round(time.time(), 3),
+                  **(meta or {})})

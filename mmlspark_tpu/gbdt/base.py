@@ -15,6 +15,7 @@ params that have no TPU meaning (``useBarrierExecutionMode``, ``numTasks``,
 
 from __future__ import annotations
 
+import logging
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -29,6 +30,8 @@ from .binning import fit_bin_mapper
 from .booster import Booster
 from .engine import TrainParams, train
 from .objectives import get_objective
+
+log = logging.getLogger("mmlspark_tpu.gbdt")
 
 
 class LightGBMParams(HasFeaturesCol, HasLabelCol, HasPredictionCol,
@@ -219,7 +222,8 @@ class LightGBMParams(HasFeaturesCol, HasLabelCol, HasPredictionCol,
         "profileTraceDir",
         "Directory for a jax.profiler device trace of the whole fit "
         "(empty disables).  Perfetto/TensorBoard-readable; "
-        "core.profiling.summarize_trace parses it offline — the "
+        "core.profiling.summarize_trace and idle_by_span parse it (the "
+        "fit logs both tables) — the "
         "TPU-native replacement for the reference's Spark-UI stage "
         "timings (SURVEY.md section 5.1)",
         default="", typeConverter=TypeConverters.toString)
@@ -455,8 +459,9 @@ class LightGBMBase(Estimator, LightGBMParams):
             )
             if val_init_scores is not None:
                 val_kwargs["val_init_scores"] = val_init_scores
-        from ..core.profiling import maybe_trace
-        with maybe_trace(self.getProfileTraceDir()):
+        from ..core.profiling import maybe_trace, trace_tables
+        trace_dir = self.getProfileTraceDir()
+        with maybe_trace(trace_dir):
             booster = train(
                 bins, y_train, w_train, mapper, objective, params,
                 feature_names=feature_names,
@@ -465,6 +470,9 @@ class LightGBMBase(Estimator, LightGBMParams):
                 init_scores=init_scores,
                 ranking_info=ranking_info,
                 **val_kwargs)
+        if trace_dir:
+            log.info("trace of the fit under %s\n%s", trace_dir,
+                     trace_tables(trace_dir))
         if init_booster is not None:
             booster = init_booster.extended(booster)
         model = self._make_model(booster)

@@ -467,7 +467,7 @@ def _ckpt_event(name: str, **fields) -> None:
 
 
 def _dispatch_chunk(run, scores, val_scores, it: int, trees: int,
-                    t_chunk: float, **ids):
+                    t_chunk: float, uploaded=None, **ids):
     """One bracketed chunk dispatch, for the serial and the mesh loop
     alike: ``run(scores, val_scores)`` under ``train.launch`` (tracing,
     compile or cache look-up, enqueue; its attrs say how much of it was
@@ -477,11 +477,20 @@ def _dispatch_chunk(run, scores, val_scores, it: int, trees: int,
     host needs these results before the next chunk (or the final fetch)
     anyway, so it moves a wait, it does not add one.
 
-    Also feeds the ``train.boost_chunk.*`` dispatch ledger (host glue
-    since ``t_chunk`` against device wait, the ``compile_seq`` delta
-    classifying the dispatch as cache hit or miss) and journals the
-    ``profile_span`` that ``tools/trace_report.py`` lays on the fit's
-    timeline (ISSUE 12)."""
+    ``uploaded``: a fit's FIRST chunk hands over ``(operands, bytes)``,
+    the arrays ``train.upload`` (and ``train.rank_pack``) sent that the
+    program does not donate, and the bytes those spans said went up.
+    ``jnp.asarray`` returns before a transfer ends, so the program just
+    enqueued waits for its table; ``train.upload_wait`` blocks on the
+    operands first, and what ``train.device_wait`` then holds is device
+    work and its drain.  The device sees nothing new: the host waits in
+    two steps where it waited in one, and with the profiler off in one.
+
+    Also feeds the ``train.boost_chunk`` dispatch ledger (the
+    ``compile_seq`` delta classifying the dispatch as cache hit or miss)
+    and journals the ``profile_span`` that ``tools/trace_report.py`` lays
+    on the fit's timeline (ISSUE 12): host glue since ``t_chunk``
+    against the two waits."""
     p = get_profiler()
     seq0 = p.compile_seq()
     traced0 = p.jax_seconds("jaxpr_trace")
@@ -495,11 +504,15 @@ def _dispatch_chunk(run, scores, val_scores, it: int, trees: int,
             backend_compile_s=round(
                 p.jax_seconds("backend_compile") - compiled0, 6))
     t_host = time.perf_counter()
+    if uploaded is not None and p.enabled:
+        operands, nbytes = uploaded
+        with p.region("train.upload_wait", bytes=int(nbytes)):
+            jax.block_until_ready(operands)
     with p.region("train.device_wait", it=int(it), trees=int(trees)):
         jax.block_until_ready(out[0])
     t_done = time.perf_counter()
-    p.dispatch("train.boost_chunk", t_host - t_chunk, t_done - t_host,
-               misses)
+    if p.enabled:
+        p.count_dispatch("train.boost_chunk", misses)
     p.span("train.boost_chunk", t_done - t_chunk, journal=True,
            it=int(it), host_ms=round((t_host - t_chunk) * 1e3, 3),
            device_ms=round((t_done - t_host) * 1e3, 3), **ids)
@@ -1421,8 +1434,11 @@ def _fetch_host_trees(chunks: List[TreeArrays], num_leaves: int
     with get_profiler().region("train.fetch_trees", bytes=0) as sp:
         if not chunks:
             return [], np.zeros(0, np.int64)
-        packed = np.concatenate(
-            [np.asarray(_pack_trees_stacked(c)) for c in chunks])
+        # the device's pack and the transfer; the unpacking below is
+        # this span's self time
+        with get_profiler().region("train.fetch_wait"):
+            packed = np.concatenate(
+                [np.asarray(_pack_trees_stacked(c)) for c in chunks])
         sp["bytes"] = int(packed.nbytes)
         L, m = num_leaves, num_leaves - 1
         W = chunks[0].node_cat_bits.shape[-1]
@@ -1628,7 +1644,19 @@ def _capture_reference_profile(booster: Booster, bins, mapper,
     (``bins`` a ``BundledTable``) counts its ``(n, G)`` table, expands
     the counts to features by the map ``grower._efb_expand`` gathers by,
     and decodes the sampled rows as ``grower.efb_feature_column`` does:
-    the same profile as the unbundled table's."""
+    the same profile as the unbundled table's.
+
+    Its children say where the capture's time goes.
+    ``train.refprofile_counts`` (``where`` = the parent's ``counts``): the
+    count pass from its dispatch to the counts on the host, the wait for
+    the device included, opened twice on the device path because the
+    sampled rows are taken in between, while the device counts; on the
+    host path the column passes.  ``train.refprofile_sample``: the
+    sampled rows' take from the host table, then their upload, their
+    decode where bundled, and the look-up of their representatives.
+    ``train.refprofile_margins``: the bin-space forest's margins of those
+    rows, to the host.  ``train.refprofile_rollup`` (``features``):
+    ``build_reference_profile``, counts to sketches."""
     if os.environ.get(REF_PROFILE_ENV, "1") == "0" or mapper is None:
         return
     with get_profiler().region("train.reference_profile",
@@ -1648,49 +1676,62 @@ def _capture_reference_profile(booster: Booster, bins, mapper,
             sp["rows"] = int(n)
             # the host rows as the fit uploaded them: (n, G) when bundled
             rows_h = bins if bundled is None else bundled.table
+            prof = get_profiler()
             counts_d = None
             if device_table:
-                counts_d = _table_bin_counts(
-                    device_table.pop("bins"), mapper.num_total_bins,
-                    device_table.get("mesh"))
-            sample = rows_h
-            if n > _REF_PROFILE_MARGIN_ROWS:
-                idx = np.random.default_rng(0).choice(
-                    n, size=_REF_PROFILE_MARGIN_ROWS, replace=False)
-                idx.sort()
-                sample = rows_h[idx]
-            fine_counts = None
-            if counts_d is not None:
-                # the wait is the capture's; with it the fit's table
-                # leaves the device, before the sampled rows arrive
-                fine_counts = np.asarray(counts_d)[:rows_h.shape[1]].astype(
-                    np.int64)
-                fine_counts[:, 0] -= device_table["pad_rows"]
                 sp["counts"] = "device"
-            elif bundled is not None:
-                fine_counts = np.stack([
-                    np.bincount(rows_h[:, g],
-                                minlength=mapper.num_total_bins)
-                    for g in range(rows_h.shape[1])])
-            if bundled is not None:
-                fine_counts = expand_counts(fine_counts, bundled.maps(), n)
-            table = _representative_table(mapper)
-            if jax.default_backend() == "cpu":
-                # predict_margin walks numpy rows natively there
-                if bundled is not None:
-                    sample = decode_rows(sample, bundled.maps(),
-                                         mapper.missing_bin)
-                Xr = table[np.arange(f), sample]
-            else:
-                sample_d = jnp.asarray(sample, mapper.bin_dtype)
-                if bundled is not None:
-                    sample_d = _decode_bundled_rows(
-                        sample_d, (device_table or {}).get("efb")
-                        or _efb_dev_from_host(bundled.maps()),
-                        mapper.missing_bin)
-                Xr = _representative_rows(sample_d, jnp.asarray(table))
-            margins = np.asarray(
-                _bin_space_forest(booster, mapper).predict_margin(Xr))
+                with prof.region("train.refprofile_counts", where="device"):
+                    counts_d = _table_bin_counts(
+                        device_table.pop("bins"), mapper.num_total_bins,
+                        device_table.get("mesh"))
+            # the sampled rows are taken while the device counts
+            with prof.region("train.refprofile_sample"):
+                sample = rows_h
+                if n > _REF_PROFILE_MARGIN_ROWS:
+                    idx = np.random.default_rng(0).choice(
+                        n, size=_REF_PROFILE_MARGIN_ROWS, replace=False)
+                    idx.sort()
+                    sample = rows_h[idx]
+            fine_counts = None
+            if counts_d is not None or bundled is not None:
+                with prof.region("train.refprofile_counts",
+                                 where=sp["counts"]):
+                    if counts_d is not None:
+                        # the wait is the capture's; with it the fit's
+                        # table leaves the device, before the sampled
+                        # rows arrive
+                        fine_counts = np.asarray(counts_d)[
+                            :rows_h.shape[1]].astype(np.int64)
+                        fine_counts[:, 0] -= device_table["pad_rows"]
+                    else:
+                        fine_counts = np.stack([
+                            np.bincount(rows_h[:, g],
+                                        minlength=mapper.num_total_bins)
+                            for g in range(rows_h.shape[1])])
+                    if bundled is not None:
+                        fine_counts = expand_counts(fine_counts,
+                                                    bundled.maps(), n)
+            with prof.region("train.refprofile_sample"):
+                table = _representative_table(mapper)
+                if jax.default_backend() == "cpu":
+                    # predict_margin walks numpy rows natively there
+                    if bundled is not None:
+                        sample = decode_rows(sample, bundled.maps(),
+                                             mapper.missing_bin)
+                    Xr = table[np.arange(f), sample]
+                else:
+                    sample_d = jnp.asarray(sample, mapper.bin_dtype)
+                    if bundled is not None:
+                        sample_d = _decode_bundled_rows(
+                            sample_d, (device_table or {}).get("efb")
+                            or _efb_dev_from_host(bundled.maps()),
+                            mapper.missing_bin)
+                    Xr = _representative_rows(sample_d, jnp.asarray(table))
+            with prof.region("train.refprofile_margins"):
+                margins = np.asarray(
+                    _bin_space_forest(booster, mapper).predict_margin(Xr))
+            # ``train.refprofile_rollup`` and, where nothing was counted
+            # yet, the host's column passes (``train.refprofile_counts``)
             booster.reference_profile = build_reference_profile(
                 bins, mapper, margins, feature_names=feature_names,
                 meta={"trees": len(booster.trees),
@@ -1754,7 +1795,9 @@ def train(*args, **kwargs) -> Booster:
             _capture_reference_profile(booster, bins, _arg(3, "mapper"),
                                        _arg(6, "feature_names"),
                                        device_table)
-            sp.update(_fit_attrs(booster, bins, mesh, _arg(3, "mapper")))
+            with get_profiler().region("train.fit_attrs"):
+                attrs = _fit_attrs(booster, bins, mesh, _arg(3, "mapper"))
+            sp.update(attrs)
             _tm.get_journal().emit(
                 "fit_end", fit=span,
                 dur_s=round(time.perf_counter() - t0, 3),
@@ -1976,144 +2019,159 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
     from .efb import BundledTable, bundling_applies
     bundled = bins if isinstance(bins, BundledTable) else None
     n, f = bins.shape
-    K = objective.num_model_per_iteration
-    rng = np.random.default_rng(params.seed)
-    bag_rng = np.random.default_rng(params.bagging_seed)
+    # everything before the hand-over to ``train.upload`` /
+    # ``prepare_arrays``: weights, the objective's preparation and
+    # initial score (passes over the labels), the histogram schedule,
+    # the argument checks and the budget guard
+    with get_profiler().region("train.prepare", rows=int(n)):
+        K = objective.num_model_per_iteration
+        rng = np.random.default_rng(params.seed)
+        bag_rng = np.random.default_rng(params.bagging_seed)
 
-    w = np.ones(n) if weights is None else np.asarray(weights, np.float64)
-    objective.prepare(np.asarray(labels), w)
-    # Per-row init scores (initScoreCol) replace boost_from_average, as in
-    # LightGBM; they are a training-time offset not baked into the model.
-    init = objective.init_score(np.asarray(labels), w) \
-        if params.boost_from_average and init_scores is None else 0.0
+        w = np.ones(n) if weights is None \
+            else np.asarray(weights, np.float64)
+        # the objective's passes over the labels (class sums, the base
+        # rate): row-length float64 work, the largest part of this span
+        # on a long table (PERF.md Findings, PR 35)
+        with get_profiler().region("train.label_stats"):
+            objective.prepare(np.asarray(labels), w)
+            # Per-row init scores (initScoreCol) replace
+            # boost_from_average, as in LightGBM; a training-time offset
+            # not baked into the model.
+            init = objective.init_score(np.asarray(labels), w) \
+                if params.boost_from_average and init_scores is None \
+                else 0.0
 
-    use_voting = params.parallelism == "voting"
-    collective, mesh, coll_downgrade = _resolve_collective_cfg(
-        params, mesh, ranking=ranking_info is not None)
-    qbits, qmc, qwire, collective, qdown = _resolve_quantized(
-        params, n, mesh, collective, ranking=ranking_info is not None)
-    cfg = GrowerConfig(
-        num_leaves=params.num_leaves, max_depth=params.max_depth,
-        num_bins=mapper.num_total_bins, lambda_l1=params.lambda_l1,
-        lambda_l2=params.lambda_l2, min_data_in_leaf=params.min_data_in_leaf,
-        min_sum_hessian_in_leaf=params.min_sum_hessian_in_leaf,
-        min_gain_to_split=params.min_gain_to_split,
-        hist_method=params.histogram_method,
-        collective=collective,
-        voting_k=params.top_k if use_voting else 0,
-        use_categorical=mapper.has_categorical,
-        cat_smooth=params.cat_smooth, cat_l2=params.cat_l2,
-        max_cat_threshold=params.max_cat_threshold,
-        max_cat_to_onehot=params.max_cat_to_onehot,
-        quantized_bits=qbits, quantized_seed=params.seed,
-        quantized_max_code=qmc, quantized_wire=qwire,
-        debug_checks=_debug.debug_enabled())
-    coll_sched = _collective_sched_for(cfg, mesh, n, f)
-    hist_sched = _hist_sched_for(cfg, mesh, n)
-    _record_fit_resolution(cfg, collective, coll_downgrade, coll_sched,
-                           quantized_downgrade=qdown, hist_sched=hist_sched)
-    from ..core.mesh import FEATURE_AXIS
-    _fs = int(dict(mesh.shape).get(FEATURE_AXIS, 1)) if mesh is not None \
-        else 1
-    last_fit_info.update(hist_cache_bytes=str(
-        cfg.num_leaves * -(-f // _fs) * cfg.num_bins * 3 * 4))
-    if mesh is None and cfg.compact_rows:
-        # one device: the host knows every segment's rows from the
-        # returned trees' node counts (``_segment_walk_attrs``); a
-        # mesh's shards each hold their own share of a node
-        last_fit_info.update(seg_min_bucket=str(cfg.min_bucket))
-    if bundled is not None:
-        last_fit_info.update(
-            efb_features=str(f), efb_bundles=str(bundled.table.shape[1]),
-            efb_table_bytes=str(bundled.table.nbytes),
-            efb_conflict_rows=str(bundled.conflict_rows))
+        use_voting = params.parallelism == "voting"
+        collective, mesh, coll_downgrade = _resolve_collective_cfg(
+            params, mesh, ranking=ranking_info is not None)
+        qbits, qmc, qwire, collective, qdown = _resolve_quantized(
+            params, n, mesh, collective, ranking=ranking_info is not None)
+        cfg = GrowerConfig(
+            num_leaves=params.num_leaves, max_depth=params.max_depth,
+            num_bins=mapper.num_total_bins, lambda_l1=params.lambda_l1,
+            lambda_l2=params.lambda_l2,
+            min_data_in_leaf=params.min_data_in_leaf,
+            min_sum_hessian_in_leaf=params.min_sum_hessian_in_leaf,
+            min_gain_to_split=params.min_gain_to_split,
+            hist_method=params.histogram_method,
+            collective=collective,
+            voting_k=params.top_k if use_voting else 0,
+            use_categorical=mapper.has_categorical,
+            cat_smooth=params.cat_smooth, cat_l2=params.cat_l2,
+            max_cat_threshold=params.max_cat_threshold,
+            max_cat_to_onehot=params.max_cat_to_onehot,
+            quantized_bits=qbits, quantized_seed=params.seed,
+            quantized_max_code=qmc, quantized_wire=qwire,
+            debug_checks=_debug.debug_enabled())
+        coll_sched = _collective_sched_for(cfg, mesh, n, f)
+        hist_sched = _hist_sched_for(cfg, mesh, n)
+        _record_fit_resolution(cfg, collective, coll_downgrade, coll_sched,
+                               quantized_downgrade=qdown,
+                               hist_sched=hist_sched)
+        from ..core.mesh import FEATURE_AXIS
+        _fs = int(dict(mesh.shape).get(FEATURE_AXIS, 1)) if mesh is not None \
+            else 1
+        last_fit_info.update(hist_cache_bytes=str(
+            cfg.num_leaves * -(-f // _fs) * cfg.num_bins * 3 * 4))
+        if mesh is None and cfg.compact_rows:
+            # one device: the host knows every segment's rows from the
+            # returned trees' node counts (``_segment_walk_attrs``); a
+            # mesh's shards each hold their own share of a node
+            last_fit_info.update(seg_min_bucket=str(cfg.min_bucket))
+        if bundled is not None:
+            last_fit_info.update(
+                efb_features=str(f), efb_bundles=str(bundled.table.shape[1]),
+                efb_table_bytes=str(bundled.table.nbytes),
+                efb_conflict_rows=str(bundled.conflict_rows))
 
-    if params.boosting not in ("gbdt", "goss", "dart", "rf"):
-        raise NotImplementedError(
-            f"boostingType={params.boosting!r} is not supported; "
-            "use 'gbdt', 'goss', 'dart' or 'rf'")
-    use_goss = params.boosting == "goss"
-    use_dart = params.boosting == "dart"
-    use_rf = params.boosting == "rf"
-    if use_rf:
-        if not (params.bagging_freq > 0 and
-                0.0 < params.bagging_fraction < 1.0):
-            raise ValueError(
-                "boostingType='rf' requires bagging: set "
-                "baggingFraction in (0,1) and baggingFreq > 0 "
-                "(as in LightGBM)")
-
-    if use_dart:
-        if params.early_stopping_round > 0:
+        if params.boosting not in ("gbdt", "goss", "dart", "rf"):
             raise NotImplementedError(
-                "boostingType='dart' does not support early stopping "
-                "(dropped-tree rescaling is not invertible by truncation); "
-                "unset earlyStoppingRound")
-    if use_goss:
-        if params.bagging_freq > 0 and params.bagging_fraction < 1.0:
-            raise ValueError("Cannot use bagging in GOSS "
-                             "(as in LightGBM); unset baggingFraction/"
-                             "baggingFreq or use boostingType='gbdt'")
-        if not (0.0 < params.top_rate < 1.0 and
-                0.0 < params.other_rate < 1.0) or \
-                params.top_rate + params.other_rate >= 1.0:
-            raise ValueError("GOSS needs 0 < topRate < 1, "
-                             "0 < otherRate < 1 and topRate + otherRate "
-                             f"< 1, got {params.top_rate}/"
-                             f"{params.other_rate}")
-        k1 = max(1, int(np.ceil(n * params.top_rate)))
-        k2 = max(1, int(np.ceil(n * params.other_rate)))
-        if k1 + k2 >= n:
-            use_goss = False   # rounding on tiny n: nothing to shrink
-            if params.verbosity > 0:
-                log.info("GOSS sample covers every row (n=%d); training "
-                         "falls back to plain gbdt", n)
-        else:
-            goss_amp = (1.0 - params.top_rate) / params.other_rate
-            goss_keys = jax.random.split(
-                jax.random.PRNGKey(params.bagging_seed),
-                params.num_iterations)
+                f"boostingType={params.boosting!r} is not supported; "
+                "use 'gbdt', 'goss', 'dart' or 'rf'")
+        use_goss = params.boosting == "goss"
+        use_dart = params.boosting == "dart"
+        use_rf = params.boosting == "rf"
+        if use_rf:
+            if not (params.bagging_freq > 0 and
+                    0.0 < params.bagging_fraction < 1.0):
+                raise ValueError(
+                    "boostingType='rf' requires bagging: set "
+                    "baggingFraction in (0,1) and baggingFreq > 0 "
+                    "(as in LightGBM)")
 
-    use_mesh = mesh is not None and int(np.prod(
-        [mesh.shape[a] for a in mesh.axis_names])) > 1
-    if bundled is not None and not bundling_applies(
-            mapper, True, ranker=grad_fn_override is not None
-            or ranking_info is not None, mesh=mesh if use_mesh else None,
-            voting=use_voting, goss=use_goss, dart=use_dart):
-        raise ValueError(
-            "this fit cannot take a bundled table (efb.bundling_applies "
-            "says where bundles apply): hand it the unbundled bins")
-    # scale guard (BASELINE config 5): estimate per-device HBM before the
-    # first compile and fail fast with remediation if the fit can't fit
-    from .budget import check_fit_budget
-    _dn = (int(mesh.shape["data"]) if use_mesh else 1)
-    _bagging = params.bagging_freq > 0 and params.bagging_fraction < 1.0
-    # model the chunk the loop will ACTUALLY use: with nothing forcing a
-    # host sync the whole fit is ONE scan stacking T*K trees on device
-    _chunk = params.num_iterations
-    if _bagging:
-        _chunk = min(_chunk, 64)
-    if val_bins is not None:
-        _chunk = min(_chunk, 64)
-    if callbacks:
-        _chunk = min(_chunk, 8)
-    if params.fault_tolerant_retries > 0:
-        _chunk = min(_chunk, 32)
-    if params.checkpoint_dir:
-        _chunk = min(_chunk, max(1, params.checkpoint_chunk))
-    check_fit_budget(
-        n_local=-(-n // _dn), num_features=f,
-        num_bundles=bundled.table.shape[1] if bundled is not None else None,
-        num_bins=mapper.num_total_bins, num_leaves=params.num_leaves,
-        num_class=K, chunk=_chunk,
-        bin_itemsize=np.dtype(mapper.bin_dtype).itemsize,
-        bagging=_bagging,
-        n_val_local=(-(-val_bins.shape[0] // _dn)
-                     if val_bins is not None else 0),
-        data_shards=_dn, verbosity=params.verbosity,
-        hist_on_chip=hist_sched["fused"] == hist_sched["sites"],
-        rank_layout_bytes=(grad_fn_override.nbytes
-                           if grad_fn_override is not None else 0))
+        if use_dart:
+            if params.early_stopping_round > 0:
+                raise NotImplementedError(
+                    "boostingType='dart' does not support early stopping "
+                    "(dropped-tree rescaling is not invertible by "
+                    "truncation); unset earlyStoppingRound")
+        if use_goss:
+            if params.bagging_freq > 0 and params.bagging_fraction < 1.0:
+                raise ValueError("Cannot use bagging in GOSS "
+                                 "(as in LightGBM); unset baggingFraction/"
+                                 "baggingFreq or use boostingType='gbdt'")
+            if not (0.0 < params.top_rate < 1.0 and
+                    0.0 < params.other_rate < 1.0) or \
+                    params.top_rate + params.other_rate >= 1.0:
+                raise ValueError("GOSS needs 0 < topRate < 1, "
+                                 "0 < otherRate < 1 and topRate + otherRate "
+                                 f"< 1, got {params.top_rate}/"
+                                 f"{params.other_rate}")
+            k1 = max(1, int(np.ceil(n * params.top_rate)))
+            k2 = max(1, int(np.ceil(n * params.other_rate)))
+            if k1 + k2 >= n:
+                use_goss = False   # rounding on tiny n: nothing to shrink
+                if params.verbosity > 0:
+                    log.info("GOSS sample covers every row (n=%d); training "
+                             "falls back to plain gbdt", n)
+            else:
+                goss_amp = (1.0 - params.top_rate) / params.other_rate
+                goss_keys = jax.random.split(
+                    jax.random.PRNGKey(params.bagging_seed),
+                    params.num_iterations)
+
+        use_mesh = mesh is not None and int(np.prod(
+            [mesh.shape[a] for a in mesh.axis_names])) > 1
+        if bundled is not None and not bundling_applies(
+                mapper, True, ranker=grad_fn_override is not None
+                or ranking_info is not None, mesh=mesh if use_mesh else None,
+                voting=use_voting, goss=use_goss, dart=use_dart):
+            raise ValueError(
+                "this fit cannot take a bundled table (efb.bundling_applies "
+                "says where bundles apply): hand it the unbundled bins")
+        # scale guard (BASELINE config 5): estimate per-device HBM before the
+        # first compile and fail fast with remediation if the fit can't fit
+        from .budget import check_fit_budget
+        _dn = (int(mesh.shape["data"]) if use_mesh else 1)
+        _bagging = params.bagging_freq > 0 and params.bagging_fraction < 1.0
+        # model the chunk the loop will ACTUALLY use: with nothing forcing a
+        # host sync the whole fit is ONE scan stacking T*K trees on device
+        _chunk = params.num_iterations
+        if _bagging:
+            _chunk = min(_chunk, 64)
+        if val_bins is not None:
+            _chunk = min(_chunk, 64)
+        if callbacks:
+            _chunk = min(_chunk, 8)
+        if params.fault_tolerant_retries > 0:
+            _chunk = min(_chunk, 32)
+        if params.checkpoint_dir:
+            _chunk = min(_chunk, max(1, params.checkpoint_chunk))
+        check_fit_budget(
+            n_local=-(-n // _dn), num_features=f,
+            num_bundles=(bundled.table.shape[1] if bundled is not None
+                         else None),
+            num_bins=mapper.num_total_bins, num_leaves=params.num_leaves,
+            num_class=K, chunk=_chunk,
+            bin_itemsize=np.dtype(mapper.bin_dtype).itemsize,
+            bagging=_bagging,
+            n_val_local=(-(-val_bins.shape[0] // _dn)
+                         if val_bins is not None else 0),
+            data_shards=_dn, verbosity=params.verbosity,
+            hist_on_chip=hist_sched["fused"] == hist_sched["sites"],
+            rank_layout_bytes=(grad_fn_override.nbytes
+                               if grad_fn_override is not None else 0))
     if use_mesh:
         if ranking_info is not None:
             if init_scores is not None:
@@ -2176,8 +2234,9 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             scores0 = scores0 + (iscores if scores0.ndim == iscores.ndim
                                  else iscores[:, None])
         scores = jnp.asarray(scores0)
-        sp["bytes"] = int(bins_d.nbytes + labels_d.nbytes
-                          + weights_d.nbytes + scores.nbytes)
+        upload_bytes = sp["bytes"] = int(
+            bins_d.nbytes + labels_d.nbytes + weights_d.nbytes
+            + scores.nbytes)
 
     # A ranker's gradient rides the ordinary programs: its query layout
     # goes up as their ``labels``, its row multipliers as their
@@ -2189,6 +2248,7 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             step_obj = rank.objective
             step_labels, step_weights = rank.upload()
             sp["bytes"] = rank.nbytes
+        upload_bytes += rank.nbytes
         lay = rank.layout
         last_fit_info.update(
             rank_queries=str(lay.queries),
@@ -2363,6 +2423,9 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
                     efb=efb_dev, rf=use_rf))
         cb_list: List[TreeArrays] = []
         it = 0
+        # what the first chunk's program waits for (``train.upload_wait``):
+        # the operands it does not donate, and the bytes that went up
+        uploaded = ((bins_d, step_labels, step_weights), upload_bytes)
         if ckpt:
             snap = _ckpt_load(ckpt, ckpt_fp)
             if snap is None:
@@ -2492,7 +2555,9 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
                                 params.num_iterations)
             else:
                 trees_st, scores, val_scores, val_hist = _dispatch_chunk(
-                    run_chunk, scores, val_scores, it, C * K, t_chunk)
+                    run_chunk, scores, val_scores, it, C * K, t_chunk,
+                    uploaded=uploaded)
+                uploaded = None
             trees_chunks.append(trees_st)
             _monitor_chunk(it, it + C, time.perf_counter() - t_chunk,
                            n, K, cfg.hist_method, objective, scores,
@@ -3063,10 +3128,11 @@ def _export_booster(chunks, K, stop_iter, init, params, objective, mapper,
                     rf: bool = False) -> Booster:
     """The tail every trainer shares: the device trees to the host
     (``train.fetch_trees``), then ``train.finalize``: cut to
-    ``stop_iter`` iterations and to the last iteration that grew, bake
-    in the dart weights (``dart_scales``: one per ITERATION, shared by
-    its K class trees) or the forest average (``rf``), and build the
-    Booster."""
+    ``stop_iter`` iterations and to the last iteration that grew
+    (``train.host_trees``: the HostTrees of those that stay), bake in
+    the dart weights (``dart_scales``: one per ITERATION, shared by its
+    K class trees) or the forest average (``rf``), and build the Booster
+    (``train.booster``)."""
     trees, nls = _fetch_host_trees(chunks, params.num_leaves)
     with get_profiler().region("train.finalize"):
         trees, nls = trees[:stop_iter * K], nls[:stop_iter * K]
@@ -3074,8 +3140,9 @@ def _export_booster(chunks, K, stop_iter, init, params, objective, mapper,
                                                params.verbosity)
         # real-valued thresholds and, for categorical splits, bitsets over
         # raw values (``train.cat_bitsets``), for the trees that stay
-        trees = [host_tree_from_arrays(t, mapper, mapper.missing_bin)
-                 for t in trees]
+        with get_profiler().region("train.host_trees", trees=len(trees)):
+            trees = [host_tree_from_arrays(t, mapper, mapper.missing_bin)
+                     for t in trees]
         if dart_scales is not None:
             for t, s in zip(trees, np.repeat(dart_scales, K)):
                 t.leaf_value = t.leaf_value * s
@@ -3083,8 +3150,9 @@ def _export_booster(chunks, K, stop_iter, init, params, objective, mapper,
                 t.shrinkage = s
         elif rf:
             _rf_average_trees(trees, K)
-        return _finalize_booster(trees, K, init, params, objective,
-                                 mapper, feature_names, f, stop_iter)
+        with get_profiler().region("train.booster"):
+            return _finalize_booster(trees, K, init, params, objective,
+                                     mapper, feature_names, f, stop_iter)
 
 
 def _train_distributed_dart(bins, labels, w, mapper, objective, params,
@@ -3333,6 +3401,10 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
             return prepare_arrays(bins_np, labels_np, w_np, mesh, K, init,
                                   init_scores)
     bins_d, labels_d, w_d, real, scores, rp, fp = prep_arrays()
+    # what the first chunk's program waits for (``train.upload_wait``):
+    # the sharded operands it does not donate, and ``train.upload``'s bytes
+    uploaded = ((bins_d, labels_d, w_d, real),
+                sum(a.nbytes for a in (bins_d, labels_d, w_d, real, scores)))
     if shard_data is None:
         real_pos = np.arange(n)
         n_padded = n + rp
@@ -3569,7 +3641,8 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
         else:
             trees_st, scores, val_scores, val_hist = _dispatch_chunk(
                 run_step, scores, val_scores, it, C * K, t_chunk,
-                mesh=True)
+                uploaded=uploaded, mesh=True)
+            uploaded = None
         chunks.append(trees_st)
         # objective=None: the gang's score vector is sharded (not fully
         # addressable on any one controller), so train loss is skipped

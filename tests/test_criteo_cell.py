@@ -250,9 +250,13 @@ def test_categorical_fit_says_its_splits_and_words():
     assert root["attrs"]["cat_features"] == 26
     assert root["attrs"]["cat_splits"] == cat_nodes
     assert root["attrs"]["cat_bitset_words"] == words
+    # one span a tree that holds a categorical split, inside the loop
+    # over the trees that ``train.finalize`` runs
     built = [s for s in spans if s["name"] == "train.cat_bitsets"]
     finalize = next(s for s in spans if s["name"] == "train.finalize")
-    assert built and all(s["parent"] == finalize["id"] for s in built)
+    host_trees = next(s for s in spans if s["name"] == "train.host_trees")
+    assert host_trees["parent"] == finalize["id"]
+    assert built and all(s["parent"] == host_trees["id"] for s in built)
     assert sum(s["attrs"]["words"] for s in built) == words
 
 

@@ -20,11 +20,27 @@ from mmlspark_tpu.gbdt.binning import fit_bin_mapper
 from mmlspark_tpu.gbdt.grower import GrowerConfig, make_feat_info
 from mmlspark_tpu.gbdt.objectives import BinaryObjective, get_objective
 
-#: span -> how often a fit of one chunk emits it
+ROOT = "train.fit"
+#: span -> (how often a fit of one chunk emits it, the span it lies in)
 FIT_SPANS = {
-    "train.upload": 1, "train.build_step": 1, "train.launch": 1,
-    "train.device_wait": 1, "train.monitor": 1, "train.fetch_trees": 1,
-    "train.finalize": 1, "train.reference_profile": 1,
+    "train.prepare": (1, ROOT), "train.label_stats": (1, "train.prepare"),
+    "train.upload": (1, ROOT),
+    "train.build_step": (1, ROOT), "train.launch": (1, ROOT),
+    "train.upload_wait": (1, ROOT), "train.device_wait": (1, ROOT),
+    "train.monitor": (1, ROOT),
+    "train.fetch_trees": (1, ROOT),
+    "train.fetch_wait": (1, "train.fetch_trees"),
+    "train.finalize": (1, ROOT),
+    "train.host_trees": (1, "train.finalize"),
+    "train.booster": (1, "train.finalize"),
+    "train.reference_profile": (1, ROOT),
+    # the sampled rows are taken between the count pass's dispatch and
+    # the wait for it: each of the two opens twice
+    "train.refprofile_counts": (2, "train.reference_profile"),
+    "train.refprofile_sample": (2, "train.reference_profile"),
+    "train.refprofile_margins": (1, "train.reference_profile"),
+    "train.refprofile_rollup": (1, "train.reference_profile"),
+    "train.fit_attrs": (1, ROOT),
 }
 DEVICE_SCOPES = ("root_hist", "row_gather", "segment_hist", "partition",
                  "split_scan", "cache_update", "reduce", "score_update",
@@ -241,18 +257,32 @@ def test_fit_emits_every_span_once_inside_its_root(fit_inputs, devices):
     # fits, because the suite's other workers share these cores
     for _ in range(3):
         booster, root, spans = _fit(fit_inputs, mesh)
-        covered = sum(s["end"] - s["start"] for s in spans)
+        covered = sum(s["end"] - s["start"] for s in spans
+                      if s["parent"] == root["id"])
         if covered >= 0.95 * (root["end"] - root["start"]):
             break
     assert covered >= 0.95 * (root["end"] - root["start"])
     counts = {}
     for s in spans:
         counts[s["name"]] = counts.get(s["name"], 0) + 1
-    assert counts == FIT_SPANS
+    assert counts == {name: n for name, (n, _) in FIT_SPANS.items()}
+    by_id = {s["id"]: s for s in spans + [root]}
     for s in spans:
-        assert s["parent"] == root["id"] and s["fit"] == root["fit"]
-        assert root["start"] <= s["start"] <= s["end"] <= root["end"]
+        parent = by_id[s["parent"]]
+        assert parent["name"] == FIT_SPANS[s["name"]][1], s["name"]
+        assert s["fit"] == root["fit"]
+        assert parent["start"] <= s["start"] <= s["end"] <= parent["end"]
     assert root["fit"] is not None and root["parent"] is None
+    # the capture, the export and the fetch are their children's: what
+    # the children leave of each is under 20 ms a tree (ISSUE 35's rule
+    # for the chip's cells, kept here at a size where it is easy)
+    for name in ("train.reference_profile", "train.finalize",
+                 "train.fetch_trees"):
+        whole, = [s for s in spans if s["name"] == name]
+        named = sum(s["end"] - s["start"] for s in spans
+                    if s["parent"] == whole["id"])
+        assert named > 0
+        assert whole["end"] - whole["start"] - named < 0.020 * 6, name
     n, f = fit_inputs["bins"].shape
     a = root["attrs"]
     assert (a["trees"], a["rows"], a["features"], a["devices"]) == \
@@ -262,6 +292,15 @@ def test_fit_emits_every_span_once_inside_its_root(fit_inputs, devices):
     assert by_name["train.fetch_trees"]["attrs"]["bytes"] > 0
     assert by_name["train.reference_profile"]["attrs"]["rows"] == n
     assert by_name["train.device_wait"]["attrs"] == {"it": 0, "trees": 6}
+    # the wait names the bytes the upload said went up, and the capture's
+    # children where the rows were counted and how many features rolled up
+    assert by_name["train.upload_wait"]["attrs"]["bytes"] == \
+        by_name["train.upload"]["attrs"]["bytes"]
+    assert by_name["train.prepare"]["attrs"] == {"rows": n}
+    assert {s["attrs"]["where"] for s in spans
+            if s["name"] == "train.refprofile_counts"} == {"device"}
+    assert by_name["train.refprofile_rollup"]["attrs"] == {"features": f}
+    assert by_name["train.host_trees"]["attrs"] == {"trees": 6}
     if devices == 1:
         assert (a["collective_count"], a["collective_bytes"]) == (0, 0)
         assert by_name["train.launch"]["attrs"]["compile_misses"] == 0
@@ -431,7 +470,9 @@ def test_fit_counts_the_rows_its_segments_walked(fit_inputs, devices):
                for p, t in zip(per_tree, booster.trees))
 
 
-def test_chunked_fit_emits_launch_wait_monitor_per_chunk(fit_inputs):
+@pytest.mark.parametrize("devices", [1, 4], ids=["serial", "mesh4"])
+def test_chunked_fit_emits_launch_wait_monitor_per_chunk(fit_inputs,
+                                                         devices):
     calls = []
     prof = get_profiler()
     before = {s["id"] for s in prof.spans()}
@@ -440,16 +481,59 @@ def test_chunked_fit_emits_launch_wait_monitor_per_chunk(fit_inputs):
                  fit_inputs["params"].__class__(
                      **{**fit_inputs["params"].__dict__,
                         "num_iterations": 10}),
-                 callbacks=[lambda it, trees: calls.append(it)])
-    names = [s["name"] for s in prof.spans() if s["id"] not in before]
+                 callbacks=[lambda it, trees: calls.append(it)],
+                 mesh=_mesh4() if devices == 4 else None)
+    new = sorted((s for s in prof.spans() if s["id"] not in before),
+                 key=lambda s: s["start"])
+    names = [s["name"] for s in new]
     assert len(calls) == 10
     # callbacks bound the chunk at 8 iterations: chunks of 8 and 2
     for per_chunk in ("train.launch", "train.device_wait",
                       "train.monitor"):
         assert names.count(per_chunk) == 2
-    for per_fit in ("train.fit", "train.upload", "train.fetch_trees",
-                    "train.finalize", "train.reference_profile"):
+    for per_fit in ("train.fit", "train.prepare", "train.upload",
+                    "train.upload_wait", "train.fetch_trees",
+                    "train.finalize", "train.reference_profile",
+                    "train.fit_attrs"):
         assert names.count(per_fit) == 1
+    # the table is waited for once, by the fit's first chunk: after its
+    # launch has returned, before its device wait opens
+    waits = [n for n in names if n in ("train.launch", "train.upload_wait",
+                                       "train.device_wait")]
+    assert waits == ["train.launch", "train.upload_wait",
+                     "train.device_wait", "train.launch",
+                     "train.device_wait"]
+    launch, upload_wait, device_wait = [
+        s for s in new if s["name"] in waits][:3]
+    assert launch["end"] <= upload_wait["start"] \
+        <= upload_wait["end"] <= device_wait["start"]
+
+
+def test_a_categorical_fits_bitsets_lie_in_host_trees():
+    """``train.cat_bitsets`` (one a tree with a categorical split) is a
+    child of ``train.host_trees``, which is ``train.finalize``'s."""
+    rng = np.random.default_rng(3)
+    n = 4000
+    cat = rng.integers(0, 12, n)
+    X = np.column_stack([cat, rng.normal(size=n)]).astype(np.float32)
+    y = (np.isin(cat, (1, 4, 7)) ^ (X[:, 1] > 1.0)).astype(np.float32)
+    est = LightGBMClassifier(numIterations=3, numLeaves=7, verbosity=0,
+                             categoricalSlotIndexes=[0])
+    mapper = fit_bin_mapper(X, max_bin=est.getMaxBin(), seed=est.getSeed(),
+                            categorical_features=[0])
+    prof = get_profiler()
+    before = {s["id"] for s in prof.spans()}
+    booster = engine.train(
+        mapper.transform_packed(X), est._prepare_labels(y), None, mapper,
+        get_objective(est.getObjective(), num_class=1,
+                      **est._objective_kwargs()), est._train_params())
+    new = {s["id"]: s for s in prof.spans() if s["id"] not in before}
+    bitsets = [s for s in new.values() if s["name"] == "train.cat_bitsets"]
+    assert len(bitsets) == sum(t.num_cat > 0 for t in booster.trees) > 0
+    for s in bitsets:
+        host_trees = new[s["parent"]]
+        assert host_trees["name"] == "train.host_trees"
+        assert new[host_trees["parent"]]["name"] == "train.finalize"
 
 
 def test_launch_counts_its_own_compiles():
@@ -488,18 +572,140 @@ def test_disabled_profiler_a_fit_records_no_span(fit_inputs, monkeypatch):
         return real(name, **kw)
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", spy)
-    before = len(prof.spans())
-    prof.configure(enabled=False)
-    try:
-        booster = engine.train(
+    waited = []
+    real_wait = jax.block_until_ready
+
+    def wait_spy(x):
+        waited.append(x)
+        return real_wait(x)
+
+    def fit():
+        return engine.train(
             fit_inputs["bins"], fit_inputs["labels"], None,
             fit_inputs["mapper"], fit_inputs["objective"],
             fit_inputs["params"])
+
+    monkeypatch.setattr(engine.jax, "block_until_ready", wait_spy)
+    before = len(prof.spans())
+    prof.configure(enabled=False)
+    try:
+        booster = fit()
     finally:
         prof.configure(enabled=True)
     assert len(booster.trees) == 6
     assert len(prof.spans()) == before
     assert [n for n in entered if n.startswith("train.")] == []
+    # one wait a chunk, for its trees: the table's is the profiler's
+    assert len(waited) == 1
+    del waited[:]
+    enabled = fit()
+    assert len(waited) == 2
+    # the spans observe the fit, they are no part of it
+    assert enabled.save_native_model_string() == \
+        booster.save_native_model_string()
+
+
+# ------------------------------------------------------- idle by span
+
+
+#: two fits on one device's clock; in the first the table arrives while
+#: ``train.upload_wait`` is open and the device then works under
+#: ``train.device_wait`` (3.5 .. 7.5 s), the capture's count pass runs at
+#: 8.2 .. 8.6 s inside ``train.refprofile_counts``
+HAND_ANNOTATIONS = [
+    ("train.fit", 0.0, 10.0),
+    ("train.prepare", 0.0, 1.0),
+    ("train.upload", 1.0, 2.0),
+    ("train.launch", 2.0, 2.5),
+    ("train.upload_wait", 2.5, 3.5),
+    ("train.device_wait", 3.5, 7.5),
+    ("train.reference_profile", 8.0, 9.5),
+    ("train.refprofile_counts", 8.0, 8.75),
+    ("train.refprofile_rollup", 9.0, 9.5),
+    ("train.fit", 12.0, 20.0),
+    ("train.device_wait", 13.0, 19.0),
+    # a span of another thread that outlives the trace's last fit
+    ("bin.bundle_plan", 19.5, 25.0),
+]
+HAND_EVENTS = [
+    (3.5, 5.0), (4.0, 7.25),            # nested: a loop and its body
+    (8.25, 8.5),                        # the count pass
+    (10.5, 11.0),                       # between the fits
+    (12.5, 18.0),                       # enqueued before the wait opened
+    (30.0, 31.0),                       # after the last fit: not counted
+]
+
+
+def test_idle_is_charged_to_the_innermost_open_span():
+    from mmlspark_tpu.core.profiling import charge_idle
+    got = dict((name, secs) for secs, name in
+               charge_idle(HAND_EVENTS, HAND_ANNOTATIONS))
+    assert got == pytest.approx({
+        "train.prepare": 1.0, "train.upload": 1.0, "train.launch": 0.5,
+        "train.upload_wait": 1.0,
+        # the drain after the last op, 7.25 .. 7.5
+        "train.device_wait": 0.25 + 1.0,
+        # a gap cut at span boundaries: 7.5 .. 8.25 is the fit's until
+        # the capture opens at 8.0, then the count pass's dispatch
+        "train.refprofile_counts": 0.25 + 0.25,
+        "train.reference_profile": 0.25,
+        "train.refprofile_rollup": 0.5,
+        "train.fit": 0.5 + 0.5 + 0.5 + 0.5,
+        # 10 .. 12 outside any fit, less the op that ran there
+        "between fits": 1.5,
+        "bin.bundle_plan": 0.5,
+    })
+    # largest first, and nothing outside the fits' extent
+    rows = charge_idle(HAND_EVENTS, HAND_ANNOTATIONS)
+    assert [secs for secs, _ in rows] == sorted(
+        (secs for secs, _ in rows), reverse=True)
+    busy = (7.25 - 3.5) + 0.25 + 0.5 + 5.5
+    assert sum(secs for secs, _ in rows) == pytest.approx(20.0 - busy)
+
+
+@pytest.mark.parametrize("case", ["no fit", "no events", "unsorted"])
+def test_idle_by_span_on_the_edges(case):
+    from mmlspark_tpu.core.profiling import charge_idle
+    if case == "no fit":
+        assert charge_idle(HAND_EVENTS, [("train.upload", 0.0, 1.0)]) == []
+    elif case == "no events":
+        got = dict((n, s) for s, n in charge_idle(
+            [], [("train.fit", 1.0, 3.0), ("train.upload", 1.0, 2.0)]))
+        assert got == pytest.approx({"train.upload": 1.0, "train.fit": 1.0})
+    else:
+        shuffled = list(reversed(HAND_EVENTS))
+        assert charge_idle(shuffled, HAND_ANNOTATIONS) == \
+            charge_idle(HAND_EVENTS, HAND_ANNOTATIONS)
+
+
+def test_idle_by_span_reads_a_fits_own_trace(fit_inputs, tmp_path):
+    """On a real trace (the CPU's: its host threads' HLO-op events stand
+    in for the device) the rows are the fit's own spans and add up to
+    the fit's seconds less the busy union."""
+    from mmlspark_tpu.core.profiling import idle_by_span, trace_tables
+    assert idle_by_span(str(tmp_path)) == []
+    _fit(fit_inputs)                          # compiles are no part of it
+    with jax.profiler.trace(str(tmp_path)):
+        _, root, spans = _fit(fit_inputs)
+    rows = idle_by_span(str(tmp_path))
+    total_ms, last = rows[-1]
+    assert last == "total_idle_ms"
+    assert total_ms == pytest.approx(sum(ms for ms, _ in rows[:-1]),
+                                     abs=1e-2)
+    names = {name for _, name in rows[:-1]}
+    assert names <= {ROOT, *FIT_SPANS}
+    # the host parts of a fit do not run on the device
+    assert {"train.label_stats", "train.refprofile_rollup"} <= names
+    fit_ms = (root["end"] - root["start"]) * 1e3
+    assert 0 < total_ms <= fit_ms * 1.001
+    # a host span with no device work in it is idle for all it lasts
+    by_name = dict((name, ms) for ms, name in rows[:-1])
+    rollup, = [s for s in spans if s["name"] == "train.refprofile_rollup"]
+    assert by_name["train.refprofile_rollup"] == pytest.approx(
+        (rollup["end"] - rollup["start"]) * 1e3, rel=0.05, abs=0.5)
+    text = trace_tables(str(tmp_path))
+    assert "idle device time by the host's span" in text
+    assert "train.prepare" in text and "total_idle_ms" in text
 
 
 # ---------------------------------------------------------- device scopes

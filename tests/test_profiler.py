@@ -48,7 +48,6 @@ class TestPhaseAttribution:
         p.record_phase("a", 0.1)
         with p.phase("b"):
             pass
-        p.dispatch("site", 0.1, 0.1, 1)
         p.span("c", 0.1, journal=True)
         snap = p.snapshot()
         assert snap["phases"]["stages"] == {}
@@ -103,19 +102,12 @@ class TestCompileLedger:
             f = jax.jit(lambda x: x * 2.0 + 1.0)
             x = jnp.ones(11)                  # unique shape: compiles
             seq0 = p.compile_seq()
-            t0 = time.perf_counter()
-            out = f(x)
-            t_host = time.perf_counter()
-            np.asarray(out)
-            p.dispatch("test_site", t_host - t0,
-                       time.perf_counter() - t_host,
-                       p.compile_seq() - seq0)
+            np.asarray(f(x))
+            p.count_dispatch("test_site", p.compile_seq() - seq0)
             assert p.compile_seq() > seq0, "first call must compile"
             seq1 = p.compile_seq()
-            t0 = time.perf_counter()
             np.asarray(f(x))                  # warm: cache hit
-            p.dispatch("test_site", time.perf_counter() - t0, 0.0,
-                       p.compile_seq() - seq1)
+            p.count_dispatch("test_site", p.compile_seq() - seq1)
             led = p.snapshot()["dispatch"]["test_site"]
             assert led["misses"] >= 1
             assert led["hits"] >= 1
@@ -133,6 +125,44 @@ class TestCompileLedger:
 
 
 # ---------------------------------------------------------------- sampler
+
+
+class TestMemoryWatermarks:
+    def test_reserved_bytes_are_sampled_beside_bytes_in_use(
+            self, monkeypatch):
+        """The TPU runtime counts the loaded programs' temporaries apart,
+        as reserved: the watermark gauge carries both kinds, so that
+        ``peak_bytes_in_use + peak_bytes_reserved`` is the benchmark's
+        ``peak_hbm_bytes``."""
+        import jax
+        jax.devices()                      # the backend is initialized
+
+        class Chip:
+            platform, id = "tpu", 0
+
+            def memory_stats(self):
+                return {"bytes_in_use": 10, "peak_bytes_in_use": 30,
+                        "bytes_reserved": 5, "peak_bytes_reserved": 70,
+                        "bytes_limit": 1000, "num_allocs": 3}
+
+        class NoStats:
+            platform, id = "cpu", 1
+
+            def memory_stats(self):
+                return None
+
+        monkeypatch.setattr(jax, "local_devices",
+                            lambda: [Chip(), NoStats()])
+        p = Profiler(enabled=True)
+        mem = p.snapshot()["memory_bytes"]
+        assert mem == {"tpu:0/bytes_in_use": 10.0,
+                       "tpu:0/peak_bytes_in_use": 30.0,
+                       "tpu:0/bytes_reserved": 5.0,
+                       "tpu:0/peak_bytes_reserved": 70.0,
+                       "tpu:0/bytes_limit": 1000.0}
+        text = p.render_prometheus()
+        assert ('mmlspark_tpu_profile_memory_bytes{device="tpu:0",'
+                'kind="peak_bytes_reserved"} 70') in text
 
 
 class TestSampler:
@@ -192,7 +222,7 @@ class TestExposition:
     def test_all_profile_families_render_when_seeded(self):
         p = Profiler(enabled=True)
         p.record_phase("scoring.score", 0.002)
-        p.dispatch("scoring", 1e-4, 2e-4, 1)
+        p.count_dispatch("scoring", 1)
         p._on_jax_duration("/jax/core/compile/backend_compile_duration",
                            0.01)
         p.record_memory("tpu:0", "bytes_in_use", 123456)
@@ -318,11 +348,17 @@ class TestEngineWiring:
         assert len(spans) > before, "boost chunks must journal spans"
         s = spans[-1]
         assert "host_ms" in s and "device_ms" in s and "fit" in s
-        stages = prof.snapshot()["phases"]["stages"]
-        assert stages.get("train.boost_chunk.dispatch_host",
-                          {}).get("count", 0) >= 1
-        assert stages.get("train.boost_chunk.device_wait",
-                          {}).get("count", 0) >= 1
+        # the same helper's regions are the chunk's phases, and its
+        # dispatch is in the hit/miss ledger
+        snap = prof.snapshot()
+        for phase in ("train.launch", "train.upload_wait",
+                      "train.device_wait", "train.boost_chunk"):
+            assert snap["phases"]["stages"].get(
+                phase, {}).get("count", 0) >= 1, phase
+        led = snap["dispatch"]["train.boost_chunk"]
+        assert led["hits"] + led["misses"] >= 1
+        assert not [k for k in snap["phases"]["stages"]
+                    if k.startswith("train.boost_chunk.")]
 
 
 # ------------------------------------------------------- flight recorder

@@ -1007,7 +1007,7 @@ class TestMetricFamilyDocGuard:
         reg.register_exposition("slo", mon.render_prometheus)
         prof = Profiler(enabled=True)
         prof.record_phase("scoring.score", 0.002)
-        prof.dispatch("scoring", 1e-4, 2e-4, 1)
+        prof.count_dispatch("scoring", 1)
         prof._on_jax_duration(
             "/jax/core/compile/backend_compile_duration", 0.01)
         prof.record_memory("tpu:0", "bytes_in_use", 1 << 20)

@@ -2,8 +2,9 @@
 
 Writes a perfetto/tensorboard trace under ``artifacts/trace_<backend>/`` and
 prints device self time by the grower's named scopes
-(``core.profiling.summarize_trace``), so the hot spots are visible
-without a UI.
+(``core.profiling.summarize_trace``) and the device's idle time by the
+host's span (``core.profiling.idle_by_span``), so the hot spots are
+visible without a UI.
 
 Usage: python tools/profile_boost_step.py [--rows 400000] [--steps 3]
 """
@@ -33,7 +34,8 @@ def main():
 
     import jax.numpy as jnp
     import numpy as np
-    from mmlspark_tpu.core.profiling import summarize_trace
+    from mmlspark_tpu.core.profiler import get_profiler
+    from mmlspark_tpu.core.profiling import idle_by_span, summarize_trace
     from mmlspark_tpu.gbdt.grower import (GrowerConfig, grow_tree,
                                           make_feat_info)
     from mmlspark_tpu.gbdt.objectives import BinaryObjective
@@ -80,10 +82,15 @@ def main():
     per_step = (time.perf_counter() - t0) / 3
     print(f"steady-state boost step: {per_step*1e3:.1f} ms")
 
+    # the engine's span names, so that the idle table reads as a fit's
+    prof = get_profiler()
     with jax.profiler.trace(out_dir):
-        for _ in range(args.steps):
-            tree, scores = boost_step(bins, binsT, scores)
-        jax.block_until_ready((tree, scores))
+        with prof.region("train.fit"):
+            for _ in range(args.steps):
+                with prof.region("train.launch"):
+                    tree, scores = boost_step(bins, binsT, scores)
+            with prof.region("train.device_wait"):
+                jax.block_until_ready((tree, scores))
     print(f"trace written to {out_dir}")
     rows = summarize_trace(out_dir)
     if not rows:
@@ -93,6 +100,12 @@ def main():
           f"(total {rows[-1][0]:.1f} ms):")
     for ms, name in rows[:-1]:
         print(f"  {ms / args.steps:9.2f} ms/step  {name[:100]}")
+    idle = idle_by_span(out_dir)
+    if idle:
+        print(f"idle device time by the host's span (total "
+              f"{idle[-1][0]:.1f} ms):")
+        for ms, name in idle[:-1]:
+            print(f"  {ms / args.steps:9.2f} ms/step  {name}")
 
 
 if __name__ == "__main__":
