@@ -73,6 +73,15 @@ PROFILE_FORMAT = 1
 EPS = 1e-4
 
 
+def _edge_positions(n: int, max_edges: int) -> np.ndarray:
+    """Indices of the at most ``max_edges`` of ``n`` ascending edges a
+    profile keeps, evenly spaced by index."""
+    if n <= max_edges:
+        return np.arange(n)
+    return np.unique(np.linspace(0, n - 1, max_edges)
+                     .round().astype(np.int64))
+
+
 def downsample_edges(edges: np.ndarray,
                      max_edges: int = MAX_PROFILE_EDGES) -> np.ndarray:
     """At most ``max_edges`` of ``edges``, evenly spaced by INDEX (i.e.
@@ -82,9 +91,7 @@ def downsample_edges(edges: np.ndarray,
     edges = np.asarray(edges, np.float64)
     if len(edges) <= max_edges:
         return edges
-    idx = np.unique(np.linspace(0, len(edges) - 1, max_edges)
-                    .round().astype(np.int64))
-    return edges[idx]
+    return edges[_edge_positions(len(edges), max_edges)]
 
 
 class StreamSketch:
@@ -400,9 +407,12 @@ class ReferenceProfile:
                  meta: Optional[Dict[str, Any]] = None):
         self.feature_edges = [np.asarray(e, np.float64)
                               for e in feature_edges]
-        self.feature_sketches = [dict(s) for s in feature_sketches]
+        # the snapshot dicts are kept as handed over, not copied: every
+        # caller builds them for this profile (2000 of them at Epsilon's
+        # width)
+        self.feature_sketches = list(feature_sketches)
         self.margin_edges = np.asarray(margin_edges, np.float64)
-        self.margin_sketch = dict(margin_sketch)
+        self.margin_sketch = margin_sketch
         f = len(self.feature_edges)
         self.feature_names = list(feature_names) if feature_names \
             else [f"f{j}" for j in range(f)]
@@ -469,6 +479,70 @@ class ReferenceProfile:
                    meta=d.get("meta"))
 
 
+#: the snapshot's bucket keys, ``str(i)``, made once
+_BUCKET_KEYS = tuple(str(i) for i in range(MAX_PROFILE_EDGES + 1))
+
+
+def _rollup_features(fine: np.ndarray, mapper, max_edges: int):
+    """Every feature's coarse edges and sketch snapshot from its fine
+    counts ``fine`` ``(f, B)``, for a group of features at a time:
+    returns ``(edges, snapshots, groups)``, the first two in feature
+    order.
+
+    What regroups a feature's fine bins is fixed by its kind alone: a
+    categorical feature's ladder is empty and every bin below the
+    missing one is finite (bucket 0); a numeric feature of ``L`` bounds
+    keeps those at ``_edge_positions(L)`` and its value bins are
+    ``0 .. L``.  Fine bin ``b`` rolls up to the first kept edge whose
+    position ``searchsorted(ub, edges, "left")`` is ``>= b``, so a
+    bucket holds the bins from just past the previous kept position to
+    its own: with exclusive cumulative counts, one difference a bucket.
+    The position of a kept bound is the first index of its run of equal
+    bounds, which is its own index when the bounds strictly ascend (as
+    binning cuts them) and still ``searchsorted``'s where they repeat.
+    Integer arithmetic throughout: the snapshots are those of
+    :class:`StreamSketch`, bucket for bucket."""
+    f, nb = fine.shape
+    ubs = mapper.upper_bounds
+    kind = np.array([-1 if mapper.is_categorical(j) else len(ubs[j])
+                     for j in range(f)], np.int64)
+    # cum[b, j]: feature j's rows in fine bins below b
+    cum = np.empty((nb + 1, f), np.int64)
+    cum[0] = 0
+    np.cumsum(fine.T, axis=0, out=cum[1:])
+    nans = fine[:, mapper.missing_bin].tolist()
+    edges: List[np.ndarray] = [None] * f
+    snaps: List[Dict[str, Any]] = [None] * f
+    groups = np.unique(kind).tolist()
+    for L in groups:
+        js = np.flatnonzero(kind == L)
+        m = len(js)
+        if L > 0:
+            ub = np.array([ubs[j] for j in js], np.float64)
+            sel = _edge_positions(L, max_edges)
+            new_run = np.ones(ub.shape, bool)
+            new_run[:, 1:] = ub[:, 1:] != ub[:, :-1]
+            first = np.maximum.accumulate(
+                np.where(new_run, np.arange(L, dtype=np.int32), 0),
+                axis=1)
+            e, pos = ub[:, sel], first[:, sel]
+        else:
+            e, pos = np.empty((m, 0)), np.empty((m, 0), np.int32)
+        top = mapper.missing_bin if L < 0 else L + 1
+        ends = np.concatenate([np.zeros((m, 1), np.int64), pos + 1,
+                               np.full((m, 1), top)], axis=1)
+        counts = np.diff(cum[ends, js[:, None]], axis=1).tolist()
+        finite = cum[top, js].tolist()
+        keys = _BUCKET_KEYS if e.shape[1] < len(_BUCKET_KEYS) \
+            else tuple(str(i) for i in range(e.shape[1] + 1))
+        for j, ej, n, row in zip(js.tolist(), e, finite, counts):
+            edges[j] = ej
+            snaps[j] = {"n": n, "nan": nans[j], "posinf": 0, "neginf": 0,
+                        "below": 0, "above": 0, "mean": 0.0, "m2": 0.0,
+                        "buckets": {k: c for k, c in zip(keys, row) if c}}
+    return edges, snaps, len(groups)
+
+
 def build_reference_profile(bins: np.ndarray, mapper,
                             margins: Optional[np.ndarray] = None,
                             feature_names: Optional[Sequence[str]]
@@ -518,42 +592,9 @@ def build_reference_profile(bins: np.ndarray, mapper,
         raise ValueError(
             f"fine_counts has shape {fine_counts.shape}; the table "
             f"has {f} features of {mapper.num_total_bins} bins")
-    with prof.region("train.refprofile_rollup", features=int(f)):
-        edges_list: List[np.ndarray] = []
-        sketches: List[Dict[str, Any]] = []
-        for j in range(f):
-            ub = mapper.upper_bounds[j]
-            if mapper.is_categorical(j) or len(ub) == 0:
-                edges = np.empty(0, np.float64)
-            else:
-                edges = downsample_edges(ub, max_edges)
-            lo, hi = ((float(edges[0]), float(edges[-1]))
-                      if len(edges) else (None, None))
-            sk = StreamSketch(edges, lo, hi)
-            fine = fine_counts[j]
-            sk.nan = int(fine[mapper.missing_bin])
-            if mapper.is_categorical(j):
-                # category identity occupies the fine bins; the coarse
-                # ladder is empty → everything finite in bucket 0
-                finite = int(fine[:mapper.missing_bin].sum())
-                sk.counts[0] = finite
-                sk.count = finite
-            else:
-                value_bins = fine[:len(ub) + 1]
-                if len(edges):
-                    # fine bin b (first bound >= v is ub[b]) rolls up to
-                    # the first coarse edge position >= b
-                    idx = np.searchsorted(ub, edges, side="left")
-                    coarse_of_fine = np.searchsorted(
-                        idx, np.arange(len(ub) + 1), side="left")
-                    sk.counts += np.bincount(
-                        coarse_of_fine, weights=value_bins,
-                        minlength=len(sk.counts)).astype(np.int64)
-                else:
-                    sk.counts[0] = int(value_bins.sum())
-                sk.count = int(value_bins.sum())
-            edges_list.append(edges)
-            sketches.append(sk.snapshot())
+    with prof.region("train.refprofile_rollup", features=int(f)) as sp:
+        edges_list, sketches, sp["ladders"] = _rollup_features(
+            fine_counts, mapper, max_edges)
         if margins is not None and np.asarray(margins).size:
             mg = np.asarray(margins, np.float64).ravel()
             mg = mg[np.isfinite(mg)]

@@ -299,7 +299,12 @@ def test_fit_emits_every_span_once_inside_its_root(fit_inputs, devices):
     assert by_name["train.prepare"]["attrs"] == {"rows": n}
     assert {s["attrs"]["where"] for s in spans
             if s["name"] == "train.refprofile_counts"} == {"device"}
-    assert by_name["train.refprofile_rollup"]["attrs"] == {"features": f}
+    # one group a ladder length (categorical features one of their own)
+    mapper = fit_inputs["mapper"]
+    ladders = {-1 if mapper.is_categorical(j) else len(ub)
+               for j, ub in enumerate(mapper.upper_bounds)}
+    assert by_name["train.refprofile_rollup"]["attrs"] == \
+        {"features": f, "ladders": len(ladders)}
     assert by_name["train.host_trees"]["attrs"] == {"trees": 6}
     if devices == 1:
         assert (a["collective_count"], a["collective_bytes"]) == (0, 0)
