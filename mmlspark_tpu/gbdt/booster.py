@@ -757,10 +757,20 @@ class CompiledPredictor:
         return margins[:, 0] if K == 1 else margins
 
 
+def _float_text(v) -> str:
+    """``v`` to 17 places after the point, or, where that does not read
+    back as ``v`` (under 0.1 it keeps too few digits: a threshold equal
+    to a value in the data, such as a pixel level, would send that value
+    the other way), the shortest text that does."""
+    text = np.format_float_positional(v, precision=17, trim="0")
+    if float(text) == v or v != v:
+        return text
+    return np.format_float_positional(v, trim="0")
+
+
 def _arr_line(name: str, arr: np.ndarray) -> str:
     if arr.dtype.kind == "f":
-        vals = " ".join(np.format_float_positional(
-            v, precision=17, trim="0") for v in arr)
+        vals = " ".join(_float_text(v) for v in arr)
     else:
         # a forest with categorical splits on wide columns holds tens of
         # millions of bitset words: no Python-level loop over them

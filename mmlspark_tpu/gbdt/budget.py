@@ -18,9 +18,9 @@ its one-hot temporaries, the Mosaic build (PR 28) only the bucket's
 transposed copy.  Against the peaks measured on a v5e (PR 34) it reads
 0.98x at 400 000 x 2000, 0.98x at 1 183 747 x 968, 0.98x at 7 325 625 x
 220 with a ranker's layout, 1.42x at 30 000 000 x 39, where XLA keeps no
-second copy of a narrow table, and 1.01x at 13 184 290 rows bundled into
-90 columns with the cache 4228
-features wide (PERF.md; tests/test_budget.py holds the cells).  It deliberately over-counts
+second copy of a narrow table, 1.01x at 13 184 290 rows bundled into
+90 columns with the cache 4228 features wide, and 0.96x at 2 400 000 x
+784 with 10 classes (PERF.md; tests/test_budget.py holds the cells).  It deliberately over-counts
 slightly (gradients and their gh-stack both appear) — a guard that errs
 a few percent high beats an OOM at iteration 40.
 """
@@ -72,8 +72,11 @@ def estimate_fit_bytes(n_local: int, num_features: int, num_bins: int,
     costs["bins_transposed"] = n * f * bin_itemsize
     # scores + labels + weights + real/bag mask + row_order
     costs["row_vectors"] = n * 4 * (K + 4)
-    # grad/hess (n, K) each + the (n, 3) gh stack the grower consumes
-    costs["gradients"] = n * 4 * (2 * K + 3)
+    # grad/hess (n, K) each + the (n, 3) gh stack the grower consumes;
+    # a softmax (K > 1) also holds its exponentials and probabilities,
+    # two more (K, n) (the class loop keeps all of them class-major, so
+    # no row pads K to a lane tile)
+    costs["gradients"] = n * 4 * (2 * K + 3 + (2 * K if K > 1 else 0))
     # per-leaf histogram state: (L, f, B, 3) f32
     costs["leaf_hist"] = L * num_features * B * 3 * 4
     if num_bundles is not None:

@@ -798,12 +798,15 @@ def prepare_arrays_from_shards(bins_shards, label_shards, weight_shards,
     # init scores (initScoreCol) offset the local slice, pad rows keep the
     # plain init (their weight is zero anyway)
     def score_shard(d):
-        if init_score_shards is None or init_score_shards[d] is None:
-            base = np.full(sizes[d], init, np.float32)
-        else:
-            base = init + np.asarray(init_score_shards[d], np.float32)
-        return base if num_class == 1 else \
-            np.broadcast_to(base[:, None], (sizes[d], num_class))
+        # ``init``: a float, or one start score per class
+        shape = (sizes[d],) if num_class == 1 else (sizes[d], num_class)
+        base = np.full(shape, init, np.float32)
+        if init_score_shards is not None \
+                and init_score_shards[d] is not None:
+            extra = np.asarray(init_score_shards[d], np.float32)
+            base = base + (extra if extra.ndim == base.ndim
+                           else extra[:, None])
+        return base
 
     if num_class > 1:
         scores = make(P(DATA_AXIS, None), np.float32, init, score_shard,

@@ -33,7 +33,7 @@ from .grower import (EFBArrays, GrowerConfig, TreeArrays, apply_shrinkage,
                      collective_schedule, hist_build_schedule,
                      predict_tree_binned, predict_tree_binned_any,
                      predict_tree_binned_efb, segment_walk_stats,
-                     _grow_tree_impl)
+                     GROW_TRACES, _grow_tree_impl)
 from .objectives import Objective, MulticlassObjective
 
 
@@ -466,8 +466,14 @@ def _ckpt_event(name: str, **fields) -> None:
     _tm.get_journal().emit(name, fit=_tm.current_fit_span(), **fields)
 
 
+#: grower bodies in each chunk program this process built, by the
+#: program's static arguments and operand shapes: what ``grow_bodies``
+#: reads where a dispatch found its program in jax's cache
+_grow_bodies_built: Dict[tuple, int] = {}
+
+
 def _dispatch_chunk(run, scores, val_scores, it: int, trees: int,
-                    t_chunk: float, uploaded=None, **ids):
+                    t_chunk: float, uploaded=None, program=None, **ids):
     """One bracketed chunk dispatch, for the serial and the mesh loop
     alike: ``run(scores, val_scores)`` under ``train.launch`` (tracing,
     compile or cache look-up, enqueue; its attrs say how much of it was
@@ -486,6 +492,11 @@ def _dispatch_chunk(run, scores, val_scores, it: int, trees: int,
     work and its drain.  The device sees nothing new: the host waits in
     two steps where it waited in one, and with the profiler off in one.
 
+    ``program``: a hashable key of the chunk program (its static
+    arguments and shapes).  The grower bodies traced under the launch
+    are recorded under it, and ``last_fit_info["grow_bodies"]`` says how
+    many the program holds, traced now or recorded when it was built.
+
     Also feeds the ``train.boost_chunk`` dispatch ledger (the
     ``compile_seq`` delta classifying the dispatch as cache hit or miss)
     and journals the ``profile_span`` that ``tools/trace_report.py`` lays
@@ -495,6 +506,7 @@ def _dispatch_chunk(run, scores, val_scores, it: int, trees: int,
     seq0 = p.compile_seq()
     traced0 = p.jax_seconds("jaxpr_trace")
     compiled0 = p.jax_seconds("backend_compile")
+    bodies0 = GROW_TRACES[0]
     with p.region("train.launch") as sp:
         out = run(scores, val_scores)
         misses = p.compile_seq() - seq0
@@ -503,6 +515,12 @@ def _dispatch_chunk(run, scores, val_scores, it: int, trees: int,
             jaxpr_trace_s=round(p.jax_seconds("jaxpr_trace") - traced0, 6),
             backend_compile_s=round(
                 p.jax_seconds("backend_compile") - compiled0, 6))
+    if program is not None:
+        if GROW_TRACES[0] > bodies0:
+            _grow_bodies_built[program] = GROW_TRACES[0] - bodies0
+        if program in _grow_bodies_built:
+            last_fit_info.update(
+                grow_bodies=str(_grow_bodies_built[program]))
     t_host = time.perf_counter()
     if uploaded is not None and p.enabled:
         operands, nbytes = uploaded
@@ -529,7 +547,8 @@ def _monitor_chunk(it0: int, it1: int, dt_s: float, n_rows: int, K: int,
                    hist_method: str, objective=None, scores=None,
                    labels=None, weights=None,
                    collective: str = "none",
-                   coll_sched: Optional[dict] = None) -> None:
+                   coll_sched: Optional[dict] = None,
+                   class_major: bool = False) -> None:
     """Per-boost-chunk live training telemetry: ms/tree, rows/s,
     last-iteration and (when the objective can compute it cheaply)
     train-loss gauges on ``train_stats``, plus one ``boost_chunk``
@@ -550,6 +569,9 @@ def _monitor_chunk(it0: int, it1: int, dt_s: float, n_rows: int, K: int,
     the ``collective_count``/``collective_payload_bytes`` counters and
     journaled on the ``boost_chunk`` event, so the payload a wide-data
     voting fit saves is machine-checkable on /metrics (ISSUE 16).
+
+    ``class_major``: ``scores`` are (K, n), as the multiclass scan holds
+    them; the loss reads them transposed on the host.
 
     The whole of it is the fit's ``train.monitor`` span: telemetry's own
     cost inside the fit, ``loss_rows`` being the rows the loss gauge
@@ -577,13 +599,16 @@ def _monitor_chunk(it0: int, it1: int, dt_s: float, n_rows: int, K: int,
                 labels_np = np.asarray(labels)
                 stride = max(1, len(labels_np) // _MONITOR_LOSS_MAX_ROWS)
                 if stride > 1:
-                    scores = scores[::stride]    # device-side slice: the
-                    labels_np = labels_np[::stride]   # D2H stays bounded
+                    # device-side slice: the D2H stays bounded
+                    scores = (scores[:, ::stride] if class_major
+                              else scores[::stride])
+                    labels_np = labels_np[::stride]
                     weights = (None if weights is None
                                else np.asarray(weights)[::stride])
                 sp["loss_rows"] = int(len(labels_np))
-                loss = objective.train_loss(np.asarray(scores), labels_np,
-                                            weights)
+                host = np.asarray(scores)
+                loss = objective.train_loss(
+                    host.T if class_major else host, labels_np, weights)
             except Exception:  # noqa: BLE001 - telemetry must never kill
                 loss = None    # the fit it observes
         if loss is not None:
@@ -1352,43 +1377,53 @@ def _boost_scan_multi(bins, scores, labels, weights, bag_masks, fi_stack,
                       cfg: GrowerConfig, lr: float, K: int, has_val: bool,
                       efb=None, rf: bool = False):
     """Multiclass chunk: grad/hess computed ONCE per iteration for all K
-    trees (LightGBM softmax semantics), then K grow steps consume the fixed
-    gradients.  Emits trees flattened to (C*K, ...), iteration-major,
-    class-minor — the order the model file expects.
+    trees (LightGBM softmax semantics), then a loop over the classes grows
+    each class's tree from the fixed gradients with ONE grower body, so
+    the program's size does not grow with K.  Emits trees flattened to
+    (C*K, ...), iteration-major, class-minor — the order the model file
+    expects.
+
+    ``scores`` are class-major, (K, n), and so are the gradients: a
+    class's column is one contiguous row, read by its tree and written
+    in place by its score update.  ``val_scores`` stay (n_val, K).
 
     ``rf``: random-forest mode — every tree fits the gradient at the
     CONSTANT init scores, unshrunk (per-class averaging at export)."""
     binsT = bins.T   # fit-invariant; hoisted out of the scan (PERF.md r4)
+    classes = jnp.arange(K)
 
-    def body(carry, xs):
+    def grow_class(carry, xs):
         scores, val_scores = carry
-        bag, fi = xs
-        bag = jnp.broadcast_to(bag, (scores.shape[0],))
-        with jax.named_scope("gradients"):
-            g, h = obj.grad_hess(scores, labels, weights)
-        trees_k = []
-        for k in range(K):
-            gh = jnp.stack([g[:, k] * bag, h[:, k] * bag, bag], axis=1)
+        k, g, h, bag, fi = xs
+        with jax.named_scope("class_tree"):
+            gh = jnp.stack([g * bag, h * bag, bag], axis=1)
             tree, row_leaf = _grow_tree_impl(bins, gh, fi, cfg, efb,
                                              binsT=binsT)
             if not rf:
                 with jax.named_scope("score_update"):
-                    scores = scores.at[:, k].add(
-                        lr * tree.leaf_value[row_leaf])
+                    scores = scores.at[k].add(lr * tree.leaf_value[row_leaf])
                 tree = apply_shrinkage(tree, lr)
             if has_val:
-                val_scores = val_scores.at[:, k].add(predict_tree_binned(
+                val_scores = val_scores.at[k].add(predict_tree_binned(
                     tree, val_bins, cfg.num_leaves))
-            trees_k.append(tree)
-        trees = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees_k)
-        out_val = val_scores if has_val else _dummy_val(K)
-        return (scores, val_scores), (trees, out_val)
+        return (scores, val_scores), tree
+
+    def body(carry, xs):
+        bag, fi = xs
+        bag = jnp.broadcast_to(bag, (carry[0].shape[1],))
+        with jax.named_scope("gradients"):
+            g, h = obj.grad_hess_class_major(carry[0], labels, weights)
+        carry, trees = jax.lax.scan(
+            lambda c, x: grow_class(c, x + (bag, fi)), carry,
+            (classes, g, h))
+        out_val = carry[1] if has_val else _dummy_val(K).T
+        return carry, (trees, out_val)
 
     (scores, val_scores), (trees, val_hist) = jax.lax.scan(
-        body, (scores, val_scores), (bag_masks, fi_stack))
+        body, (scores, val_scores.T), (bag_masks, fi_stack))
     trees = jax.tree_util.tree_map(
         lambda a: a.reshape((-1,) + a.shape[2:]), trees)
-    return trees, scores, val_scores, val_hist
+    return trees, scores, val_scores.T, jnp.swapaxes(val_hist, 1, 2)
 
 
 @jax.jit
@@ -1846,7 +1881,12 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
     the rows that lost a value to a conflict (0 at ``maxConflictRate``
     0); any other fit has none of the four.  A one-device fit that
     compacts rows says what its splits asked of the bucket ladders
-    (``_segment_walk_attrs``); a mesh fit has none of the three."""
+    (``_segment_walk_attrs``); a mesh fit has none of the three.
+    ``num_class`` and ``trees_per_iteration`` are the forest's class
+    count and the trees an iteration grows (K for a softmax fit, else
+    1); a fit whose trees came from chunk programs says how many grower
+    bodies the last of them holds (``grow_bodies``: 1 for any K where
+    the classes loop over one body; a dart fit has none)."""
     shards = bins if isinstance(bins, (list, tuple)) else [bins]
     shapes = [np.shape(b) for b in shards if b is not None]
     trees = len(booster.trees)
@@ -1864,7 +1904,11 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
         "hist_build": last_fit_info.get("hist_build", ""),
         "hist_build_rungs": last_fit_info.get("hist_build_rungs", ""),
         "hist_cache_bytes": int(last_fit_info.get("hist_cache_bytes", 0)),
+        "num_class": int(booster.num_class),
+        "trees_per_iteration": int(booster.num_class),
     }
+    if "grow_bodies" in last_fit_info:
+        attrs["grow_bodies"] = int(last_fit_info["grow_bodies"])
     attrs.update({k: int(v) for k, v in last_fit_info.items()
                   if k.startswith("efb_")})
     if "rank_queries" in last_fit_info:
@@ -2233,6 +2277,10 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             iscores = np.asarray(init_scores, np.float32)
             scores0 = scores0 + (iscores if scores0.ndim == iscores.ndim
                                  else iscores[:, None])
+        # the multiclass scan holds its scores class-major, (K, n)
+        class_major = K > 1 and not (use_goss or use_dart)
+        if class_major:
+            scores0 = np.ascontiguousarray(scores0.T)
         scores = jnp.asarray(scores0)
         upload_bytes = sp["bytes"] = int(
             bins_d.nbytes + labels_d.nbytes + weights_d.nbytes
@@ -2556,12 +2604,16 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             else:
                 trees_st, scores, val_scores, val_hist = _dispatch_chunk(
                     run_chunk, scores, val_scores, it, C * K, t_chunk,
-                    uploaded=uploaded)
+                    uploaded=uploaded, program=(
+                        "goss" if use_goss else "scan", K, step_obj, cfg,
+                        params.learning_rate, has_val, use_rf, C,
+                        bins_d.shape, str(bins_d.dtype), efb_dev is None))
                 uploaded = None
             trees_chunks.append(trees_st)
             _monitor_chunk(it, it + C, time.perf_counter() - t_chunk,
                            n, K, cfg.hist_method, objective, scores,
-                           labels, w, coll_sched=coll_sched)
+                           labels, w, coll_sched=coll_sched,
+                           class_major=class_major)
             stop = False
             if has_val:
                 vh = np.asarray(val_hist)        # (C, n_val[, K])
@@ -3092,13 +3144,16 @@ def _feat_info_from_mapper(mapper: BinMapper, f: int) -> np.ndarray:
 
 def _finalize_booster(trees, K, init, params, objective, mapper,
                       feature_names, f, stop_iter) -> Booster:
-    if trees and params.boost_from_average and init != 0.0:
+    if trees and params.boost_from_average:
         # Bake the init score into the first tree per class so the exported
-        # model is self-contained, as LightGBM does for boost_from_average.
+        # model is self-contained, as LightGBM does for boost_from_average
+        # (a multiclass fit starts each class at its own score).
+        start = np.broadcast_to(np.asarray(init, np.float64), (K,))
         for k in range(K):
-            t = trees[k]
-            t.leaf_value = t.leaf_value + init
-            t.internal_value = t.internal_value + init
+            if start[k] != 0.0:
+                t = trees[k]
+                t.leaf_value = t.leaf_value + start[k]
+                t.internal_value = t.internal_value + start[k]
 
     # pass_through keys that NAME TrainParams fields were applied by
     # __post_init__ and are already reflected in the typed values above
@@ -3641,7 +3696,7 @@ def _train_distributed(bins, labels, w, mapper, objective, params, cfg, mesh,
         else:
             trees_st, scores, val_scores, val_hist = _dispatch_chunk(
                 run_step, scores, val_scores, it, C * K, t_chunk,
-                uploaded=uploaded, mesh=True)
+                uploaded=uploaded, program=("mesh", step, C), mesh=True)
             uploaded = None
         chunks.append(trees_st)
         # objective=None: the gang's score vector is sharded (not fully

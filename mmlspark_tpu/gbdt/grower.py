@@ -1021,8 +1021,15 @@ def make_feat_info(f: int, feature_mask=None, is_cat=None, nbins=None):
     return out
 
 
+#: grower bodies traced in this process: ``_grow_tree_impl`` runs once
+#: for each copy of the grower a program being traced holds (the engine
+#: reads it around a chunk's dispatch: ``train.fit``'s ``grow_bodies``)
+GROW_TRACES = [0]
+
+
 def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                     binsT=None):
+    GROW_TRACES[0] += 1
     # debug-mode invariants: every training path funnels through here,
     # so corrupt bins / non-finite gradients are caught regardless of
     # entry point.  Traced only when the config asks (GrowerConfig.

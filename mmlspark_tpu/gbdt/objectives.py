@@ -13,8 +13,10 @@ Semantics track LightGBM:
   ``boost_from_average`` init score = log(p/(1-p))/sigmoid.
 * ``regression`` (l2), ``regression_l1`` (gradient = sign, hessian = 1),
   ``huber``, ``fair``, ``poisson``, ``quantile``, ``mape``.
-* ``multiclass``: one-vs-all softmax, K trees per iteration,
-  hessian = 2·p·(1-p) · factor (K/(K-1)) as in LightGBM.
+* ``multiclass``: softmax over K classes, K trees per iteration,
+  hessian = factor·p·(1-p) with factor = K/(K-1), as in LightGBM;
+  ``boost_from_average`` starts class k at log(share of class k)
+  (LightGBM's ``MulticlassSoftmax::BoostFromScore``).
 * ``lambdarank``: in :mod:`mmlspark_tpu.gbdt.ranking` (pairwise ΔNDCG).
 """
 
@@ -67,6 +69,13 @@ class Objective:
     def grad_hess(self, scores: Array, labels: Array,
                   weights: Array) -> Tuple[Array, Array]:
         raise NotImplementedError
+
+    def grad_hess_class_major(self, scores: Array, labels: Array,
+                              weights: Array) -> Tuple[Array, Array]:
+        """An objective of K trees an iteration, with the class axis
+        first: scores (K, n) → (K, n) g/h (``grad_hess`` transposed)."""
+        g, h = self.grad_hess(scores.T, labels, weights)
+        return g.T, h.T
 
     def transform_prediction(self, scores: Array) -> Array:
         """Raw margin → output space (e.g. sigmoid for binary)."""
@@ -386,7 +395,12 @@ class MulticlassObjective(Objective):
         self.factor = self.num_class / (self.num_class - 1.0)
 
     def init_score(self, labels, weights):
-        return 0.0
+        """``(K,)``: class k starts at the log of its weighted share of
+        the rows, floored at 1e-15 (LightGBM's kEpsilon)."""
+        w = np.asarray(weights, np.float64)
+        share = np.bincount(np.asarray(labels, np.int64), weights=w,
+                            minlength=self.num_class) / w.sum()
+        return np.log(np.maximum(share[:self.num_class], 1e-15))
 
     def grad_hess(self, scores, labels, weights):
         """scores: (n, K); labels: (n,) int class ids → (n, K) g/h."""
@@ -394,6 +408,18 @@ class MulticlassObjective(Objective):
         y = jax.nn.one_hot(labels.astype(jnp.int32), self.num_class,
                            dtype=p.dtype)
         w = weights[:, None]
+        g = (p - y) * w
+        h = self.factor * p * (1.0 - p) * w
+        return g, h
+
+    def grad_hess_class_major(self, scores, labels, weights):
+        """``grad_hess`` with the class axis first: scores (K, n) →
+        (K, n) g/h, so that one class's row is contiguous."""
+        p = jax.nn.softmax(scores, axis=0)
+        y = (labels.astype(jnp.int32)[None, :]
+             == jnp.arange(self.num_class, dtype=jnp.int32)[:, None]
+             ).astype(p.dtype)
+        w = weights[None, :]
         g = (p - y) * w
         h = self.factor * p * (1.0 - p) * w
         return g, h

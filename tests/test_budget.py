@@ -101,6 +101,19 @@ class TestEstimate:
         assert est["rank_layout"] == est["total"] - bare["total"]
         assert "rank_layout" not in bare
 
+    def test_estimate_against_the_multiclass_cells_measured_peak(self):
+        """``mnist8m_fit`` on a v5e: 5 345 013 248 bytes at 2 400 000 x
+        784, 10 classes, scores and gradients class-major in the class
+        loop.  A class loop unrolled in Python, with its scores (n, 10),
+        read 6 018 166 784 at the same shape."""
+        est = estimate_fit_bytes(2_400_000, 784, 256, 255, num_class=10,
+                                 chunk=2, hist_on_chip=True)
+        assert 0.9 < est["total"] / 5_345_013_248 < 1.1
+        one = estimate_fit_bytes(2_400_000, 784, 256, 255, chunk=2,
+                                 hist_on_chip=True)
+        assert est["gradients"] == 2_400_000 * 4 * (4 * 10 + 3)
+        assert one["gradients"] == 2_400_000 * 4 * 5
+
     def test_ranking_fit_hands_its_layout_to_the_guard(self, monkeypatch):
         from mmlspark_tpu.gbdt import budget
         from tests.test_istella_cell import small_rank_fit

@@ -125,6 +125,34 @@ def test_split_loop_updates_the_histogram_cache_in_place(v5e, case):
     assert [c for c in copies if c[1] == cache] == []
 
 
+def test_class_loop_holds_one_grower_and_copies_no_scores(v5e):
+    """The multiclass chunk at 40 000 x 200, K = 10: as many kernel call
+    sites as the one-class scan (one grower body, where the unrolled loop
+    held ten), and no synchronous copy of the class-major (K, n) scores,
+    which each class's tree updates in place (a ``copy-start`` of them is
+    the compiler's prefetch into fast memory, not a carry copy)."""
+    from mmlspark_tpu.core.profiling import compiled_copies
+    from mmlspark_tpu.gbdt import engine
+    from mmlspark_tpu.gbdt.grower import GrowerConfig
+    from mmlspark_tpu.gbdt.objectives import MulticlassObjective
+    n, f, K = _CACHE_N, _CACHE_F, 10
+    one = SingleDeviceSharding(v5e[0])
+    compiled = engine._boost_scan_multi.lower(
+        _sds((n, f), jnp.uint8, one), _sds((K, n), jnp.float32, one),
+        _sds((n,), jnp.int32, one), _sds((n,), jnp.float32, one),
+        _sds((2, 1), jnp.float32, one), _sds((2, f, 3), jnp.float32, one),
+        _sds((1, f), jnp.uint8, one), _sds((1, K), jnp.float32, one),
+        obj=MulticlassObjective(K), cfg=GrowerConfig(
+            num_leaves=_CACHE_L, num_bins=_CACHE_B, hist_method="dot16",
+            min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0),
+        lr=0.1, K=K, has_val=False).compile()
+    sites = _compile_grower(v5e, "boost_scan").as_text().count(
+        "tpu_custom_call")
+    assert compiled.as_text().count("tpu_custom_call") == sites
+    assert [c for c in compiled_copies(compiled)
+            if c[1] == (K, n) and c[0].startswith("copy.")] == []
+
+
 # ------------------------------- a long segment, built chunk by chunk
 
 _WALK_N, _WALK_F = 300_000, 39
