@@ -61,6 +61,15 @@ class LightGBMParams(HasFeaturesCol, HasLabelCol, HasPredictionCol,
                           typeConverter=TypeConverters.toInt)
     minGainToSplit = Param("minGainToSplit", "Minimal split gain", default=0.0,
                            typeConverter=TypeConverters.toFloat)
+    useMissing = Param("useMissing",
+                       "Handle NaN as missing: each split sends it the "
+                       "better of its two default directions (false: "
+                       "NaN goes right)", default=True,
+                       typeConverter=TypeConverters.toBool)
+    zeroAsMissing = Param("zeroAsMissing",
+                          "Treat zeros as missing values (not supported; "
+                          "NaN is the missing value)", default=False,
+                          typeConverter=TypeConverters.toBool)
     baggingFraction = Param("baggingFraction", "Row subsample fraction",
                             default=1.0, typeConverter=TypeConverters.toFloat)
     baggingFreq = Param("baggingFreq",
@@ -245,6 +254,8 @@ class LightGBMParams(HasFeaturesCol, HasLabelCol, HasPredictionCol,
             min_data_in_leaf=self.getMinDataInLeaf(),
             min_sum_hessian_in_leaf=self.getMinSumHessianInLeaf(),
             min_gain_to_split=self.getMinGainToSplit(),
+            use_missing=self.getUseMissing(),
+            zero_as_missing=self.getZeroAsMissing(),
             bagging_fraction=self.getBaggingFraction(),
             bagging_freq=self.getBaggingFreq(),
             feature_fraction=self.getFeatureFraction(),

@@ -99,6 +99,11 @@ class SparseBins:
         return out
 
 
+#: the threshold of a split at a feature's top value bin, whose upper
+#: bound is infinite: LightGBM's model text clamps it to this
+THRESHOLD_MAX = 1e300
+
+
 @dataclass
 class BinMapper:
     """Per-feature binning spec: ``upper_bounds[f]`` sorted ascending.
@@ -377,11 +382,13 @@ class BinMapper:
 
         Matches LightGBM's convention of storing the bin upper bound in the
         model file, so exported models score identically on raw features.
+        The top value bin's bound is infinite, and LightGBM writes it as
+        ``1e300`` (``Common::AvoidInf``): a split there isolates the
+        missing bin, every finite value going left.
         """
         ub = self.upper_bounds[feature]
         if bin_idx >= len(ub):
-            # split isolating the top/missing bin: everything finite goes left
-            return np.inf
+            return THRESHOLD_MAX
         return float(ub[bin_idx])
 
     def feature_infos(self) -> List[str]:

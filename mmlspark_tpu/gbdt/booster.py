@@ -138,10 +138,18 @@ def host_tree_from_arrays(tree: TreeArrays, mapper: BinMapper,
     cat_bits = np.asarray(tree.node_cat_bits)[:m]
     thr = np.array([mapper.bin_threshold_value(int(f), int(b))
                     for f, b in zip(feat, bins)], dtype=np.float64)
-    # decision_type: numerical split; missing (NaN) routes right in training
-    # (missing bin is the trailing bin), i.e. default_left = false.
-    dt = np.where(mapper.has_missing[feat] if m else np.zeros(0, bool),
-                  8, 2).astype(np.int32)  # 8 = missing:NaN, 2 = default-left
+    # decision_type (LightGBM): bit 1 default_left, bits 2-3 the missing
+    # type.  2 = default left with missing None (a feature without NaN);
+    # 8 = missing NaN, default right; 10 = missing NaN, default left.  A
+    # tree grown without missing values sends NaN right; one grown with
+    # them says where each split sent it (``node_dleft``)
+    has_nan = mapper.has_missing[feat] if m else np.zeros(0, bool)
+    if tree.node_dleft is None:
+        dt = np.where(has_nan, 8, 2).astype(np.int32)
+    else:
+        dleft = np.asarray(tree.node_dleft)[:m] >= 0
+        dt = np.where(has_nan | dleft, 8 | (2 * dleft),
+                      2).astype(np.int32)
     num_cat = 0
     cat_boundaries = [0]
     cat_words: List[np.ndarray] = []
@@ -187,6 +195,18 @@ def host_tree_from_arrays(tree: TreeArrays, mapper: BinMapper,
         cat_threshold=(np.concatenate(cat_words).astype(np.uint32)
                        if cat_words else np.zeros(0, np.uint32)),
     )
+
+
+def _nan_left(decision_type: np.ndarray) -> np.ndarray:
+    """Where a node sends NaN LEFT (1), as LightGBM's decision reads the
+    bits: a categorical node by its default_left bit; a numeric node by
+    it only where its missing type (bits 2-3) is NaN.  Every other NaN
+    goes right (a numeric node of missing type None or Zero: LightGBM
+    reads NaN as zero there; this walk does not)."""
+    dt = np.asarray(decision_type, np.int32)
+    default_left = (dt & 2) > 0
+    nan_type = ((dt >> 2) & 3) == 2
+    return (default_left & (((dt & 1) > 0) | nan_type)).astype(np.int32)
 
 
 class Booster:
@@ -311,7 +331,9 @@ class Booster:
             # Round thresholds UP to float32 so the f32 decision `x <= thr`
             # agrees with the exact f64 threshold for every f32-representable
             # x (rounding down could flip a midpoint onto the right value).
-            v = t.threshold.astype(np.float32)
+            # LightGBM's 1e300 (a split isolating the missing bin) is inf.
+            with np.errstate(over="ignore"):
+                v = t.threshold.astype(np.float32)
             low = v.astype(np.float64) < t.threshold
             v[low] = np.nextafter(v[low], np.float32(np.inf))
             return v
@@ -328,7 +350,7 @@ class Booster:
                 [t.num_leaves <= 1 for t in self.trees], np.bool_),
             "is_cat": pad([(t.decision_type & 1).astype(np.int32)
                            for t in self.trees], m, np.int32),
-            "dleft": pad([((t.decision_type & 2) >> 1).astype(np.int32)
+            "dleft": pad([_nan_left(t.decision_type)
                           for t in self.trees], m, np.int32),
             # zero-padded; padded entries are only read for numeric nodes
             # whose categorical branch result is discarded
@@ -836,7 +858,8 @@ def _predict_forest(X, feat, thr, left, right, leaf, single, is_cat, dleft,
             safe = jnp.maximum(node, 0)
             f = tfeat[safe]
             x = jnp.take_along_axis(X, f[:, None], axis=1)[:, 0]
-            go_left = x <= tthr[safe]
+            # NaN fails the compare and goes by the node's NaN direction
+            go_left = (x <= tthr[safe]) | (jnp.isnan(x) & (tdleft[safe] > 0))
             if has_cat:  # static: numeric-only forests skip the bitset walk
                 left_cat = _cat_go_left(x, tthr[safe].astype(jnp.int32),
                                         tdleft[safe], tbnd, twords)
@@ -873,7 +896,8 @@ def _predict_leaves(X, feat, thr, left, right, single, is_cat, dleft,
             safe = jnp.maximum(node, 0)
             f = tfeat[safe]
             x = jnp.take_along_axis(X, f[:, None], axis=1)[:, 0]
-            go_left = x <= tthr[safe]
+            # NaN fails the compare and goes by the node's NaN direction
+            go_left = (x <= tthr[safe]) | (jnp.isnan(x) & (tdleft[safe] > 0))
             if has_cat:  # static: numeric-only forests skip the bitset walk
                 left_cat = _cat_go_left(x, tthr[safe].astype(jnp.int32),
                                         tdleft[safe], tbnd, twords)

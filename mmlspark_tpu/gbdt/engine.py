@@ -33,6 +33,7 @@ from .grower import (EFBArrays, GrowerConfig, TreeArrays, apply_shrinkage,
                      collective_schedule, hist_build_schedule,
                      predict_tree_binned, predict_tree_binned_any,
                      predict_tree_binned_efb, segment_walk_stats,
+                     missing_split_stats,
                      GROW_TRACES, _grow_tree_impl)
 from .objectives import Objective, MulticlassObjective
 
@@ -268,6 +269,12 @@ class TrainParams:
     #: re-uploads the inputs and replays THAT chunk up to this many times
     #: — the TPU-shaped analog of the reference's executor gang-restart.
     fault_tolerant_retries: int = 0
+    #: missing values (LightGBM names and defaults): NaN is the missing
+    #: value, and a split sends it the better of its two default
+    #: directions; ``use_missing`` off sends it right, and
+    #: ``zero_as_missing`` is refused (``_scans_missing``)
+    use_missing: bool = True
+    zero_as_missing: bool = False
     #: Exclusive Feature Bundling (Ke et al. 2017; LightGBM
     #: enable_bundle): merge mutually-exclusive sparse features into
     #: bundle columns so histogram work scales with bundles, not
@@ -736,7 +743,10 @@ def _ckpt_read_chunks(ckpt_dir, n_chunks, n_trees=None):
     chunks = []
     for i in range(n_chunks):
         with np.load(os.path.join(ckpt_dir, _CKPT_CHUNK.format(i))) as cz:
-            chunks.append(TreeArrays(*[cz[name]
+            # a fit on a table without missing values saved no
+            # node_dleft / node_missing (None)
+            chunks.append(TreeArrays(*[cz[name] if name in cz.files
+                                       else None
                                        for name in TreeArrays._fields]))
     if n_trees is not None and _ckpt_tree_count(chunks) != n_trees:
         raise ValueError(
@@ -756,7 +766,8 @@ def _ckpt_write_chunks(ckpt_dir, trees_chunks) -> None:
         tmp = cpath + ".tmp"
         with open(tmp, "wb") as fh:
             np.savez(fh, **{name: np.asarray(arr) for name, arr
-                            in zip(TreeArrays._fields, ch)})
+                            in zip(TreeArrays._fields, ch)
+                            if arr is not None})
             fh.flush()
             os.fsync(fh.fileno())
         os.replace(tmp, cpath)
@@ -1447,6 +1458,13 @@ def _pack_trees_stacked(stacked: TreeArrays) -> jnp.ndarray:
     bits_hi = f32(bits >> jnp.uint32(16))
     node_count = stacked.node_count.astype(jnp.int32)
     leaf_count = stacked.leaf_count.astype(jnp.int32)
+    missing = []
+    if stacked.node_dleft is not None:
+        # a tree grown on a table with missing values: its default
+        # directions and each node's missing rows (two halves, as counts)
+        node_missing = stacked.node_missing.astype(jnp.int32)
+        missing = [f32(stacked.node_dleft), f32(node_missing & 0xFFFF),
+                   f32(node_missing >> 16)]
     return jnp.concatenate([
         f32(stacked.num_leaves)[:, None],
         f32(stacked.node_feat), f32(stacked.node_bin),
@@ -1456,6 +1474,7 @@ def _pack_trees_stacked(stacked: TreeArrays) -> jnp.ndarray:
         f32(stacked.node_is_cat),
         stacked.leaf_value, stacked.leaf_weight, f32(leaf_count & 0xFFFF),
         bits_lo, bits_hi, f32(node_count >> 16), f32(leaf_count >> 16),
+        *missing,
     ], axis=1)
 
 
@@ -1478,8 +1497,12 @@ def _fetch_host_trees(chunks: List[TreeArrays], num_leaves: int
         sp["bytes"] = int(packed.nbytes)
         L, m = num_leaves, num_leaves - 1
         W = chunks[0].node_cat_bits.shape[-1]
-        offs = np.cumsum([1] + [m] * 9 + [L] * 3 + [m * W] * 2 + [m, L])
+        widths = [1] + [m] * 9 + [L] * 3 + [m * W] * 2 + [m, L]
+        if packed.shape[1] > sum(widths):
+            widths += [m] * 3         # node_dleft, node_missing's halves
+        offs = np.cumsum(widths)
         cols = [packed[:, a:b] for a, b in zip([0] + list(offs), offs)]
+        missing = len(cols) > 17
         nls = cols[0][:, 0].astype(np.int64)
         out = []
         for i in range(packed.shape[0]):
@@ -1500,6 +1523,11 @@ def _fetch_host_trees(chunks: List[TreeArrays], num_leaves: int
                 leaf_count=(cols[12][i].astype(np.int64)
                             + (cols[16][i].astype(np.int64) << 16)),
                 num_leaves=nls[i])
+            if missing:
+                tree = tree._replace(
+                    node_dleft=cols[17][i].astype(np.int32),
+                    node_missing=(cols[18][i].astype(np.int64)
+                                  + (cols[19][i].astype(np.int64) << 16)))
             out.append(tree)
         return out, nls
 
@@ -1990,6 +2018,8 @@ def _fit_attrs(booster: Booster, bins, mesh, mapper) -> dict:
             rank_size_classes=int(last_fit_info["rank_size_classes"]),
             rank_pairs_useful=per_tree("rank_pairs_useful_per_tree"),
             rank_pairs_computed=per_tree("rank_pairs_computed_per_tree"))
+    attrs.update({k: int(v) for k, v in last_fit_info.items()
+                  if k.startswith("missing_")})
     if "seg_min_bucket" in last_fit_info:
         attrs.update(_segment_walk_attrs(
             booster.trees, attrs["rows"],
@@ -2058,6 +2088,25 @@ def train_incremental(bins: np.ndarray, labels: np.ndarray,
     # (the canary's drift monitor compares live margins against it)
     _capture_reference_profile(merged, bins, mapper, feature_names)
     return merged
+
+
+def _scans_missing(mapper: BinMapper, params: TrainParams) -> bool:
+    """``GrowerConfig.has_missing``: a numeric feature of the binned table
+    held NaN (``mapper.has_missing``) and ``use_missing`` is on, so the
+    split scan scores both default directions of the missing bin.
+    ``use_missing`` off keeps the one-direction scan, in which NaN rows go
+    right (LightGBM reads them as zero then).  ``zero_as_missing`` is
+    refused: no path of this trainer routes zeros as missing."""
+    if params.zero_as_missing:
+        raise NotImplementedError(
+            "zeroAsMissing=True is not supported: only NaN is a missing "
+            "value here (LightGBM's default, zero_as_missing=false)")
+    if not params.use_missing:
+        return False
+    numeric = mapper.has_missing
+    if mapper.categorical is not None:
+        numeric = numeric & ~mapper.categorical
+    return bool(np.any(numeric))
 
 
 def _train_impl(bins: np.ndarray, labels: np.ndarray,
@@ -2184,7 +2233,8 @@ def _train_impl(bins: np.ndarray, labels: np.ndarray,
             max_cat_to_onehot=params.max_cat_to_onehot,
             quantized_bits=qbits, quantized_seed=params.seed,
             quantized_max_code=qmc, quantized_wire=qwire,
-            debug_checks=_debug.debug_enabled())
+            debug_checks=_debug.debug_enabled(),
+            has_missing=_scans_missing(mapper, params))
         coll_sched = _collective_sched_for(cfg, mesh, n, f)
         hist_sched = _hist_sched_for(cfg, mesh, n)
         _record_fit_resolution(cfg, collective, coll_downgrade, coll_sched,
@@ -2842,7 +2892,8 @@ def _train_distributed_sharded(bins_shards, label_shards, weight_shards,
         max_cat_to_onehot=params.max_cat_to_onehot,
         quantized_bits=qbits, quantized_seed=params.seed,
         quantized_max_code=qmc, quantized_wire=qwire,
-        debug_checks=_debug.debug_enabled())
+        debug_checks=_debug.debug_enabled(),
+        has_missing=_scans_missing(mapper, params))
 
     from .budget import check_fit_budget
     f_sh = next(b.shape[1] for b in bins_shards if b is not None)
@@ -3274,6 +3325,9 @@ def _export_booster(chunks, K, stop_iter, init, params, objective, mapper,
         trees, nls = trees[:stop_iter * K], nls[:stop_iter * K]
         trees, stop_iter = _truncate_no_growth(trees, nls, K, stop_iter,
                                                params.verbosity)
+        if trees and trees[0].node_dleft is not None:
+            last_fit_info.update({k: str(v) for k, v in
+                                  missing_split_stats(trees).items()})
         # real-valued thresholds and, for categorical splits, bitsets over
         # raw values (``train.cat_bitsets``), for the trees that stay
         with get_profiler().region("train.host_trees", trees=len(trees)):

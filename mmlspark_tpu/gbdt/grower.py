@@ -153,6 +153,14 @@ class GrowerConfig:
     #: built anew) another persistent-cache key, so the cache
     #: missed and the fit compiled for real (PERF.md Findings, PR 25)
     debug_checks: bool = False
+    #: a numeric feature of the table holds NaN (the engine reads the bin
+    #: mapper's ``has_missing``; no user knob): the split scan scores both
+    #: default directions of the missing bin, as LightGBM scans a feature
+    #: of ``missing_type`` NaN, and each split records where it sends it
+    #: (``TreeArrays.node_dleft``).  Static, and off traces nothing of the
+    #: second direction: a table with no missing values compiles the
+    #: one-direction program, in which the missing bin always goes right
+    has_missing: bool = False
 
     @property
     def cat_words(self) -> int:
@@ -183,6 +191,14 @@ class TreeArrays(NamedTuple):
     leaf_weight: jnp.ndarray  # (L,) f32
     leaf_count: jnp.ndarray   # (L,) i32
     num_leaves: jnp.ndarray   # () i32 actual leaves grown
+    #: grown with ``GrowerConfig.has_missing`` only, None otherwise.
+    #: ``node_dleft``: the bin that goes LEFT besides ``<= node_bin``: the
+    #: missing bin where the split sends NaN left (LightGBM's
+    #: default_left), -1 where it sends it right.  ``node_missing``: the
+    #: node's rows in the split feature's missing bin, which the default
+    #: direction routed (0 on a categorical split)
+    node_dleft: Optional[jnp.ndarray] = None    # (L-1,) i32
+    node_missing: Optional[jnp.ndarray] = None  # (L-1,) i32
 
 
 class _GrowState(NamedTuple):
@@ -206,6 +222,7 @@ class _GrowState(NamedTuple):
     best_is_cat: jnp.ndarray  # (L,) i32
     best_cat_bits: jnp.ndarray  # (L, W) u32
     tree: TreeArrays
+    best_dleft: Optional[jnp.ndarray] = None  # (L,) i32, has_missing only
 
 
 def _threshold_l1(g, l1):
@@ -319,46 +336,90 @@ def _find_best_cat_split(hist, parent_g, parent_h, parent_c, cat_allowed,
     return gain, feat, k, _pack_bin_mask(mask_bins, cfg)
 
 
+def _numeric_gains(hist, parent_g, parent_h, parent_c, cfg: GrowerConfig):
+    """Numeric split gains of every (feature, direction, bin) of a
+    ``(f, B, 3)`` histogram, and where each split is admissible:
+    ``(gains, valid)``, ``(f, 2, B)`` each with ``cfg.has_missing``, else
+    the one direction's ``(f, B)``.  The caller masks the gains.
+
+    Direction 0 sends the missing bin (the trailing bin ``B - 1``: NaN)
+    right: left = bins <= b, LightGBM's FindBestThreshold.  With
+    ``cfg.has_missing`` direction 1 sends it left: left = bins <= b plus
+    the missing bin, from the same cumulative sums, admissible only where
+    the node holds missing rows of the feature and the right side keeps a
+    row (LightGBM's two scans of a feature of ``missing_type`` NaN).
+    Validity by min_data_in_leaf / min_sum_hessian; the missing bin is
+    never a threshold."""
+    B = hist.shape[1]
+    md, mh = cfg.min_data_in_leaf, cfg.min_sum_hessian_in_leaf
+
+    def scan(cum):
+        gl, hl, cl = cum[..., 0], cum[..., 1], cum[..., 2]
+        gr, hr, cr = parent_g - gl, parent_h - hl, parent_c - cl
+        valid = ((cl >= md) & (cr >= md) & (hl >= mh) & (hr >= mh)
+                 & (jnp.arange(B) < B - 1)[None, :])
+        parent_gain = _leaf_gain(parent_g, parent_h, cfg)
+        gains = (_leaf_gain(gl, hl, cfg) + _leaf_gain(gr, hr, cfg)
+                 - parent_gain)
+        return gains, valid, cr
+
+    cum = jnp.cumsum(hist, axis=1)           # (f, B, 3)
+    gains, valid, _ = scan(cum)
+    if not cfg.has_missing:
+        return gains, valid
+    with jax.named_scope("missing_left"):
+        nan = hist[:, B - 1:, :]             # (f, 1, 3)
+        gains_l, valid_l, cr = scan(cum + nan)
+        valid_l = valid_l & (nan[..., 2] > 0) & (cr >= 1)
+    return (jnp.stack([gains, gains_l], axis=1),
+            jnp.stack([valid, valid_l], axis=1))
+
+
+def _best_numeric(gains, cand=None):
+    """``(gain, feature, bin, dleft)`` of the first best entry of
+    :func:`_numeric_gains`' masked array in (feature, direction, bin)
+    order: a tie keeps the lower feature (LightGBM's SplitInfo order),
+    then missing right (the one-direction scan's answer, so a direction
+    the node's rows cannot tell apart stays right), then the lower bin.
+    ``cand``: the feature ids of the array's rows (voting's candidates).
+    ``dleft`` is None for the one direction's ``(f, B)``."""
+    B = gains.shape[-1]
+    flat = gains.reshape(-1)
+    idx = jnp.argmax(flat)
+    gain = flat[idx]
+    if gains.ndim == 2:
+        row = (idx // B).astype(jnp.int32)
+        dleft = None
+    else:
+        row = (idx // (2 * B)).astype(jnp.int32)
+        dleft = ((idx // B) % 2).astype(jnp.int32)
+    feat = row if cand is None else cand[row]
+    return gain, feat, (idx % B).astype(jnp.int32), dleft
+
+
 def find_best_split(hist: jnp.ndarray, parent_g, parent_h, parent_c,
                     feat_info: jnp.ndarray, depth_ok,
-                    cfg: GrowerConfig) -> Tuple[jnp.ndarray, jnp.ndarray,
-                                                jnp.ndarray, jnp.ndarray,
-                                                jnp.ndarray]:
+                    cfg: GrowerConfig) -> Tuple[jnp.ndarray, ...]:
     """Best split over a (f, B, 3) histogram.
 
     ``feat_info``: (f, 3) float32 — [:, 0] feature mask, [:, 1] categorical
     flag, [:, 2] per-feature value-bin count.  Returns ``(gain, feature,
-    bin, is_cat, cat_bits)`` where ``cat_bits`` is the (W,) u32 left-subset
-    bin bitset (zeros for numeric splits).
+    bin, is_cat, cat_bits, dleft)`` where ``cat_bits`` is the (W,) u32
+    left-subset bin bitset (zeros for numeric splits) and ``dleft`` 1
+    where the missing bin goes left (None without ``cfg.has_missing``).
 
-    Numeric path mirrors LightGBM's FindBestThreshold: left = bins <= b,
-    validity by min_data_in_leaf / min_sum_hessian, gain = ΔL over the
-    parent leaf; first-occurrence argmax reproduces LightGBM's ascending
-    scan tie-break.  Categorical path: :func:`_find_best_cat_split`.
+    Numeric path: :func:`_numeric_gains` and :func:`_best_numeric`.
+    Categorical path: :func:`_find_best_cat_split`.
     """
     feature_mask = feat_info[:, 0]
     is_cat_f = feat_info[:, 1] > 0
-    cum = jnp.cumsum(hist, axis=1)           # (f, B, 3)
-    gl, hl, cl = cum[..., 0], cum[..., 1], cum[..., 2]
-    gr = parent_g - gl
-    hr = parent_h - hl
-    cr = parent_c - cl
-    valid = ((cl >= cfg.min_data_in_leaf) & (cr >= cfg.min_data_in_leaf)
-             & (hl >= cfg.min_sum_hessian_in_leaf)
-             & (hr >= cfg.min_sum_hessian_in_leaf))
-    # cannot split on the last bin (nothing to the right)
-    valid = valid & (jnp.arange(hist.shape[1]) < hist.shape[1] - 1)[None, :]
-    parent_gain = _leaf_gain(parent_g, parent_h, cfg)
-    gains = (_leaf_gain(gl, hl, cfg) + _leaf_gain(gr, hr, cfg) - parent_gain)
+    gains, valid = _numeric_gains(hist, parent_g, parent_h, parent_c, cfg)
     num_allowed = (feature_mask > 0) & (~is_cat_f if cfg.use_categorical
                                         else True)
-    gains = jnp.where(valid & num_allowed[:, None] & depth_ok,
-                      gains, -jnp.inf)
-    flat = gains.reshape(-1)
-    idx = jnp.argmax(flat)
-    best_gain = flat[idx]
-    feat = (idx // hist.shape[1]).astype(jnp.int32)
-    b = (idx % hist.shape[1]).astype(jnp.int32)
+    num_allowed = (num_allowed[:, None] if gains.ndim == 2
+                   else num_allowed[:, None, None])
+    gains = jnp.where(valid & num_allowed & depth_ok, gains, -jnp.inf)
+    best_gain, feat, b, dleft = _best_numeric(gains)
     is_cat = jnp.asarray(0, jnp.int32)
     cat_bits = jnp.zeros(cfg.cat_words, jnp.uint32)
     if cfg.use_categorical:
@@ -372,6 +433,8 @@ def find_best_split(hist: jnp.ndarray, parent_g, parent_h, parent_c,
         b = jnp.where(cat_wins, 0, b)
         is_cat = cat_wins.astype(jnp.int32)
         cat_bits = jnp.where(cat_wins, cat_bits_w, cat_bits)
+        if dleft is not None:
+            dleft = jnp.where(cat_wins, 0, dleft)
     if cfg.feature_axis_name is not None:
         # feature-parallel learner: each shard scanned its feature slice;
         # allgather candidate splits and pick the global winner
@@ -389,9 +452,11 @@ def find_best_split(hist: jnp.ndarray, parent_g, parent_h, parent_c,
         b = bins_all[shard]
         is_cat = cats_all[shard]
         cat_bits = bits_all[shard]
+        if dleft is not None:
+            dleft = jax.lax.all_gather(dleft, ax)[shard]
     gain_ok = best_gain > jnp.maximum(cfg.min_gain_to_split, EPS_GAIN)
     return (jnp.where(gain_ok, best_gain, -jnp.inf), feat, b, is_cat,
-            cat_bits)
+            cat_bits, dleft)
 
 
 def _is_voting(cfg: GrowerConfig) -> bool:
@@ -518,18 +583,13 @@ def _voting_masks(feat_info, depth_ok, cfg: GrowerConfig):
 
 def _voting_feature_gains(hist, pg, ph, pc, mask_cols, depth_ok,
                           cfg: GrowerConfig):
-    """Per-(feature, bin) numeric split gains over ``hist`` against the
-    given parent totals — the scan both the vote and decide phases run."""
-    B = hist.shape[1]
-    md, mh = cfg.min_data_in_leaf, cfg.min_sum_hessian_in_leaf
-    cum = jnp.cumsum(hist, axis=1)
-    gl, hl, cl = cum[..., 0], cum[..., 1], cum[..., 2]
-    gr, hr, cr = pg - gl, ph - hl, pc - cl
-    valid = ((cl >= md) & (cr >= md) & (hl >= mh) & (hr >= mh)
-             & (jnp.arange(B) < B - 1)[None, :])
-    parent_gain = _leaf_gain(pg, ph, cfg)
-    gains = (_leaf_gain(gl, hl, cfg) + _leaf_gain(gr, hr, cfg)
-             - parent_gain)
+    """Per-(feature, direction, bin) numeric split gains over ``hist``
+    against the given parent totals (:func:`_numeric_gains`), masked by
+    the ``(f, 1)`` ``mask_cols`` — the scan both the vote and decide
+    phases run."""
+    gains, valid = _numeric_gains(hist, pg, ph, pc, cfg)
+    if gains.ndim == 3:
+        mask_cols = mask_cols[..., None]
     return jnp.where(valid & mask_cols & depth_ok, gains, -jnp.inf)
 
 
@@ -542,7 +602,7 @@ def _voting_votes(hist_local, feat_info, depth_ok, num_mask, cat_allowed,
     gains_loc = _voting_feature_gains(hist_local, s_loc[0], s_loc[1],
                                       s_loc[2], num_mask[:, None],
                                       depth_ok, cfg)
-    score_f = jnp.max(gains_loc, axis=1)
+    score_f = jnp.max(gains_loc, axis=tuple(range(1, gains_loc.ndim)))
     if cfg.use_categorical:
         with jax.named_scope("cat_scan"):
             gains_cat_loc, _, _ = _cat_split_gains(
@@ -568,15 +628,10 @@ def _voting_candidates(votes_flat, f: int, cfg: GrowerConfig):
 def _voting_decide(hist_cand, cand, pg, ph, pc, feat_info, depth_ok,
                    num_mask, cat_allowed, cfg: GrowerConfig):
     """Exact decision over the globally reduced candidate histograms."""
-    B = hist_cand.shape[1]
     gains_cand = _voting_feature_gains(hist_cand, pg, ph, pc,
                                        num_mask[cand][:, None],
                                        depth_ok, cfg)
-    flat = gains_cand.reshape(-1)
-    idx = jnp.argmax(flat)
-    best_gain = flat[idx]
-    feat = cand[(idx // B).astype(jnp.int32)]
-    b = (idx % B).astype(jnp.int32)
+    best_gain, feat, b, dleft = _best_numeric(gains_cand, cand)
     is_cat = jnp.asarray(0, jnp.int32)
     cat_bits = jnp.zeros(cfg.cat_words, jnp.uint32)
     if cfg.use_categorical:
@@ -589,9 +644,11 @@ def _voting_decide(hist_cand, cand, pg, ph, pc, feat_info, depth_ok,
         b = jnp.where(cat_wins, 0, b)
         is_cat = cat_wins.astype(jnp.int32)
         cat_bits = jnp.where(cat_wins, cat_bits_w, cat_bits)
+        if dleft is not None:
+            dleft = jnp.where(cat_wins, 0, dleft)
     gain_ok = best_gain > jnp.maximum(cfg.min_gain_to_split, EPS_GAIN)
     return (jnp.where(gain_ok, best_gain, -jnp.inf), feat, b, is_cat,
-            cat_bits)
+            cat_bits, dleft)
 
 
 def find_best_split_voting(hist_local, parent_g, parent_h, parent_c,
@@ -728,9 +785,30 @@ def segment_walk_stats(parents, smaller, n_rows: int,
             "seg_chunked_nodes": int((smaller > build[-1]).sum())}
 
 
+def missing_split_stats(trees) -> dict:
+    """What a fit's splits did with missing values, from host
+    ``TreeArrays`` grown with ``GrowerConfig.has_missing`` (others have
+    none and count nothing): ``missing_splits``, the splits whose node
+    held rows in the split feature's missing bin; ``missing_left_splits``,
+    those of them that sent the rows left; ``missing_rows_routed``, the
+    rows those splits routed by their default direction."""
+    splits = left = rows = 0
+    for t in trees:
+        if t.node_missing is None:
+            continue
+        m = max(int(t.num_leaves) - 1, 0)
+        held = np.asarray(t.node_missing)[:m]
+        went_left = np.asarray(t.node_dleft)[:m] >= 0
+        splits += int((held > 0).sum())
+        left += int(((held > 0) & went_left).sum())
+        rows += int(held.astype(np.int64).sum())
+    return {"missing_splits": splits, "missing_left_splits": left,
+            "missing_rows_routed": rows}
+
+
 @jax.named_scope("partition")
 def _partition_switch(row_order, col, off, cnt, thr, use_cat, cat_bits,
-                      n, sizes, cfg: GrowerConfig):
+                      n, sizes, cfg: GrowerConfig, left_bin=None):
     """Partition the split leaf's contiguous ``row_order`` segment into
     left|right in place — LightGBM's ``DataPartition::Split`` re-shaped for
     static-shape jit.  The segment (dynamic offset, dynamic length ``cnt``)
@@ -740,16 +818,21 @@ def _partition_switch(row_order, col, off, cnt, thr, use_cat, cat_bits,
     chosen branch executes, and no collectives live inside branches (shards
     may pick different buckets under a data mesh).
 
-    Returns ``(row_order', cnt_left, cnt_right)`` (counts of ALL leaf rows
-    per side, bagged-out rows included — the partition tracks membership,
-    histograms track contribution).  On the CPU backend the whole
-    partition is one in-place native pass (ops/histogram.py
-    native_partition).
+    A numeric split sends bins ``<= thr`` left, and ``left_bin``
+    (``TreeArrays.node_dleft``: the missing bin where NaN goes left, -1
+    where it goes right; None without ``cfg.has_missing``) with them.
+
+    Returns ``(row_order', cnt_left, cnt_right)`` (counts of ALL leaf
+    rows per side, bagged-out rows included — the partition tracks
+    membership, histograms track contribution) and, given ``left_bin``,
+    ``cnt_missing``: a numeric split's rows in the missing bin.  On the
+    CPU backend the whole partition is one in-place native pass
+    (ops/histogram.py native_partition).
     """
     if cfg.hist_method in ("auto", "native"):
         from ..ops.histogram import native_partition
         res = native_partition(row_order, col, off, cnt, thr, use_cat,
-                               cat_bits, cfg.num_bins)
+                               cat_bits, cfg.num_bins, left_bin=left_bin)
         if res is not None:
             return res
 
@@ -760,11 +843,19 @@ def _partition_switch(row_order, col, off, cnt, thr, use_cat, cat_bits,
             valid = iota < cnt
             rows = jnp.minimum(seg, n - 1)
             cseg = jnp.take(col, rows).astype(jnp.int32)
+            num_left = cseg <= thr
+            cnt_m = ()
+            if left_bin is not None:
+                num_left = num_left | (cseg == left_bin)
+                is_m = valid & (cseg == cfg.num_bins - 1)
+                if cfg.use_categorical:
+                    is_m = is_m & ~use_cat
+                cnt_m = (jnp.sum(is_m, dtype=jnp.int32),)
             if cfg.use_categorical:
                 gl = jnp.where(use_cat, bin_in_bitset(cat_bits, cseg),
-                               cseg <= thr)
+                               num_left)
             else:
-                gl = cseg <= thr
+                gl = num_left
             go_l = valid & gl
             go_r = valid & ~gl
             cnt_r = jnp.sum(go_r, dtype=jnp.int32)
@@ -776,7 +867,7 @@ def _partition_switch(row_order, col, off, cnt, thr, use_cat, cat_bits,
             tgt = jnp.where(go_l, pos_l, jnp.where(go_r, pos_r, size))
             new_seg = seg.at[tgt].set(seg, mode="drop")
             out = jax.lax.dynamic_update_slice(row_order, new_seg, (off,))
-            return out, cnt_l, cnt_r
+            return (out, cnt_l, cnt_r) + cnt_m
         return fn
 
     branch = jnp.searchsorted(jnp.asarray(sizes, jnp.int32), cnt,
@@ -886,14 +977,15 @@ def _find_split(hist, pg, ph, pc, fi, depth_ok, cfg: GrowerConfig,
     if deq is not None:
         hist = deq(hist)
     if (cfg.hist_method in ("auto", "native") and not cfg.use_categorical
+            and not cfg.has_missing
             and cfg.axis_name is None and cfg.feature_axis_name is None
             and (cfg.min_sum_hessian_in_leaf > 0 or cfg.lambda_l2 > 0)):
         # serial CPU path: the whole FindBestThreshold scan as one FFI
         # call; the C++ pass picks the winner, the gain is recomputed on
         # XLA's float trajectory (see native_find_split).  Mesh/voting/
-        # categorical keep XLA; so does the degenerate min_sum_hessian=
-        # lambda_l2=0 config, whose empty-side gains go NaN and argmax
-        # semantics would differ.
+        # categorical and missing values keep XLA; so does the degenerate
+        # min_sum_hessian=lambda_l2=0 config, whose empty-side gains go
+        # NaN and argmax semantics would differ.
         from ..ops.histogram import native_find_split
         res = native_find_split(
             hist, pg, ph, pc, fi[:, 0], depth_ok,
@@ -903,7 +995,7 @@ def _find_split(hist, pg, ph, pc, fi, depth_ok, cfg: GrowerConfig,
         if res is not None:
             gain, feat, b = res
             return (gain, feat, b, jnp.asarray(0, jnp.int32),
-                    jnp.zeros(cfg.cat_words, jnp.uint32))
+                    jnp.zeros(cfg.cat_words, jnp.uint32), None)
     return find_best_split(hist, pg, ph, pc, fi, depth_ok, cfg)
 
 
@@ -983,7 +1075,8 @@ def collective_schedule(cfg: GrowerConfig, f: int, *,
             # through _wire_cast_psum even on ring fits): counts are
             # bounded by n, which any resolved narrow wire admits
             cnt_item = {"int8": 1, "int16": 2}.get(cfg.quantized_wire, 4)
-            payload += (L - 1) * 2 * cnt_item
+            # with missing values the node's missing rows ride along
+            payload += (L - 1) * (3 if cfg.has_missing else 2) * cnt_item
     if cfg.feature_axis_name is not None and feature_shards > 1:
         count += L - 1                             # split-column psums
         payload += (L - 1) * n_rows_local * 4
@@ -1077,9 +1170,10 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
         hist0 = _hist(bins, gh, cfg, efb)
     g0, h0, c0 = _global_totals(*tot_deq(*_totals_from_hist(hist0)), cfg)
     depth0_ok = (cfg.max_depth <= 0) | (0 < cfg.max_depth)
-    bg0, bf0, bb0, bc0, bits0 = _find_split(
+    bg0, bf0, bb0, bc0, bits0, bd0 = _find_split(
         hist0, g0, h0, c0, feat_info, jnp.asarray(depth0_ok), cfg,
         deq=deq)
+    missing = cfg.has_missing
 
     tree = TreeArrays(
         node_feat=jnp.zeros(L - 1, jnp.int32),
@@ -1097,6 +1191,8 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
         leaf_weight=jnp.zeros(L, jnp.float32).at[0].set(h0),
         leaf_count=jnp.zeros(L, jnp.int32).at[0].set(c0),
         num_leaves=jnp.asarray(1, jnp.int32),
+        node_dleft=jnp.full(L - 1, -1, jnp.int32) if missing else None,
+        node_missing=jnp.zeros(L - 1, jnp.int32) if missing else None,
     )
     if cfg.compact_rows:
         n_pow = sizes[-1]
@@ -1130,6 +1226,8 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
         best_is_cat=jnp.zeros(L, jnp.int32).at[0].set(bc0),
         best_cat_bits=jnp.zeros((L, W), jnp.uint32).at[0].set(bits0),
         tree=tree,
+        best_dleft=(jnp.zeros(L, jnp.int32).at[0].set(bd0) if missing
+                    else None),
     )
 
     def split_step(i, state: _GrowState) -> _GrowState:
@@ -1149,6 +1247,11 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
             feat = state.best_feat[l]
             thr = state.best_bin[l]
             new_id = (i + 1).astype(jnp.int32)
+            # the bin that goes left besides ``<= thr``: the missing bin
+            # where the split sends NaN left (TreeArrays.node_dleft)
+            left_bin = (jnp.where(state.best_dleft[l] > 0,
+                                  cfg.num_bins - 1, -1).astype(jnp.int32)
+                        if missing else None)
             if cfg.feature_axis_name is not None:
                 # feat is a GLOBAL index but bins holds this shard's feature
                 # slice: the owning shard contributes the split column, the
@@ -1182,18 +1285,22 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 off = state.leaf_start[l]
                 cnt = jnp.where(ds, state.leaf_cnt[l], 0)
                 use_cat = state.best_is_cat[l] > 0
-                row_order, cnt_l_p, cnt_r_p = _partition_switch(
+                row_order, cnt_l_p, cnt_r_p, *cnt_m = _partition_switch(
                     state.row_order, col, off, cnt, thr, use_cat,
-                    state.best_cat_bits[l], n, sizes, cfg)
+                    state.best_cat_bits[l], n, sizes, cfg,
+                    left_bin=left_bin)
                 if cfg.axis_name is not None:
                     # counts are bounded by n, which the quantized wire
-                    # policy keeps within the wire dtype — ride it too
+                    # policy keeps within the wire dtype — ride it too,
+                    # with the node's missing rows where there are any
                     with jax.named_scope("reduce"):
                         tot = _wire_cast_psum(
-                            jnp.stack([cnt_l_p, cnt_r_p]), cfg)
+                            jnp.stack([cnt_l_p, cnt_r_p] + cnt_m), cfg)
                     use_right = tot[1] <= tot[0]
+                    cnt_m = list(tot[2:])
                 else:
                     use_right = cnt_r_p <= cnt_l_p
+                cnt_m = cnt_m[0] if missing else None
                 child_off = jnp.where(use_right, off + cnt_l_p, off)
                 child_cnt = jnp.where(use_right, cnt_r_p, cnt_l_p)
                 hist_small = _segment_hist(
@@ -1219,13 +1326,24 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                                          .at[new_id].set(cnt_r_p)
             else:
                 in_leaf = (state.row_leaf == l) & ds
+                num_left = col <= thr
+                if missing:
+                    num_left = num_left | (col == left_bin)
+                    is_m = in_leaf & (col == cfg.num_bins - 1)
+                    if cfg.use_categorical:
+                        is_m = is_m & (state.best_is_cat[l] == 0)
+                    cnt_m = jnp.sum(is_m, dtype=jnp.int32)
+                    if cfg.axis_name is not None:
+                        cnt_m = _wire_cast_psum(cnt_m, cfg)
                 if cfg.use_categorical:
                     go_left_val = jnp.where(
                         state.best_is_cat[l] > 0,
                         bin_in_bitset(state.best_cat_bits[l],
                                       col.astype(jnp.int32)),
-                        col <= thr)
+                        num_left)
                     go_right = in_leaf & ~go_left_val
+                elif missing:
+                    go_right = in_leaf & ~num_left
                 else:
                     go_right = in_leaf & (col > thr)
                 row_leaf = jnp.where(go_right, new_id, state.row_leaf)
@@ -1247,18 +1365,18 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 # batched frontier (ISSUE 16): both children's votes
                 # ride one allgather and both candidate slabs one
                 # stacked reduction — 1 collective per grow step
-                ((bg_l, bf_l, bb_l, bc_l, bits_l),
-                 (bg_r, bf_r, bb_r, bc_r, bits_r)) = \
+                ((bg_l, bf_l, bb_l, bc_l, bits_l, bd_l),
+                 (bg_r, bf_r, bb_r, bc_r, bits_r, bd_r)) = \
                     find_best_split_voting_pair(
                         hist_l, hist_r,
                         (g_l, h_l, c_l.astype(jnp.float32)),
                         (g_r, h_r, c_r.astype(jnp.float32)),
                         feat_info, depth_ok, cfg, deq=deq)
             else:
-                bg_l, bf_l, bb_l, bc_l, bits_l = _find_split(
+                bg_l, bf_l, bb_l, bc_l, bits_l, bd_l = _find_split(
                     hist_l, g_l, h_l, c_l, feat_info, depth_ok, cfg,
                     deq=deq)
-                bg_r, bf_r, bb_r, bc_r, bits_r = _find_split(
+                bg_r, bf_r, bb_r, bc_r, bits_r, bd_r = _find_split(
                     hist_r, g_r, h_r, c_r, feat_info, depth_ok, cfg,
                     deq=deq)
 
@@ -1292,6 +1410,10 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 leaf_count=t.leaf_count.at[l].set(c_l).at[new_id].set(c_r),
                 num_leaves=t.num_leaves + 1,
             )
+            if missing:
+                tree = tree._replace(
+                    node_dleft=t.node_dleft.at[i].set(left_bin),
+                    node_missing=t.node_missing.at[i].set(cnt_m))
             with jax.named_scope("cache_update"):
                 # XLA updates a loop's carry in place only if no read of
                 # the OLD buffer can come after the first write to it.
@@ -1338,6 +1460,8 @@ def _grow_tree_impl(bins, gh, feat_info, cfg: GrowerConfig, efb=None,
                 best_cat_bits=state.best_cat_bits.at[l].set(bits_l)
                                                  .at[new_id].set(bits_r),
                 tree=tree,
+                best_dleft=(state.best_dleft.at[l].set(bd_l)
+                            .at[new_id].set(bd_r) if missing else None),
             )
 
         new_state = do(state, do_split)
@@ -1372,11 +1496,11 @@ def apply_shrinkage(tree: TreeArrays, learning_rate: float) -> TreeArrays:
 def _tree_walk(tree: TreeArrays, n: int, max_steps: int, get_val):
     """Shared depth-bounded tree walk: ``get_val(safe_node)`` supplies
     each row's current split-column bin (local gather, or a psum-
-    assembled feature-sharded gather); everything else — threshold and
-    categorical-bitset compares, next-node selection, the early-exit
-    while_loop, leaf extraction — lives HERE once, so the local and
-    feature-sharded walks cannot drift apart (their parity is
-    test-pinned).
+    assembled feature-sharded gather); everything else — threshold,
+    default-direction and categorical-bitset compares, next-node
+    selection, the early-exit while_loop, leaf extraction — lives HERE
+    once, so the local and feature-sharded walks cannot drift apart
+    (their parity is test-pinned).
 
     The ``while_loop`` stops as soon as every row reached a leaf, so the
     walk costs O(actual tree depth) iterations — typically ~log2(L) —
@@ -1390,6 +1514,9 @@ def _tree_walk(tree: TreeArrays, n: int, max_steps: int, get_val):
         val = get_val(safe)
         thr = tree.node_bin[safe]
         go_left = val <= thr
+        if tree.node_dleft is not None:
+            # the missing bin goes left where the split sends NaN left
+            go_left = go_left | (val == tree.node_dleft[safe])
         # categorical nodes: left iff the row's bin is in the subset bitset
         words = jnp.take_along_axis(tree.node_cat_bits[safe],
                                     (val >> 5)[:, None], axis=1)[:, 0]

@@ -25,7 +25,8 @@ def _thr32_up(threshold: np.ndarray) -> np.ndarray:
     """Thresholds rounded UP to float32 — the predictor's convention
     (booster._stack's thr32), so the f32 decision agrees with the exact
     f64 threshold for every f32-representable x."""
-    v = threshold.astype(np.float32)
+    with np.errstate(over="ignore"):               # 1e300 -> inf
+        v = threshold.astype(np.float32)
     low = v.astype(np.float64) < threshold
     v[low] = np.nextafter(v[low], np.float32(np.inf))
     return v
@@ -34,8 +35,9 @@ def _thr32_up(threshold: np.ndarray) -> np.ndarray:
 def _decide_left(tree, thr32: np.ndarray, node: int,
                  xrow: np.ndarray) -> bool:
     """Mirror _predict_forest's decision EXACTLY (f32 inputs vs up-rounded
-    f32 thresholds; numeric NaN always right; categorical NaN by the
-    missing_left bit; unseen/out-of-range categories right) — any
+    f32 thresholds; numeric NaN by the default_left bit where the missing
+    type is NaN, else right; categorical NaN by the default_left bit;
+    unseen/out-of-range categories right) — any
     divergence breaks SHAP local accuracy on those rows."""
     f = int(tree.split_feature[node])
     v = xrow[f]                                    # float32
@@ -50,8 +52,8 @@ def _decide_left(tree, thr32: np.ndarray, node: int,
         if c < 0 or (c >> 5) >= len(words):
             return False                           # unseen -> right
         return bool((int(words[c >> 5]) >> (c & 31)) & 1)
-    if np.isnan(v):
-        return False                               # numeric NaN -> right
+    if np.isnan(v):                                # missing type NaN
+        return bool(dt & 2) and (dt >> 2) & 3 == 2  # by default_left
     return v <= thr32[node]
 
 

@@ -9,8 +9,9 @@
 // well under a second.
 //
 // Exactness contract: bitwise-identical margins to _predict_forest.  The
-// walk uses the same float32 `x <= thr` decision (NaN -> right for
-// numeric nodes), the same categorical bitset semantics as _cat_go_left
+// walk uses the same float32 `x <= thr` decision (NaN -> the node's
+// ``dleft`` for numeric nodes: booster._nan_left), the same categorical
+// bitset semantics as _cat_go_left
 // (NaN -> default_left, negative / out-of-range categories -> right),
 // and accumulates per-row tree values in the same tree order in float32,
 // so every IEEE operation matches the XLA scan.
@@ -120,7 +121,10 @@ void PredictRows(const Forest& fr, const float* X, int64_t f, int64_t r0,
                               fr.cat_bnd + t * fr.C1, fr.C1,
                               fr.cat_words + t * fr.W, fr.W);
         } else {
-          go_left = x <= tthr[node];  // NaN -> right, as in the XLA walk
+          // NaN fails the compare and goes by the node's NaN direction,
+          // as in the XLA walk
+          go_left = std::isnan(x) ? fr.dleft[t * fr.m + node] > 0
+                                  : x <= tthr[node];
         }
         node = go_left ? tleft[node] : tright[node];
       }

@@ -137,7 +137,11 @@ XLA_FFI_DEFINE_HANDLER_SYMBOL(
 // leaf's contiguous row_order segment [off, off+cnt) is partitioned
 // into left|right by the split column; input_output_aliases makes the
 // row_order update zero-copy.  ``meta`` (4,) i32 = [off, cnt, thr,
-// use_cat]; ``counts`` out (2,) i32 = [cnt_left, cnt_right].
+// use_cat]; ``counts`` out (2,) i32 = [cnt_left, cnt_right].  A table
+// with missing values passes ``meta`` (6,) = [..., left_bin,
+// missing_bin]: a numeric split sends left_bin (the missing bin where NaN
+// goes left, -1 where it goes right) left with the bins <= thr, and
+// ``counts`` (3,) adds the numeric split's rows in missing_bin.
 static ffi::Error PartitionImpl(ffi::Buffer<ffi::S32> row_order,
                                 ffi::Buffer<ffi::U8> col,
                                 ffi::Buffer<ffi::S32> meta,
@@ -155,6 +159,9 @@ static ffi::Error PartitionImpl(ffi::Buffer<ffi::S32> row_order,
   int64_t cnt = mt[1];
   const int32_t thr = mt[2];
   const bool use_cat = mt[3] != 0;
+  const bool missing = meta.dimensions()[0] >= 6;
+  const int64_t left_bin = missing ? mt[4] : -1;
+  const int64_t missing_bin = missing && !use_cat ? mt[5] : -1;
   const uint32_t* bits = cat_bits.typed_data();
   if (off < 0) off = 0;
   if (off + cnt > m) cnt = m - off;
@@ -162,6 +169,7 @@ static ffi::Error PartitionImpl(ffi::Buffer<ffi::S32> row_order,
   std::vector<int32_t> right;
   right.reserve(static_cast<size_t>(cnt));
   int64_t w = off;
+  int64_t missing_rows = 0;
   constexpr int64_t kPrefetch = 16;
   for (int64_t i = 0; i < cnt; ++i) {
     if (i + kPrefetch < cnt) {
@@ -172,7 +180,8 @@ static ffi::Error PartitionImpl(ffi::Buffer<ffi::S32> row_order,
     int64_t bin = (row >= 0 && row < n) ? c[row] : 0;
     if (bin >= max_bin) bin = max_bin - 1;  // clamp, like the hist kernels
     const bool left = use_cat ? ((bits[bin >> 5] >> (bin & 31)) & 1u) != 0
-                              : bin <= thr;
+                              : (bin <= thr || bin == left_bin);
+    missing_rows += bin == missing_bin;
     if (left) {
       ro[w++] = row;
     } else {
@@ -182,6 +191,7 @@ static ffi::Error PartitionImpl(ffi::Buffer<ffi::S32> row_order,
   std::copy(right.begin(), right.end(), ro + w);
   counts->typed_data()[0] = static_cast<int32_t>(w - off);
   counts->typed_data()[1] = static_cast<int32_t>(right.size());
+  if (missing) counts->typed_data()[2] = static_cast<int32_t>(missing_rows);
   return ffi::Error::Success();
 }
 

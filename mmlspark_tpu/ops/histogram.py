@@ -138,25 +138,33 @@ def native_segment_hist(bins, gh, row_order, off, cnt, num_bins,
 
 
 def native_partition(row_order, col, off, cnt, thr, use_cat, cat_bits,
-                     num_bins):
+                     num_bins, left_bin=None):
     """LightGBM ``DataPartition::Split`` as one in-place stable C++ pass
     (input_output_aliases donates ``row_order``), or None when the native
-    CPU path doesn't apply.  Returns ``(row_order', cnt_left,
-    cnt_right)`` like the ``lax.switch`` bucket-ladder version it
-    replaces — without the ladder's padding work or branch dispatch."""
+    CPU path doesn't apply.  Returns ``(row_order', cnt_left, cnt_right)``
+    like the ``lax.switch`` bucket-ladder version it replaces — without
+    the ladder's padding work or branch dispatch.  ``left_bin``: the bin a
+    numeric split sends left besides ``<= thr``
+    (``TreeArrays.node_dleft``); with it the pass also counts, and
+    returns fourth, a numeric split's rows in the missing bin."""
     if not _native_applies(num_bins):
         return None
     m = row_order.shape[0]
-    meta = jnp.stack([off, cnt, thr,
-                      use_cat.astype(jnp.int32)]).astype(jnp.int32)
+    meta = [off, cnt, thr, use_cat.astype(jnp.int32)]
+    if left_bin is not None:
+        meta += [left_bin, jnp.asarray(num_bins - 1, jnp.int32)]
+    meta = jnp.stack(meta).astype(jnp.int32)
     ro, counts = _jffi.ffi_call(
         "mmlspark_fastpartition",
         (jax.ShapeDtypeStruct((m,), jnp.int32),
-         jax.ShapeDtypeStruct((2,), jnp.int32)),
+         jax.ShapeDtypeStruct((2 if left_bin is None else 3,),
+                              jnp.int32)),
         input_output_aliases={0: 0},
     )(row_order.astype(jnp.int32), col.astype(jnp.uint8), meta,
       cat_bits.astype(jnp.uint32))
-    return ro, counts[0], counts[1]
+    if left_bin is None:
+        return ro, counts[0], counts[1]
+    return ro, counts[0], counts[1], counts[2]
 
 
 def native_find_split(hist, parent_g, parent_h, parent_c, feature_mask,

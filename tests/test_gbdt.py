@@ -288,7 +288,9 @@ class TestReviewRegressions:
         from mmlspark_tpu.gbdt.binning import fit_bin_mapper
         X = np.array([[0.0], [1.0], [2.0], [np.nan]])
         m = fit_bin_mapper(X, max_bin=255, min_data_in_bin=1)
-        assert m.bin_threshold_value(0, 250) == np.inf
+        # the top value bin's bound is infinite; LightGBM's model text
+        # clamps it to 1e300 (Common::AvoidInf), and so does the export
+        assert m.bin_threshold_value(0, 250) == 1e300
 
     def test_bagging_seed_independent_of_seed(self, binary_table):
         t = {"features": binary_table["features"][:500],

@@ -185,8 +185,8 @@ class TestNativeFindSplit:
             pg = jnp.asarray(hist[0, :, 0].sum())
             ph = jnp.asarray(hist[0, :, 1].sum())
             pc = jnp.asarray(hist[0, :, 2].sum())
-            xg, xf, xb, _, _ = find_best_split(
-                hist, pg, ph, pc, fi, jnp.asarray(True), cfg)
+            xg, xf, xb = find_best_split(
+                hist, pg, ph, pc, fi, jnp.asarray(True), cfg)[:3]
             res = native_find_split(
                 hist, pg, ph, pc, fi[:, 0], jnp.asarray(True),
                 cfg.min_data_in_leaf, cfg.min_sum_hessian_in_leaf,

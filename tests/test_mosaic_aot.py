@@ -75,9 +75,10 @@ def _compile_grower(v5e, case, n=_CACHE_N, f=_CACHE_F, num_bins=_CACHE_B):
     one = SingleDeviceSharding(v5e[0])
     base = dict(num_leaves=_CACHE_L, num_bins=num_bins, hist_method="dot16",
                 min_data_in_leaf=0, min_sum_hessian_in_leaf=100.0)
-    if case in ("serial", "masked", "categorical"):
+    if case in ("serial", "masked", "categorical", "missing"):
         cfg = GrowerConfig(compact_rows=(case != "masked"),
-                           use_categorical=(case == "categorical"), **base)
+                           use_categorical=(case == "categorical"),
+                           has_missing=(case == "missing"), **base)
         return grow_tree.lower(
             _sds((n, f), jnp.uint8, one), _sds((n, 3), jnp.float32, one),
             _sds((f, 3), jnp.float32, one), cfg).compile()
@@ -111,7 +112,7 @@ def _compile_grower(v5e, case, n=_CACHE_N, f=_CACHE_F, num_bins=_CACHE_B):
 
 
 @pytest.mark.parametrize("case", ["serial", "shard_map", "masked",
-                                  "boost_scan", "categorical"])
+                                  "boost_scan", "categorical", "missing"])
 def test_split_loop_updates_the_histogram_cache_in_place(v5e, case):
     """A split changes 2 of the cache's 255 rows; the v5e's compiler must
     not copy the other 253.  It did, twice a split, while a read of the
