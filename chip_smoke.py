@@ -284,12 +284,12 @@ def phase_mesh(cfg, X, y, peaks):
         check(min(peaks) >= shard_bytes,
               f"a device peaked under its {shard_bytes}-byte bin shard: "
               f"{peaks}")
-        # the first device also stages what one device handles alone:
-        # prepare_arrays' jnp.asarray puts the whole table and its four
-        # row vectors there before they are sharded, and the reference
-        # profile scores its 32 768-row sample there (since PR 28 a
-        # chip's own peak is small enough for that to show)
-        staged = rows * (f + 4 * 4) + 2 * min(rows, 32_768) * f * 4
+        # the first device also holds what one device handles alone:
+        # the reference profile's 32 768-row sample (a chip's own peak is
+        # small enough for that to show).  The table is not among it:
+        # prepare_arrays puts each device's block straight from the host,
+        # so no device holds the whole table
+        staged = 2 * min(rows, 32_768) * f * 4
         rest = peaks[1:]
         check(max(rest) <= 2 * min(rest),
               f"per-device peaks differ by more than 2x: {peaks}")

@@ -135,12 +135,12 @@ def _build_step(builder, span: bool = True):
 
 
 def _upload(prepare):
-    """``prepare`` under the fit's ``train.upload`` span, with the bytes
-    of the global arrays it put on the mesh."""
+    """``prepare`` under the fit's ``train.upload`` span, handed to it as
+    ``_span``, with the bytes of the global arrays it put on the mesh."""
     @functools.wraps(prepare)
     def wrapped(*args, **kwargs):
         with get_profiler().region("train.upload") as sp:
-            out = prepare(*args, **kwargs)
+            out = prepare(*args, _span=sp, **kwargs)
             sp["bytes"] = int(sum(a.nbytes for a in out[:5]))
         return out
     return wrapped
@@ -703,7 +703,8 @@ def make_ranking_scan(mesh: Mesh, cfg: GrowerConfig, lr: float,
 def prepare_arrays_from_shards(bins_shards, label_shards, weight_shards,
                                mesh: Mesh, num_class: int, init: float,
                                bin_dtype, shard_rows=None,
-                               init_score_shards=None, _piece_spy=None):
+                               init_score_shards=None, _piece_spy=None,
+                               _span=None):
     """Multi-host ingestion (SURVEY.md §7 hard part 4): assemble the global
     sharded training arrays from PER-SHARD inputs without materializing the
     full matrix on any single host.
@@ -721,12 +722,10 @@ def prepare_arrays_from_shards(bins_shards, label_shards, weight_shards,
     (rp = total pad rows across shards).
     """
     D = int(mesh.shape[DATA_AXIS])
-    fn = _feat_n(mesh)
     if len(bins_shards) != D:
         raise ValueError(
             f"need exactly one shard slot per data-mesh slice: got "
             f"{len(bins_shards)} slots for data={D}")
-    from ..core.mesh import pad_to_multiple
     local = [d for d in range(D) if bins_shards[d] is not None]
     if not local:
         raise ValueError("no local shards (every slot is None)")
@@ -742,7 +741,6 @@ def prepare_arrays_from_shards(bins_shards, label_shards, weight_shards,
             raise ValueError(
                 f"shard {d}: bins rows {bins_shards[d].shape[0]}, labels "
                 f"{nl}, weights {nw} must all match")
-    f_padded = pad_to_multiple(f, fn)
     if shard_rows is not None:
         sizes = list(shard_rows)
         for d in local:
@@ -755,115 +753,111 @@ def prepare_arrays_from_shards(bins_shards, label_shards, weight_shards,
     else:
         raise ValueError("shard_rows is required when some shard slots "
                          "are None (multi-controller)")
-    S = max(sizes)
-    n_global = D * S
-
-    def make(spec, dtype, fill, shard_source, width=None):
-        sh = NamedSharding(mesh, spec)
-        shape = (n_global,) if width is None else (n_global, width)
-
-        def cb(index):
-            r0, r1, _ = index[0].indices(n_global)
-            d = r0 // S
-            local = shard_source(d)
-            rows = r1 - r0
-            if width is None:
-                out = np.full(rows, fill, dtype)
-                r = min(local.shape[0], rows)
-                out[:r] = local[:r]
-            else:
-                c0, c1s, _ = index[1].indices(width)
-                out = np.full((rows, c1s - c0), fill, dtype)
-                r = min(local.shape[0], rows)
-                c1 = min(c1s, local.shape[1])
-                if c1 > c0:
-                    out[:r, :c1 - c0] = local[:r, c0:c1]
-            if _piece_spy is not None:
-                _piece_spy(out.shape)
-            return out
-
-        return jax.make_array_from_callback(shape, sh, cb)
-
-    lab_dtype = np.int32 if num_class > 1 else np.float32
-    bins_d = make(P(DATA_AXIS, _f_ax(mesh)), bin_dtype, 0,
-                  lambda d: bins_shards[d], width=f_padded)
-    lab_d = make(P(DATA_AXIS), lab_dtype, 0,
-                 lambda d: np.asarray(label_shards[d], lab_dtype))
-    w_d = make(P(DATA_AXIS), np.float32, 0.0,
-               lambda d: np.asarray(weight_shards[d], np.float32))
-    real_d = make(P(DATA_AXIS), np.float32, 0.0,
-                  lambda d: np.ones(sizes[d], np.float32))
-    # scores ride the callback path too — no transient global array on any
-    # single device (the arrays this function exists to avoid); per-shard
-    # init scores (initScoreCol) offset the local slice, pad rows keep the
-    # plain init (their weight is zero anyway)
-    def score_shard(d):
-        # ``init``: a float, or one start score per class
-        shape = (sizes[d],) if num_class == 1 else (sizes[d], num_class)
-        base = np.full(shape, init, np.float32)
-        if init_score_shards is not None \
-                and init_score_shards[d] is not None:
-            extra = np.asarray(init_score_shards[d], np.float32)
-            base = base + (extra if extra.ndim == base.ndim
-                           else extra[:, None])
-        return base
-
-    if num_class > 1:
-        scores = make(P(DATA_AXIS, None), np.float32, init, score_shard,
-                      width=num_class)
-    else:
-        scores = make(P(DATA_AXIS), np.float32, init, score_shard)
-    rp = n_global - sum(sizes)
-    return bins_d, lab_d, w_d, real_d, scores, rp, f_padded - f
+    return _put_shards(mesh, sizes, f, bins_shards, label_shards,
+                       weight_shards, init_score_shards, num_class, init,
+                       bin_dtype, _span, _piece_spy)
 
 
 @_upload
 def prepare_arrays(bins: np.ndarray, labels: np.ndarray, weights: np.ndarray,
                    mesh: Mesh, num_class: int, init: float,
-                   init_scores: Optional[np.ndarray] = None
+                   init_scores: Optional[np.ndarray] = None,
+                   _piece_spy=None, _span=None
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
                               jnp.ndarray, jnp.ndarray, int, int]:
-    """Pad rows/features to multiples of the mesh axes and device_put.
+    """Pad rows/features to multiples of the mesh axes and put each
+    device's block straight from host memory on its device.
 
-    Pad rows carry zero weight (excluded from histograms via the bag mask);
-    pad features are constant bin 0 (never produce a valid split).
+    The table is cut into ``data``-axis shards of ceil(n / D) rows (the
+    last ones shorter or empty) and handed, as views, to the same
+    assembly as :func:`prepare_arrays_from_shards`: the padded layout is
+    the rows in order, pad rows at the end carrying zero weight (excluded
+    from histograms via the bag mask), pad features constant bin 0 (never
+    produce a valid split).  No array is ever whole on one device, and
+    only a block that holds padding is copied on the host.
+    """
+    n, f = bins.shape
+    D = int(mesh.shape[DATA_AXIS])
+    S = -(-n // D)
+    edges = [min(d * S, n) for d in range(D + 1)]
+
+    def cut(a):
+        return None if a is None else \
+            [a[edges[d]:edges[d + 1]] for d in range(D)]
+
+    return _put_shards(mesh, [b - a for a, b in zip(edges, edges[1:])], f,
+                       cut(bins), cut(labels), cut(weights),
+                       cut(init_scores), num_class, init, bins.dtype, _span,
+                       _piece_spy)
+
+
+def _put_shards(mesh: Mesh, sizes, f: int, bins_shards, label_shards,
+                weight_shards, init_score_shards, num_class: int, init,
+                bin_dtype, span=None, piece_spy=None):
+    """The five global arrays of a mesh fit from per-shard host rows.
+
+    Shard d's rows lead ``data`` block d and zero-weight pad rows fill it
+    to the longest shard's S; the feature axis is padded with bin 0 to a
+    multiple of its size.  ``jax.make_array_from_callback`` asks for each
+    addressable device's block, and each block is its own transfer from
+    host memory to that device.  A block that needs no padding is the
+    shard's own view (or a cast of it where the dtype differs); only a
+    block that holds pad rows or pad features is built on the host.
+    ``span`` (the ``train.upload`` attrs) gets ``pieces``, the per-device
+    transfers, and ``host_copy_bytes``, the bytes of those built blocks.
     """
     from ..core.mesh import pad_to_multiple
-    n, f = bins.shape
-    dn = int(mesh.shape[DATA_AXIS])
-    fn = _feat_n(mesh)
-    rp = pad_to_multiple(n, dn) - n
-    fp = pad_to_multiple(f, fn) - f
-    if rp:
-        bins = np.concatenate(
-            [bins, np.zeros((rp, bins.shape[1]), bins.dtype)], axis=0)
-        labels = np.concatenate([labels, np.zeros(rp, labels.dtype)])
-        weights = np.concatenate([weights, np.zeros(rp, weights.dtype)])
-    if fp:
-        bins = np.concatenate(
-            [bins, np.zeros((bins.shape[0], fp), bins.dtype)], axis=1)
-    real = np.concatenate(
-        [np.ones(n, np.float32), np.zeros(rp, np.float32)])
+    from .engine import train_stats
+    S = max(sizes)
+    n_global = len(sizes) * S
+    f_padded = pad_to_multiple(f, _feat_n(mesh))
+    copied = [0]
 
-    bins_d = jax.device_put(
-        jnp.asarray(bins),   # dtype preserved (uint8 when B <= 256)
-        NamedSharding(mesh, P(DATA_AXIS, _f_ax(mesh))))
-    lab_d = jax.device_put(
-        jnp.asarray(labels, jnp.int32 if num_class > 1 else jnp.float32),
-        NamedSharding(mesh, P(DATA_AXIS)))
-    w_d = jax.device_put(jnp.asarray(weights, jnp.float32),
-                         NamedSharding(mesh, P(DATA_AXIS)))
-    real_d = jax.device_put(jnp.asarray(real),
-                            NamedSharding(mesh, P(DATA_AXIS)))
-    shape = (bins.shape[0], num_class) if num_class > 1 else (bins.shape[0],)
-    spec = P(DATA_AXIS, None) if num_class > 1 else P(DATA_AXIS)
-    scores0 = np.full(shape, init, np.float32)
-    if init_scores is not None:
-        pad_init = np.concatenate(
-            [np.asarray(init_scores, np.float32),
-             np.zeros((rp,) + init_scores.shape[1:], np.float32)])
-        scores0 = scores0 + (pad_init if scores0.ndim == pad_init.ndim
-                             else pad_init[:, None])
-    scores = jax.device_put(jnp.asarray(scores0),
-                            NamedSharding(mesh, spec))
-    return bins_d, lab_d, w_d, real_d, scores, rp, fp
+    def make(spec, dtype, fill, source, width=None):
+        shape = (n_global,) if width is None else (n_global, width)
+        sharding = NamedSharding(mesh, spec)
+        want = sharding.shard_shape(shape)
+
+        def piece(index):
+            d = index[0].indices(n_global)[0] // S
+            block = np.asarray(source(d), dtype)[
+                (slice(None),) + tuple(index[1:])]
+            if block.shape != want:
+                out = np.full(want, fill, dtype)
+                out[tuple(slice(k) for k in block.shape)] = block
+                copied[0] += out.nbytes
+                block = out
+            if piece_spy is not None:
+                piece_spy(block.shape)
+            return block
+
+        return jax.make_array_from_callback(shape, sharding, piece)
+
+    def score_shard(d):
+        # ``init``: a float, or one start score per class; per-shard init
+        # scores (initScoreCol) offset the real rows, pad rows keep the
+        # plain init (their weight is zero anyway)
+        base = np.full((S,) if num_class == 1 else (S, num_class), init,
+                       np.float32)
+        if init_score_shards is not None \
+                and init_score_shards[d] is not None:
+            extra = np.asarray(init_score_shards[d], np.float32)
+            base[:sizes[d]] += (extra if extra.ndim == base.ndim
+                                else extra[:, None])
+        return base
+
+    lab_dtype = np.int32 if num_class > 1 else np.float32
+    out = (make(P(DATA_AXIS, _f_ax(mesh)), bin_dtype, 0,
+                lambda d: bins_shards[d], width=f_padded),
+           make(P(DATA_AXIS), lab_dtype, 0, lambda d: label_shards[d]),
+           make(P(DATA_AXIS), np.float32, 0.0, lambda d: weight_shards[d]),
+           make(P(DATA_AXIS), np.float32, 0.0,
+                lambda d: np.arange(S) < sizes[d]),
+           make(P(DATA_AXIS, None), np.float32, init, score_shard,
+                width=num_class) if num_class > 1 else
+           make(P(DATA_AXIS), np.float32, init, score_shard))
+    if span is not None:
+        span["pieces"] = sum(len(a.addressable_shards) for a in out)
+        span["host_copy_bytes"] = copied[0]
+    train_stats.incr("mesh_uploads_direct")
+    return out + (n_global - sum(sizes), f_padded - f)

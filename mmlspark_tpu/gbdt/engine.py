@@ -408,7 +408,7 @@ train_stats = StageStats()
 for _k in ("chunks_replayed", "ckpt_saved", "ckpt_resumed",
            "ckpt_discarded", "boost_chunks", "ref_profiles",
            "ref_profiles_device", "ref_profile_margins_from_scores",
-           "mesh_step_hits", "mesh_step_builds",
+           "mesh_step_hits", "mesh_step_builds", "mesh_uploads_direct",
            "collective_count", "collective_payload_bytes"):
     train_stats.incr(_k, 0)
 del _k
